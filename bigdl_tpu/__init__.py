@@ -35,9 +35,8 @@ if _os.environ.get("BIGDL_CPU_MESH"):
     try:
         import jax as _jax
         _jax.config.update("jax_platforms", "cpu")
-        from bigdl_tpu.utils.engine import set_cpu_device_count \
-            as _set_cpu_device_count
-        _set_cpu_device_count(int(_os.environ["BIGDL_CPU_MESH"]))
+        _jax.config.update("jax_num_cpu_devices",
+                           int(_os.environ["BIGDL_CPU_MESH"]))
     except (RuntimeError, ValueError) as _e:
         # backend already initialized, or a non-integer value
         import warnings as _warnings

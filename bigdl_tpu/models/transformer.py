@@ -257,7 +257,7 @@ def _lm_forward_window(tok, i, caches, handles, pe, pages, valid=None,
     use_kernel = _PALLAS_SPEC_VERIFY if S > 1 else _PALLAS_PAGED_ATTN
     if use_kernel:
         from bigdl_tpu.ops import pallas_kernels as pk
-        kernel_interp = (use_kernel == "interpret") or not pk._on_tpu()
+        kernel_interp = pk._interpreted(use_kernel)
     mask = (jnp.arange(n_view)[None, None, None, :]
             <= i[:, None, :, None])                      # (B, 1, S, T)
 

@@ -46,8 +46,8 @@ def run_perf(model_name: str, batch_size: int, iterations: int,
              data_type: str = "bf16", iters_per_dispatch: int = 1) -> dict:
     """``iters_per_dispatch > 1`` uses the device-side training loop
     (n scanned steps per dispatch over distinct stacked minibatches, the
-    set_iterations_per_dispatch feature) — on dispatch-latency-bound
-    setups this reports the device-limited rate."""
+    set_iterations_per_dispatch feature) — where a step's device work is
+    shorter than a host dispatch this reports the device-limited rate."""
     import jax
     import jax.numpy as jnp
     import bigdl_tpu.nn as nn
@@ -131,7 +131,7 @@ def run_perf(model_name: str, batch_size: int, iterations: int,
 
     compile_t0 = time.perf_counter()
     out = step(params, net_state, opt_state, x, y, key)
-    float(out[3])  # device->host copy = hard sync (see bench.py)
+    float(out[3])  # device->host copy: waits for the step
     compile_time = time.perf_counter() - compile_t0
     params, net_state, opt_state, _ = out
 
@@ -175,6 +175,8 @@ def main(argv=None, force_distributed=None):
                 "`python -m bigdl_tpu.models.utils.perf --distributed` instead")
     distributed = (force_distributed if force_distributed is not None
                    else args.distributed)
+    from bigdl_tpu.utils.engine import enable_compile_cache
+    enable_compile_cache()
     result = run_perf(args.model, args.batchSize, args.iteration,
                       args.warmup, distributed, args.dataType,
                       iters_per_dispatch=args.iterationsPerDispatch)

@@ -144,24 +144,6 @@ def _s2d_parts(x, w, s, pad):
     return xs, ws, oh, ow
 
 
-def _barrier_grad_supported() -> bool:
-    """Older jaxlibs have no differentiation rule for
-    ``optimization_barrier``; trace (no dispatch) a grad through one to
-    decide whether the s2d backward may pin its operands."""
-    try:
-        jax.make_jaxpr(jax.grad(
-            lambda v: lax.optimization_barrier(v * v)))(1.0)
-        return True
-    except NotImplementedError:
-        return False
-
-
-# keep the stem wgrad in s2d geometry (A/B: PERF_NOTES r4) where the
-# barrier is differentiable; otherwise plain autodiff geometry (slower
-# stem wgrad, same numbers)
-_S2D_BWD = _barrier_grad_supported()
-
-
 @partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def _space_to_depth_conv(x, w, s, pad):
     """Strided low-channel conv rewritten as space-to-depth + stride-1 conv.
@@ -201,10 +183,9 @@ def _s2d_conv_bwd(s, pad, res, g):
         xs, ws, oh, ow = _s2d_parts(x_, w_, s, pad)
         # barrier the s2d operands: without it XLA folds the phase
         # transforms into the grad convs and canonicalizes them back to
-        # the slow low-channel geometry
-        if _S2D_BWD:
-            xs = lax.optimization_barrier(xs)
-            ws = lax.optimization_barrier(ws)
+        # the slow low-channel geometry (A/B: PERF_NOTES r4)
+        xs = lax.optimization_barrier(xs)
+        ws = lax.optimization_barrier(ws)
         y = _conv(xs, ws, (1, 1), [(0, 0), (0, 0)])
         return y[:, :, :oh, :ow]
 

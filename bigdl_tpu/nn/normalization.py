@@ -184,9 +184,10 @@ class SpatialCrossMapLRN(TensorModule):
 
     def _forward(self, P, x, S, ctx):
         if self._PALLAS and x.ndim == 4:
-            from bigdl_tpu.ops.pallas_kernels import lrn_channel, _on_tpu
+            from bigdl_tpu.ops.pallas_kernels import (_interpreted,
+                                                      lrn_channel)
             return lrn_channel(x, self.size, self.alpha, self.beta, self.k,
-                               not _on_tpu()), None
+                               _interpreted(self._PALLAS)), None
         lo = (self.size - 1) // 2
         hi = self.size - 1 - lo
         if self._ANALYTIC_VJP and not self._STENCIL:
@@ -229,6 +230,17 @@ class SpatialCrossMapLRN(TensorModule):
 
 
 def _lrn_window_sum(v, size, lo, hi):
+    if v.shape[0] < 8:
+        # XLA:TPU (libtpu 0.0.34) rewrites convs whose batch is below 8
+        # into space-to-batch form and carries the rewrite into the ops
+        # that consume them; through this padded channel-window
+        # reduction it builds mis-shaped HLO (forward: "Binary op with
+        # incompatible shapes bf16[..,192] and bf16[..,188]") or aborts
+        # the compiler (backward: space_to_batch_converter.cc "Check
+        # failed").  The barrier keeps a producer conv's rewrite out of
+        # the window sum; batches of 8 and more never trigger the pass
+        # and keep the fused form.
+        v = lax.optimization_barrier(v)
     return lax.reduce_window(
         v, 0.0, lax.add,
         window_dimensions=(1, size, 1, 1),

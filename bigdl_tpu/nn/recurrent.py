@@ -48,10 +48,11 @@ _BLOCK_T = 1
 def _pallas_gate():
     """(use, interpret) — the ONE activation gate for the fused
     recurrence kernels, shared by every dispatch site."""
+    from bigdl_tpu.ops.pallas_kernels import _on_tpu
     interp = _PALLAS_BILSTM == "interpret"
     use = (bool(_PALLAS_BILSTM)
            and policy().output_dtype == jnp.float32
-           and (interp or jax.default_backend() == "tpu"))
+           and (interp or _on_tpu()))
     return use, interp
 
 

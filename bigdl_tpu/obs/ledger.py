@@ -66,24 +66,26 @@ PEAK_FLOPS = {
     "TPU v5p": 459e12, "TPU v6 lite": 918e12, "TPU v6e": 918e12,
 }
 
-DEFAULT_PEAK = 197e12   # v5e — matches bench.py's historical default
-
-
-def device_peak_flops(device=None) -> float:
-    """Datasheet peak for ``device`` (default: the first jax device).
-    Unknown kinds (CPU, new chips) fall back to the v5e number so MFU
-    stays finite and comparable across the toolchain."""
+def device_peak_flops(device=None) -> float | None:
+    """Datasheet peak for ``device`` (default: the first jax device),
+    or None on the CPU — a CPU run has no MFU, and callers publish
+    none.  Any other device kind missing from :data:`PEAK_FLOPS` is an
+    error: dividing by another chip's peak would print a plausible,
+    wrong utilization."""
     if device is None:
-        try:
-            import jax
-            device = jax.devices()[0]
-        except Exception:   # pragma: no cover - jax-less context
-            return DEFAULT_PEAK
-    kind = getattr(device, "device_kind", "")
-    for k, v in PEAK_FLOPS.items():
-        if kind.startswith(k):
-            return v
-    return DEFAULT_PEAK
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return None
+    kind = device.device_kind
+    known = max((k for k in PEAK_FLOPS if kind.startswith(k)), key=len,
+                default=None)
+    if known is None:
+        raise KeyError(
+            f"no datasheet peak for device kind {kind!r}: add it to "
+            f"bigdl_tpu.obs.ledger.PEAK_FLOPS (known: "
+            f"{sorted(PEAK_FLOPS)})")
+    return PEAK_FLOPS[known]
 
 
 def enabled() -> bool:
@@ -103,19 +105,6 @@ def _key_hash(key) -> str:
     """8-hex digest of a ledger key — the gauge label that keeps two
     shapes of the same fn distinct without exploding label size."""
     return hashlib.md5(repr(key).encode()).hexdigest()[:8]
-
-
-def _cost_dict(analysis) -> dict:
-    """Normalize XLA's cost analysis: newer jax returns a list of
-    per-computation dicts (this container's 0.4.37 does), older a dict.
-    Indexing the list form with ``["flops"]`` is the TypeError that
-    silently nan'd bench MFU — normalizing HERE is why every probe must
-    resolve through the ledger."""
-    if analysis is None:
-        return {}
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else {}
-    return dict(analysis)
 
 
 class LedgerEntry:
@@ -202,7 +191,7 @@ class CostLedger:
         if not enabled():
             return None
         try:
-            ca = _cost_dict(compiled.cost_analysis())
+            ca = compiled.cost_analysis() or {}
             kw = dict(flops=ca.get("flops", float("nan")),
                       bytes_accessed=ca.get("bytes accessed",
                                             float("nan")))
@@ -240,7 +229,7 @@ class CostLedger:
             with self._lock:
                 if key in self._entries:
                     return self._entries[key]
-            ca = _cost_dict(jitted.lower(*args).cost_analysis())
+            ca = jitted.lower(*args).cost_analysis() or {}
             return self._record(LedgerEntry(
                 fn_key, key, source="jit",
                 flops=ca.get("flops", float("nan")),

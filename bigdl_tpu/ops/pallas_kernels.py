@@ -29,10 +29,30 @@ _BLOCK = 64 * 1024  # elements per grid step (256 KiB f32 — fits VMEM easily)
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    """True on a TPU backend, False on the CPU (where callers take the
+    Pallas interpreter or their XLA reference path).  Any other backend
+    is an error: these are Mosaic TPU kernels, and quietly taking the
+    interpreter there would hide which device did the work."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas TPU kernels cannot run on the {backend!r} backend")
+    return backend == "tpu"
+
+
+def _interpreted(flag) -> bool:
+    """Whether a kernel that ``flag`` switched on runs through the Pallas
+    interpreter: only when the flag asks for it (``"interpret"``) or the
+    backend is the CPU."""
+    return flag == "interpret" or not _on_tpu()
+
+
+def _out_struct(shape, dtype, *operands):
+    """``out_shape`` entry for a ``pallas_call``: carries the union of
+    its operands' varying mesh axes, which ``jax.shard_map`` requires
+    of a kernel traced under ``check_vma=True`` (empty outside one)."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _make_sgd_kernel(nesterov: bool):
@@ -69,8 +89,8 @@ def _fused_sgd_flat(p, g, v, hyper4, interpret=False, nesterov=False):
     grid = padded // _BLOCK
     p2, v2 = pl.pallas_call(
         _SGD_KERNELS[nesterov],
-        out_shape=(jax.ShapeDtypeStruct((padded,), p.dtype),
-                   jax.ShapeDtypeStruct((padded,), v.dtype)),
+        out_shape=(_out_struct((padded,), p.dtype, p, g, v),
+                   _out_struct((padded,), v.dtype, p, g, v)),
         grid=(grid,),
         in_specs=[
             pl.BlockSpec((_BLOCK,), lambda i: (i,), memory_space=pltpu.VMEM),
@@ -165,7 +185,7 @@ def lstm_scan(zx, wht, h0, c0, interpret=False):
         ],
         out_specs=pl.BlockSpec((1, b, h), lambda i: (i, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((t, b, h), jnp.float32),
+        out_shape=_out_struct((t, b, h), jnp.float32, zx, wht, h0, c0),
         scratch_shapes=[pltpu.VMEM((b, h), jnp.float32),
                         pltpu.VMEM((b, h), jnp.float32)],
         interpret=interpret,
@@ -277,7 +297,7 @@ def _maxpool_fwd_call(x, window, strides, pads, interpret=False):
                                memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec((bc, oh, ow), lambda i: (i, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((nc, oh, ow), x.dtype),
+        out_shape=_out_struct((nc, oh, ow), x.dtype, x),
         interpret=interpret,
     )(xr)
     return y.reshape(n, c, oh, ow)
@@ -301,7 +321,7 @@ def _maxpool_bwd_call(x, g, window, strides, pads, interpret=False):
                                memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec((bc, h, w), lambda i: (i, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((nc, h, w), x.dtype),
+        out_shape=_out_struct((nc, h, w), x.dtype, x, g),
         interpret=interpret,
     )(x.reshape(nc, h, w), g.reshape(nc, oh, ow))
     return dx.reshape(n, c, h, w)
@@ -412,7 +432,11 @@ def _lrn_call(kernel, args, out_shapes, size, alpha, beta, k,
     x = args[0]
     n, c, h, w = x.shape
     hw = h * w
-    t = min(3200, -(-hw // 128) * 128)  # multiple of 128 (lane alignment)
+    # lane tile: a multiple of 128, sized so one (C, T) f32 block stays
+    # near 0.8 MB whatever C is — the backward holds four such blocks
+    # double-buffered plus its temporaries inside the 16 MB scoped VMEM
+    # (a whole 192 x 3136 image per block was refused by the compiler)
+    t = min(-(-hw // 128) * 128, max(128, 204800 // c // 128 * 128))
     # ragged final block is safe: the channel window never crosses lanes,
     # so out-of-bounds lanes compute garbage that the store drops
     flat = [a.reshape(n, c, hw) for a in args]
@@ -424,9 +448,9 @@ def _lrn_call(kernel, args, out_shapes, size, alpha, beta, k,
         grid=(n, -(-hw // t)),
         in_specs=[spec] * len(flat),
         out_specs=[spec] * len(out_shapes) if multi else spec,
-        out_shape=([jax.ShapeDtypeStruct((n, c, hw), d) for d in out_shapes]
-                   if multi else jax.ShapeDtypeStruct((n, c, hw),
-                                                      out_shapes[0])),
+        out_shape=([_out_struct((n, c, hw), d, *args) for d in out_shapes]
+                   if multi else _out_struct((n, c, hw), out_shapes[0],
+                                             *args)),
         interpret=interpret,
     )(*flat)
     if multi:
@@ -609,7 +633,7 @@ def _bilstm_fwd_call(zx, wht, interpret=False, with_c=True, block_t=1):
     kt = block_t
     out_spec = pl.BlockSpec((kt, nd, b, h), lambda i: (i, 0, 0, 0),
                             memory_space=pltpu.VMEM)
-    out_shape = jax.ShapeDtypeStruct((t, nd, b, h), jnp.float32)
+    out_shape = _out_struct((t, nd, b, h), jnp.float32, zx, wht)
     return pl.pallas_call(
         _bilstm_fwd_kernel if with_c else _bilstm_fwd_kernel_primal,
         grid=(t // kt,),
@@ -651,8 +675,8 @@ def _bilstm_bwd_call(zx, wht, hs, cs, gout, interpret=False, block_t=1):
             pl.BlockSpec((nd, h, h4), lambda i: (0, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_shape=[jax.ShapeDtypeStruct((t, nd, b, h4), jnp.float32),
-                   jax.ShapeDtypeStruct((nd, h, h4), jnp.float32)],
+        out_shape=[_out_struct((t, nd, b, h4), jnp.float32, zx, wht, gout),
+                   _out_struct((nd, h, h4), jnp.float32, zx, wht, gout)],
         scratch_shapes=[pltpu.VMEM((nd, b, h), jnp.float32),
                         pltpu.VMEM((nd, b, h), jnp.float32),
                         pltpu.VMEM((nd, h, h4), jnp.float32)],
@@ -808,7 +832,7 @@ def _gru_fwd_call(zrz, zn, wrz, wh, interpret=False, block_t=1):
         ],
         out_specs=pl.BlockSpec((kt, nd, b, h), lambda i: (i, 0, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((t, nd, b, h), jnp.float32),
+        out_shape=_out_struct((t, nd, b, h), jnp.float32, zrz, zn, wrz, wh),
         scratch_shapes=[pltpu.VMEM((nd, b, h), jnp.float32)],
         interpret=interpret,
     )(zrz, zn, wrz, wh)
@@ -842,10 +866,9 @@ def _gru_bwd_call(zrz, zn, wrz, wh, hs, gout, interpret=False, block_t=1):
             wspec2,
             wspec1,
         ],
-        out_shape=[jax.ShapeDtypeStruct((t, nd, b, h2), jnp.float32),
-                   jax.ShapeDtypeStruct((t, nd, b, h), jnp.float32),
-                   jax.ShapeDtypeStruct((nd, h, h2), jnp.float32),
-                   jax.ShapeDtypeStruct((nd, h, h), jnp.float32)],
+        out_shape=[_out_struct(shape, jnp.float32, zrz, zn, wrz, wh, gout)
+                   for shape in ((t, nd, b, h2), (t, nd, b, h),
+                                 (nd, h, h2), (nd, h, h))],
         scratch_shapes=[pltpu.VMEM((nd, b, h), jnp.float32),
                         pltpu.VMEM((nd, h, h2), jnp.float32),
                         pltpu.VMEM((nd, h, h), jnp.float32)],
@@ -959,7 +982,7 @@ def _rnn_fwd_call(zx, wht, interpret=False, block_t=1):
         ],
         out_specs=pl.BlockSpec((kt, nd, b, h), lambda i: (i, 0, 0, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((t, nd, b, h), jnp.float32),
+        out_shape=_out_struct((t, nd, b, h), jnp.float32, zx, wht),
         scratch_shapes=[pltpu.VMEM((nd, b, h), jnp.float32)],
         interpret=interpret,
     )(zx, wht)
@@ -986,8 +1009,8 @@ def _rnn_bwd_call(wht, hs, gout, interpret=False, block_t=1):
             pl.BlockSpec((nd, h, h), lambda i: (0, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_shape=[jax.ShapeDtypeStruct((t, nd, b, h), jnp.float32),
-                   jax.ShapeDtypeStruct((nd, h, h), jnp.float32)],
+        out_shape=[_out_struct((t, nd, b, h), jnp.float32, wht, hs, gout),
+                   _out_struct((nd, h, h), jnp.float32, wht, hs, gout)],
         scratch_shapes=[pltpu.VMEM((nd, b, h), jnp.float32),
                         pltpu.VMEM((nd, h, h), jnp.float32)],
         interpret=interpret,
@@ -1107,16 +1130,17 @@ def _mosaic_mp_fwd_kernel_primal(xm_ref, xh_ref, y_ref, **kw_):
     _mosaic_mp_fwd_body(xm_ref, xh_ref, y_ref, None, **kw_)
 
 
-def _mosaic_mp_bwd_kernel(gp_ref, ap_ref, gm_ref, am_ref, dx_ref, *,
-                          kh, kw, sh, sw, c, bh, nblk):
+def _mosaic_mp_bwd_kernel(gp_ref, ap_ref, gm_ref, am_ref, dx_ref, acc_ref,
+                          *, kh, kw, sh, sw, c, bh, nblk):
     """Scatter-free gather: dx row-block <- sum over the stored argmax
     of the two g/a row-blocks whose windows can reach it (previous +
-    main — the blocking guarantees no window spans further)."""
+    main — the blocking guarantees no window spans further).  The f32
+    accumulator is a VMEM scratch updated through static ref slices:
+    Mosaic has no lowering for a value-level ``.at[].add``."""
     blk = pl.program_id(1)
     bi = dx_ref.shape[1]             # s_h * bh input rows per step
     ow = gm_ref.shape[2]
-    wq = dx_ref.shape[2]
-    acc = jnp.zeros((bi, wq, sw * c), jnp.float32)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
     # the prev spec clamps blk-1 to 0 and the main spec clamps blk to
     # nblk-1: a clamped (duplicate) block must contribute nothing
     valid = ((blk > 0).astype(jnp.float32),
@@ -1136,10 +1160,9 @@ def _mosaic_mp_bwd_kernel(gp_ref, ap_ref, gm_ref, am_ref, dx_ref, *,
                 for j in range(kw):
                     contrib = g_row * (a_row == (i * kw + j)
                                        ).astype(jnp.float32)
-                    acc = acc.at[hloc, j // sw:j // sw + ow,
-                                 (j % sw) * c:(j % sw) * c + c
-                                 ].add(contrib)
-    dx_ref[0] = acc.astype(dx_ref.dtype)
+                    acc_ref[hloc, j // sw:j // sw + ow,
+                            (j % sw) * c:(j % sw) * c + c] += contrib
+    dx_ref[0] = acc_ref[...].astype(dx_ref.dtype)
 
 
 def _mosaic_mp_pack(x, window, strides, pads, fill):
@@ -1177,8 +1200,8 @@ def _mosaic_mp_fwd_call(x, window, strides, pads, interpret=False,
                         memory_space=pltpu.VMEM)
     ospec = pl.BlockSpec((1, bh, ow, c), lambda nn_, b: (nn_, b, 0, 0),
                          memory_space=pltpu.VMEM)
-    oshape = jax.ShapeDtypeStruct((n, nblk * bh, ow, c), x.dtype)
-    ashape = jax.ShapeDtypeStruct((n, nblk * bh, ow, c), jnp.int32)
+    oshape = _out_struct((n, nblk * bh, ow, c), x.dtype, x)
+    ashape = _out_struct((n, nblk * bh, ow, c), jnp.int32, x)
     body = functools.partial(
         _mosaic_mp_fwd_kernel if with_argmax
         else _mosaic_mp_fwd_kernel_primal,
@@ -1225,7 +1248,8 @@ def _mosaic_mp_bwd_call(a, g, window, strides, pads, xshape,
         grid=(n, nblk + 1),
         in_specs=[gspec_p, gspec_p, gspec_m, gspec_m],
         out_specs=dspec,
-        out_shape=jax.ShapeDtypeStruct((n, hp, wq, sw * c), g.dtype),
+        out_shape=_out_struct((n, hp, wq, sw * c), g.dtype, a, g),
+        scratch_shapes=[pltpu.VMEM((sh * bh, wq, sw * c), jnp.float32)],
         interpret=interpret,
     )(gt, a, gt, a)
     # unfold phases, drop padding, back to NCHW
@@ -1296,8 +1320,9 @@ def mosaic_maxpool2d(x, window, strides, pads, interpret=False):
 # ---------------------------------------------------------------------------
 
 
-def _paged_attn_kernel(ptab_ref, *refs, page_size, scale, quantized):
-    """One (batch row b, head h, page p) grid step.
+def _paged_attn_kernel(ptab_ref, *refs, page_size, n_heads, scale,
+                       quantized):
+    """One (batch row b, page p) grid step, every head.
 
     Page p's K/V block (and scale rows when quantized) land in VMEM via
     the scalar-prefetch index map; scratch carries the flash-attention
@@ -1306,6 +1331,9 @@ def _paged_attn_kernel(ptab_ref, *refs, page_size, scale, quantized):
     `pos >= 0`, so m is finite from the first page and the
     `exp(-inf - finite) = 0` identities keep the recurrence exact for
     fully-masked later pages (reserved-but-unwritten tail pages).
+
+    Heads ride the lane dim (the caller flattens (H, hd) -> H*hd), so a
+    head is a static lane slice — lane-tile aligned when hd % 128 == 0.
     """
     if quantized:
         (pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
@@ -1313,7 +1341,7 @@ def _paged_attn_kernel(ptab_ref, *refs, page_size, scale, quantized):
     else:
         pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
         ks_ref = vs_ref = None
-    p = pl.program_id(2)
+    p = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
@@ -1321,71 +1349,84 @@ def _paged_attn_kernel(ptab_ref, *refs, page_size, scale, quantized):
         l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
         acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)          # (S, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (page_size, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    if quantized:
-        # kvq.dequantize_view fused in-loop: int8 * per-(page-row, head)
-        # scale, indexed by the same phys page the K/V DMA used.
-        k = k * ks_ref[0, :, 0][:, None]
-        v = v * vs_ref[0, :, 0][:, None]
-    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32) * scale
-    t = p * page_size + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    pos = pos_ref[0, :]                                # (S,)
-    s = jnp.where(t <= pos[:, None], s, -jnp.inf)
-    m_prev = m_ref[...]                                # (S, 1)
-    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    w = jnp.exp(s - m_new)                             # (S, page_size)
-    l_ref[...] = l_ref[...] * alpha + w.sum(axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
-        w, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    ws = q_ref.shape[1]
+    hd = q_ref.shape[2] // n_heads
+    t = p * page_size + lax.broadcasted_iota(jnp.int32, (ws, page_size), 1)
+    live = t <= pos_ref[0]                             # pos column (S, 1)
+    for h in range(n_heads):                           # static head loop
+        lanes = slice(h * hd, (h + 1) * hd)
+        q = q_ref[0, :, lanes].astype(jnp.float32)     # (S, hd)
+        k = k_ref[0, :, lanes].astype(jnp.float32)     # (page_size, hd)
+        v = v_ref[0, :, lanes].astype(jnp.float32)
+        if quantized:
+            # kvq.dequantize_view fused in-loop: int8 * per-(page-row,
+            # head) scale, indexed by the same phys page the K/V DMA used.
+            k = k * ks_ref[0, :, h:h + 1]
+            v = v * vs_ref[0, :, h:h + 1]
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(live, s, -jnp.inf)
+        m_prev = m_ref[h]                              # (S, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        w = jnp.exp(s - m_new)                         # (S, page_size)
+        l_ref[h] = l_ref[h] * alpha + w.sum(axis=1, keepdims=True)
+        acc_ref[:, lanes] = acc_ref[:, lanes] * alpha + lax.dot_general(
+            w, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
 
-    @pl.when(p == pl.num_programs(2) - 1)
+    @pl.when(p == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[0, :, 0, :] = acc_ref[...] / l_ref[...]
+        for h in range(n_heads):
+            lanes = slice(h * hd, (h + 1) * hd)
+            o_ref[0, :, lanes] = acc_ref[:, lanes] / l_ref[h]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_attention_call(q, kpool, vpool, ptab, pos, kscale, vscale,
                           interpret):
     bsz, ws, n_heads, hd = q.shape
-    n_ptab_pages = ptab.shape[1]
-    page_size = kpool.shape[1]
+    n_pages, page_size = kpool.shape[:2]
+    d = n_heads * hd
     quantized = kscale is not None
-    scale = 1.0 / (hd ** 0.5)
-    kvspec = pl.BlockSpec((1, page_size, 1, hd),
-                          lambda b, h, p, pt: (pt[b, p], 0, h, 0))
-    sspec = pl.BlockSpec((1, page_size, 1),
-                         lambda b, h, p, pt: (pt[b, p], 0, h))
+    # Mosaic's tiling rule: a block's last two dims are multiples of
+    # (8, 128) or equal the array's.  Flattening (H, hd) -> H*hd (free:
+    # the pool is contiguous) makes every block below a whole
+    # (rows, H*hd) slab of its array, legal at any page size, window
+    # and head geometry; pos rides as a (S, 1) column for the same reason.
+    rowspec = pl.BlockSpec((1, ws, d), lambda b, p, pt: (b, 0, 0))
+    kvspec = pl.BlockSpec((1, page_size, d),
+                          lambda b, p, pt: (pt[b, p], 0, 0))
+    sspec = pl.BlockSpec((1, page_size, n_heads),
+                         lambda b, p, pt: (pt[b, p], 0, 0))
     in_specs = [
-        pl.BlockSpec((1, ws), lambda b, h, p, pt: (b, 0)),          # pos
-        pl.BlockSpec((1, ws, 1, hd), lambda b, h, p, pt: (b, 0, h, 0)),
-        kvspec, kvspec,
+        pl.BlockSpec((1, ws, 1), lambda b, p, pt: (b, 0, 0)),       # pos
+        rowspec, kvspec, kvspec,
     ]
-    operands = [pos.astype(jnp.int32), q, kpool, vpool]
+    operands = [pos.astype(jnp.int32).reshape(bsz, ws, 1),
+                q.reshape(bsz, ws, d),
+                kpool.reshape(n_pages, page_size, d),
+                vpool.reshape(n_pages, page_size, d)]
     if quantized:
         in_specs += [sspec, sspec]
         operands += [kscale, vscale]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, page_size=page_size,
-                          scale=scale, quantized=quantized),
+                          n_heads=n_heads, scale=1.0 / (hd ** 0.5),
+                          quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bsz, n_heads, n_ptab_pages),
+            grid=(bsz, ptab.shape[1]),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, ws, 1, hd),
-                                   lambda b, h, p, pt: (b, 0, h, 0)),
-            scratch_shapes=[pltpu.VMEM((ws, 1), jnp.float32),
-                            pltpu.VMEM((ws, 1), jnp.float32),
-                            pltpu.VMEM((ws, hd), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((bsz, ws, n_heads, hd),
-                                       jnp.float32),
+            out_specs=rowspec,
+            scratch_shapes=[pltpu.VMEM((n_heads, ws, 1), jnp.float32),
+                            pltpu.VMEM((n_heads, ws, 1), jnp.float32),
+                            pltpu.VMEM((ws, d), jnp.float32)]),
+        out_shape=_out_struct((bsz, ws, d), jnp.float32, *operands),
         interpret=interpret,
     )(ptab.astype(jnp.int32), *operands)
+    return out.reshape(bsz, ws, n_heads, hd)
 
 
 def paged_attention(q, kpool, vpool, ptab, pos, kscale=None, vscale=None,

@@ -38,7 +38,7 @@ import time
 
 import jax
 
-from bigdl_tpu.parallel.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P

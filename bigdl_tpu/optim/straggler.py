@@ -116,7 +116,7 @@ class StragglerPolicy:
 
         A task is dropped only when it is over the threshold AND slower
         than the fastest cohort: the threshold is a quantile over TIME,
-        so a uniformly slow iteration (GC pause, relay hiccup — every
+        so a uniformly slow iteration (GC pause, host stall — every
         task's wall identical) would otherwise mask ALL tasks and
         spuriously reject the iteration.  A straggler is slow RELATIVE
         to its peers (the reference's timeout fires while other tasks

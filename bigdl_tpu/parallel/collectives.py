@@ -42,19 +42,13 @@ def ppermute(x, axis_name: str, perm):
 
 def pvary(x, axis_names):
     """Mark ``x`` device-varying over ``axis_names`` (shard_map scan carries
-    must keep a consistent varying type).  ``lax.pvary`` is deprecated in
-    jax>=0.9 in favor of ``lax.pcast(..., to='varying')``."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis_names, to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, axis_names)
-    return x  # pre-vma jax (check_rep model): nothing to mark
+    must keep a consistent varying type)."""
+    return lax.pcast(x, axis_names, to="varying")
 
 
 def ring_shift(x, axis_name: str, shift: int = 1):
     """Shift values around the axis ring by ``shift`` positions."""
-    from bigdl_tpu.parallel.compat import axis_size as _axis_size
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 
@@ -70,5 +64,4 @@ def axis_index(axis_name: str):
 
 
 def axis_size(axis_name: str):
-    from bigdl_tpu.parallel.compat import axis_size as _axis_size
-    return _axis_size(axis_name)
+    return lax.axis_size(axis_name)

@@ -20,7 +20,7 @@ from functools import partial
 
 import jax
 
-from bigdl_tpu.parallel.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P

@@ -24,10 +24,8 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-
-from bigdl_tpu.parallel.compat import shard_map, grad_psum_is_explicit
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 
 from bigdl_tpu.parallel.collectives import pvary
 from jax.sharding import Mesh, PartitionSpec as P
@@ -345,15 +343,6 @@ def pipeline_train_1f1b(stage_fn, loss_fn, stage_params, x_micro, t_micro,
         carry = (buf_fwd, buf_bwd, resid, grad_acc, loss_acc, my_state0)
         carry, _ = lax.scan(tick, carry, jnp.arange(n_ticks))
         _, _, _, grad_acc, loss_acc, my_state = carry
-        if data_axis is not None and grad_psum_is_explicit():
-            # old-jax shard_map (check_rep=False) does NOT auto-psum the
-            # cotangent of the data-replicated my_params, so grad_acc is
-            # each replica's PARTIAL sum here — reduce it once after the
-            # scan (psum is linear, so one reduce == per-tick reduces).
-            # On vma-aware jax the per-tick gp already arrives summed
-            # and this branch must stay off or grads double-count.
-            grad_acc = jax.tree_util.tree_map(
-                lambda v: lax.psum(v, data_axis), grad_acc)
         loss = lax.psum(loss_acc, axis)  # only last rank contributed
         if data_axis is not None:
             # loss_acc already carries the 1/dscale factor: psum over the

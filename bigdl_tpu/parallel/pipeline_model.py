@@ -31,8 +31,6 @@ per-clone thread RNGs (Dropout.scala threads over Engine.model).
 from __future__ import annotations
 
 import jax
-
-from bigdl_tpu.parallel.compat import typeof as _compat_typeof
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -256,9 +254,7 @@ class StagePlan:
             # switch requires equal output types, so promote every
             # branch output to the operands' varying axes
             from bigdl_tpu.parallel.collectives import pvary
-            vma = getattr(_compat_typeof(v), "vma", None)
-            if vma is None:
-                return v
+            vma = jax.typeof(v).vma
             missing = tuple(a for a in target_vma if a not in vma)
             return pvary(v, missing) if missing else v
 
@@ -275,9 +271,8 @@ class StagePlan:
                 key = jax.random.fold_in(base_key,
                                          lax.axis_index(fold_axis))
                 branches = self.make_branches(key, training)
-            target = set(getattr(_compat_typeof(flat_x), "vma", ()) or ())
-            target |= set(getattr(_compat_typeof(flat_p), "vma", ()) or ())
-            target |= {axis}
+            target = (set(jax.typeof(flat_x).vma)
+                      | set(jax.typeof(flat_p).vma) | {axis})
             wrapped = [
                 (lambda p, s, x, mm, b=b:
                  jax.tree_util.tree_map(

@@ -22,12 +22,8 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-
-from bigdl_tpu.parallel.compat import typeof as _compat_typeof
-
-from bigdl_tpu.parallel.compat import shard_map
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 
 from bigdl_tpu.parallel.collectives import pvary
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -63,8 +59,7 @@ def ring_attention(q, k, v, axis_name: str = "seq", causal: bool = False):
     """Collective ring attention: call inside shard_map with q/k/v sequence-
     sharded over ``axis_name``.  Shapes per device: (B, T_local, H, D)."""
     scale = 1.0 / (q.shape[-1] ** 0.5)
-    from bigdl_tpu.parallel.compat import axis_size as _axis_size
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     t_local = q.shape[1]
     q_off = idx * t_local
@@ -90,7 +85,7 @@ def ring_attention(q, k, v, axis_name: str = "seq", causal: bool = False):
     b, _, h, d = q.shape
     # pvary: initial accumulators must carry the same varying type as the
     # operands (the ring axis, plus a batch axis under hybrid dp x sp)
-    vary_axes = tuple(getattr(_compat_typeof(q), "vma", None) or (axis_name,))
+    vary_axes = tuple(jax.typeof(q).vma or (axis_name,))
     m0 = pvary(jnp.full((b, h, t_local), -jnp.inf, jnp.float32), vary_axes)
     l0 = pvary(jnp.zeros((b, h, t_local), jnp.float32), vary_axes)
     o0 = pvary(jnp.zeros((b, t_local, h, d), jnp.float32), vary_axes)

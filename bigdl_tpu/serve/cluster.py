@@ -65,6 +65,7 @@ import time
 from collections import deque
 from concurrent.futures import Future
 
+import jax
 import numpy as np
 
 from bigdl_tpu.serve.engine import (PoisonedRequestError, ServeEngine,
@@ -76,6 +77,7 @@ from bigdl_tpu.serve.paging import RequestTooLongError
 from bigdl_tpu.serve.router import (DeadReplicaError, Router,
                                     replicas_default)
 from bigdl_tpu.serve.streaming import StreamFuture, TokenDelivery
+from bigdl_tpu.utils.engine import CHECKOUT, enable_compile_cache
 
 logger = logging.getLogger("bigdl_tpu.serve")
 
@@ -123,6 +125,82 @@ class ReplicaSpawnError(RuntimeError):
 #: handshake (after the init frame, before `ready`) — the drill site
 #: behind the ReplicaSpawnError and circuit-breaker regression tests
 ENV_SPAWN_FAIL = "BIGDL_SERVE_SPAWN_FAIL"
+
+#: names the jax platform of a replica worker process; unset, the
+#: worker runs where its parent's environment points it
+#: (``JAX_PLATFORMS``, else the best backend the machine has)
+ENV_WORKER_PLATFORM = "BIGDL_SERVE_WORKER_PLATFORM"
+
+
+def child_process_env(env=None) -> dict:
+    """Environment for a replica worker this process is about to start
+    (stdio :class:`ProcessReplica` children and loopback replica
+    agents): this process's environment, minus its event-log dir, plus
+    the checkout on ``PYTHONPATH`` and the caller's ``env`` overrides.
+
+    Refuses the one combination that cannot work: a chip belongs to ONE
+    process, so a parent whose jax runtime already holds the TPU cannot
+    start a child that would open it too — the child fails or hangs on
+    the chip's lock.  Either the parent stays off jax (build the model
+    on the host, let the workers own the chips), or the replicas run
+    in-process (:class:`ReplicaPool` places replica ``i`` on local
+    device ``i``), or the child is told another platform."""
+    child_env = dict(os.environ)
+    # the child must NOT inherit the parent's event-log dir: its
+    # events reach the parent's log over `op: event` frames
+    # (append_foreign, attributed replica=<name>); an inherited
+    # BIGDL_OBS_DIR would make the child open the same
+    # events.p0.jsonl and double-write every event.  An explicit
+    # env={...} override can still opt a child into its own file sink.
+    from bigdl_tpu.obs import events as obs_events
+    child_env.pop(obs_events.ENV_DIR, None)
+    child_env["PYTHONPATH"] = (CHECKOUT + os.pathsep
+                               + child_env.get("PYTHONPATH", ""))
+    if env:
+        child_env.update(env)
+    child_platform = (child_env.get(ENV_WORKER_PLATFORM)
+                      or child_env.get("JAX_PLATFORMS") or "tpu")
+    from jax._src import xla_bridge
+    if (child_platform.split(",")[0] == "tpu"
+            and xla_bridge.backends_are_initialized()
+            and jax.default_backend() == "tpu"):
+        raise ReplicaSpawnError(
+            "this process already holds the TPU, and a chip belongs to "
+            "one process: a child replica started now would fail or "
+            "hang opening it.  Keep the parent off jax, run the "
+            "replicas in-process, or set "
+            f"{ENV_WORKER_PLATFORM} for the child")
+    return child_env
+
+
+def next_local_device(counter):
+    """Device for the next in-process replica of a pool: replica ``i``
+    takes ``jax.local_devices()[i % n]``, so N replicas on an N-chip
+    host own a chip each instead of all landing on chip 0.
+    ``counter`` is the pool's ``itertools.count()`` of replicas placed."""
+    devices = jax.local_devices()
+    return devices[next(counter) % len(devices)]
+
+
+def init_worker_runtime():
+    """jax set-up of a replica worker process (stdio worker and TCP
+    agent), before its first backend touch.  The platform is the one
+    ``BIGDL_SERVE_WORKER_PLATFORM`` names, else whatever the inherited
+    environment gives jax — a worker never quietly drops to the CPU
+    under a parent that runs on the chip.  A CPU worker pins its device
+    count and full matmul precision (parity with the in-process CPU
+    tests); every worker shares the persistent compile cache, so a
+    replica's warm-up is a cache read."""
+    platform = os.environ.get(ENV_WORKER_PLATFORM)
+    if platform:
+        jax.config.update("jax_platforms", platform)
+    if (platform or os.environ.get("JAX_PLATFORMS")) == "cpu":
+        jax.config.update(
+            "jax_num_cpu_devices",
+            int(os.environ.get("BIGDL_SERVE_WORKER_DEVICES", "1")))
+        jax.config.update("jax_default_matmul_precision", "highest")
+    enable_compile_cache()
+    os.environ.setdefault("BIGDL_CHECK_SINGLETON", "0")
 
 
 # ---------------------------------------------------------------------------
@@ -275,22 +353,7 @@ class ProcessReplica:
         #: never run on, or block, the frame-reader thread
         self._delivery = None
 
-        child_env = dict(os.environ)
-        # the child must NOT inherit the parent's event-log dir: its
-        # events reach the parent's log over `op: event` frames
-        # (append_foreign, attributed replica=<name>); an inherited
-        # BIGDL_OBS_DIR would make the child open the same
-        # events.p0.jsonl and double-write every event.  An explicit
-        # env={...} override below can still opt a child into its own
-        # file sink.
-        from bigdl_tpu.obs import events as obs_events
-        child_env.pop(obs_events.ENV_DIR, None)
-        repo_root = os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-        child_env["PYTHONPATH"] = (repo_root + os.pathsep
-                                   + child_env.get("PYTHONPATH", ""))
-        if env:
-            child_env.update(env)
+        child_env = child_process_env(env)
         # the child engine's registry series must not collide with a
         # same-named engine in another replica once snapshots merge
         engine_kwargs = dict(engine_kwargs)
@@ -818,6 +881,7 @@ class ReplicaPool(DynamicMembership):
         self._served_version: int | None = None
         self._warming = 0
         self._next_replica = 0
+        self._placed = itertools.count()   # in-process replicas built
         if replicas is None:
             if model is None and replica_factory is None:
                 raise ValueError(
@@ -933,6 +997,9 @@ class ReplicaPool(DynamicMembership):
             else:
                 kw.pop("env", None)
             return ProcessReplica(self._model, name=name, env=env, **kw)
+        if "device" not in kw:
+            kw["device"] = next_local_device(self._placed)
+        logger.info("replica %s on %s", name, kw["device"])
         return LocalReplica(ServeEngine(self._model, name=name, **kw),
                             name=name)
 
@@ -1383,9 +1450,9 @@ def build_worker_ops(init, send) -> WorkerOps:
 def worker_main(stdin=None, stdout=None):
     """Entry point of a ProcessReplica child: build the ops handler the
     init frame names (engine / decode / prefill) and answer frames
-    until EOF/close.  Runs with its own jax runtime (platform via
-    ``BIGDL_SERVE_WORKER_PLATFORM``, default cpu — on a real fleet each
-    replica process owns its accelerator slice).
+    until EOF/close.  Runs with its own jax runtime
+    (:func:`init_worker_runtime` — on a real fleet each replica process
+    owns its accelerator slice).
 
     ``BIGDL_FAULTS=serve_kill@at=N[,proc=...]`` kills this process at
     the Nth submitted request (``os._exit``) — the chaos drill for the
@@ -1396,15 +1463,7 @@ def worker_main(stdin=None, stdout=None):
     stdin = stdin or sys.stdin.buffer
     stdout = stdout or sys.stdout.buffer
 
-    import jax
-    platform = os.environ.get("BIGDL_SERVE_WORKER_PLATFORM", "cpu")
-    jax.config.update("jax_platforms", platform)
-    if platform == "cpu":
-        from bigdl_tpu.utils.engine import set_cpu_device_count
-        set_cpu_device_count(
-            int(os.environ.get("BIGDL_SERVE_WORKER_DEVICES", "1")))
-        jax.config.update("jax_default_matmul_precision", "highest")
-    os.environ.setdefault("BIGDL_CHECK_SINGLETON", "0")
+    init_worker_runtime()
 
     init = _read_frame(stdin)
     if init is None or init.get("op") != "init":

@@ -103,7 +103,7 @@ immediately instead of burning steps to ``max_tokens``
 **Tensor-parallel serving** (``mesh=``): a model whose KV pool + weights
 outgrow one chip's HBM serves by sharding the decode step over the
 mesh's ``model`` axis (``parallel/mesh.hybrid_mesh``) with
-``parallel/compat.shard_map`` — Megatron-style: attention heads and the
+``jax.shard_map`` — Megatron-style: attention heads and the
 FFN hidden dim split across shards (wq/wk/wv columns + the KV pool's
 head dim; lin1 rows), each branch's output projection psum-merges once,
 and everything else (embeddings, LayerNorms, the LM head) replicates.
@@ -289,9 +289,17 @@ class ContinuousDecoder:
                  host_tier=None, prefill_adopt: bool = False,
                  max_stop_seqs: int | None = None,
                  max_stop_len: int | None = None,
-                 name: str | None = None):
+                 name: str | None = None, device=None):
         import jax
         import jax.numpy as jnp
+
+        if device is not None and mesh is not None:
+            raise ValueError("a decoder runs on one device= or shards "
+                             "over a mesh=, not both")
+        #: the jax device this decoder's state is committed to (None =
+        #: jax's default device, or the mesh under tensor parallelism);
+        #: every step program follows its carried state there
+        self.device = device
 
         from bigdl_tpu.models.transformer import (_lm_forward_one,
                                                   _lm_forward_window,
@@ -647,8 +655,6 @@ class ContinuousDecoder:
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
 
-            from bigdl_tpu.parallel import compat
-
             ax = "model"
             wspec = _tp_weight_specs(handles, ax)
             # weights pinned to the mesh ONCE, pre-sharded per the spec:
@@ -729,10 +735,20 @@ class ContinuousDecoder:
                     def step_tp(W, *st):
                         return slab_step_body(_local(W), *st, tp_axis=ax)
                     n_rep_in, n_rep_out = 13, 4
-                sharded = compat.shard_map(
+                # an INTERPRETED attention kernel cannot pass shard_map's
+                # vma check: the Pallas interpreter re-binds the kernel's
+                # primitives on the shard's varying operands without the
+                # pvary casts tracing inserts.  The compiled (Mosaic)
+                # kernel keeps the check — its out_shape carries the
+                # operands' vma (ops/pallas_kernels.py _out_struct).
+                from bigdl_tpu.ops.pallas_kernels import _interpreted
+                interpreted = any(f != "False" and _interpreted(f)
+                                  for f in flag_state)
+                sharded = jax.shard_map(
                     step_tp, mesh=mesh,
                     in_specs=(wspec, cspec) + (rep,) * n_rep_in,
-                    out_specs=(cspec,) + (rep,) * n_rep_out)
+                    out_specs=(cspec,) + (rep,) * n_rep_out,
+                    check_vma=not interpreted)
                 return xcache.tracked_jit(
                     sharded, key + ("tp%d" % self.tp,), mesh=mesh)
             if k:
@@ -819,23 +835,22 @@ class ContinuousDecoder:
             # mixing plain-jit programs into the carry chain would hand
             # the step differently-placed inputs on some paths and cost
             # a silent recompile per (program, sharding) combination
-            from bigdl_tpu.parallel import compat
             cache, rep = P(None, None, None, "model"), P()
             if self.paged:
-                admit = compat.shard_map(
+                admit = jax.shard_map(
                     admit, mesh=mesh, in_specs=(rep,) * 26,
                     out_specs=(rep,) * 14)
-                retire = compat.shard_map(
+                retire = jax.shard_map(
                     retire, mesh=mesh, in_specs=(rep,) * 3,
                     out_specs=(rep, rep))
             else:
-                admit = compat.shard_map(
+                admit = jax.shard_map(
                     admit, mesh=mesh,
                     in_specs=((cache, cache),) + (rep,) * 21,
                     out_specs=((cache, cache),) + (rep,) * 12)
-                retire = compat.shard_map(retire, mesh=mesh,
-                                          in_specs=(rep, rep),
-                                          out_specs=rep)
+                retire = jax.shard_map(retire, mesh=mesh,
+                                       in_specs=(rep, rep),
+                                       out_specs=rep)
         self._admit_fn = xcache.tracked_jit(
             admit, ("decode_admit_" + kind, fp, B, n_pos) + key_tail,
             mesh=mesh)
@@ -854,7 +869,6 @@ class ContinuousDecoder:
                 return tuple(c.at[:, pid].set(p)
                              for c, p in zip(caches, payload))
             if self.tp > 1:
-                from bigdl_tpu.parallel import compat
                 cache, rep = P(None, None, None, "model"), P()
                 # payload dims mirror a page slice: values (L, ps, H,
                 # hd), scales (L, ps, H) — the head dim shards exactly
@@ -863,7 +877,7 @@ class ContinuousDecoder:
                     (P(None, None, "model", None) if i < 2
                      else P(None, None, "model"))
                     for i in range(n_caches))
-                readmit = compat.shard_map(
+                readmit = jax.shard_map(
                     readmit, mesh=mesh,
                     in_specs=((cache,) * n_caches, rep, pay),
                     out_specs=(cache,) * n_caches)
@@ -872,7 +886,9 @@ class ContinuousDecoder:
                 ("decode_readmit_" + kind, fp, B, n_pos) + key_tail,
                 mesh=mesh)
 
-        z = jnp.zeros
+        def z(shape, dtype):
+            return jnp.zeros(shape, dtype, device=device)
+
         if self.kv_quant == "int8":
             # int8 pools + per-page-row per-head scale arrays; a fresh
             # page's stale rows are never read before their overwrite
@@ -906,7 +922,7 @@ class ContinuousDecoder:
             self._ptab = z((B, self.pages_per_slot), jnp.int32)
             # capacity starts at one page so clips/masks stay in range
             # for never-admitted slots; admit sets the real value
-            self._cap = jnp.full((B,), ps, jnp.int32)
+            self._cap = jnp.full((B,), ps, jnp.int32, device=device)
         if k:
             self._acc_hist = z((k + 1,), jnp.int32)
             self._acc_seen = np.zeros((k + 1,), np.int64)
@@ -1053,7 +1069,8 @@ class ContinuousDecoder:
         # decoder's name so close()'s drop_series reclaims them
         from bigdl_tpu.obs import ledger as obs_ledger
         self._step_flops = obs_ledger.get().flops_for(self._step.fn_key)
-        self._peak_flops = obs_ledger.device_peak_flops()
+        # None on the CPU: no utilization gauge there
+        self._peak_flops = obs_ledger.device_peak_flops(self.device)
         self._util_t_last = time.perf_counter()
         obs_ledger.note_tenant(
             "kv_pool", sum(obs_ledger.tree_nbytes(c)
@@ -1727,7 +1744,7 @@ class ContinuousDecoder:
         if wall <= 0:
             return
         self._m_toks.set(tokens / wall)
-        if self._step_flops:
+        if self._step_flops and self._peak_flops:
             self._m_util.set(self._step_flops * self.sync_interval
                              / (wall * self._peak_flops))
 

@@ -173,11 +173,15 @@ class ServeEngine:
                  max_wait_ms: float | None = None, policy=None,
                  input_shape=None, input_dtype=np.float32,
                  max_queue: int | None = None, name: str | None = None,
-                 quant: str | None = None, calibration=None):
+                 quant: str | None = None, calibration=None,
+                 device=None):
         import jax
 
         self.model = model
         self.name = name or f"engine{next(_ENGINE_SEQ)}"
+        #: the jax device this engine's weights, inputs and executables
+        #: are pinned to (None = jax's default device)
+        self.device = device
         self.max_batch = (max_batch_default() if max_batch is None
                           else max(1, int(max_batch)))
         self.max_wait_s = (max_wait_ms_default() if max_wait_ms is None
@@ -205,8 +209,9 @@ class ServeEngine:
         # (params, state) swap as ONE tuple so a refresh/commit racing
         # the compute thread can never pair new params with old state —
         # the half-swap audit tests/test_serve.py holds refresh() to
-        self._weights = (jax.device_put(self._capture(model.params())),
-                         jax.device_put(model.state()))
+        self._weights = (
+            jax.device_put(self._capture(model.params()), device),
+            jax.device_put(model.state(), device))
         self.weights_version = 0
         self._staged = None      # (version, (params, state)) or None
         self._prev_weights = None  # one-deep history for revert_weights
@@ -351,9 +356,9 @@ class ServeEngine:
     def warmup(self, row_shape: tuple, row_dtype=np.float32):
         """Pre-lower-and-compile EVERY bucket for rows of ``row_shape``.
 
-        Rides the persistent XLA compilation cache (``bench.py`` proves
-        1.15 s cold -> 0.01 s warm across processes), so a restarted
-        server re-warms from disk, not from the compiler.  Idempotent;
+        Rides the persistent XLA compilation cache where the process
+        enabled it (``utils.engine.enable_compile_cache``), so a
+        restarted server re-warms from disk, not from the compiler.  Idempotent;
         returns the number of fresh compiles."""
         import jax
 
@@ -471,7 +476,8 @@ class ServeEngine:
                 raise ValueError(
                     f"staged param leaf {np.shape(new)} {_dt(new)} does "
                     f"not match the served {np.shape(old)} {_dt(old)}")
-        staged = (jax.device_put(params), jax.device_put(state))
+        staged = (jax.device_put(params, self.device),
+                  jax.device_put(state, self.device))
         with self._lock:
             if version is None:
                 version = self.weights_version + 1
@@ -722,7 +728,7 @@ class ServeEngine:
             reqs, xs, bucket, n = item
             try:
                 self._chaos_h2d()
-                xdev = jax.device_put(xs)
+                xdev = jax.device_put(xs, self.device)
             except BaseException as e:
                 self._fail(reqs, e)
                 continue

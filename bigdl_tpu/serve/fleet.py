@@ -966,6 +966,7 @@ class DecodeFleet(DynamicMembership):
         self._scale_lock = threading.RLock()
         self._warming = 0
         self._next_decode = 0
+        self._placed = itertools.count()   # in-process replicas built
         if replicas is None:
             if model is None and replica_factory is None:
                 raise ValueError("DecodeFleet needs a model, replicas, "
@@ -1061,9 +1062,12 @@ class DecodeFleet(DynamicMembership):
                 self._model, name=name,
                 env=env if env is not None else self._decode_env,
                 host_mb=self._host_mb, **self._decoder_kwargs)
+        kw = dict(self._decoder_kwargs)
+        if kw.get("mesh") is None and "device" not in kw:
+            kw["device"] = cluster_ops.next_local_device(self._placed)
+            logger.info("decode replica %s on %s", name, kw["device"])
         return DecodeReplica(self._model, name=name,
-                             host_mb=self._host_mb,
-                             **self._decoder_kwargs)
+                             host_mb=self._host_mb, **kw)
 
     # membership()/_update_membership()/remove_replica()/
     # start_autoscaler() come from DynamicMembership — only the decode

@@ -65,13 +65,14 @@ from concurrent.futures import Future
 import numpy as np
 
 from bigdl_tpu.serve.cluster import (_EXC_TYPES, _STDERR_LINES,
-                                     ReplicaSpawnError)
+                                     ReplicaSpawnError, child_process_env)
 from bigdl_tpu.serve.frames import FrameProtocolError
 from bigdl_tpu.serve.frames import read_frame as _read_frame
 from bigdl_tpu.serve.frames import read_welcome, write_hello
 from bigdl_tpu.serve.frames import write_frame as _write_frame
 from bigdl_tpu.serve.router import DeadReplicaError
 from bigdl_tpu.serve.streaming import StreamFuture, TokenDelivery
+from bigdl_tpu.utils.engine import CHECKOUT
 
 logger = logging.getLogger("bigdl_tpu.serve")
 
@@ -787,22 +788,14 @@ def spawn_agent(host: str = "127.0.0.1", port: int = 0, token=None,
     """Spawn ``python -m tools.replica_agent`` on a loopback port and
     wait for its ``AGENT_PORT=<n>`` banner.  Returns the
     :class:`AgentHandle` whose ``.addr`` a RemoteReplica dials."""
-    child_env = dict(os.environ)
-    from bigdl_tpu.obs import events as obs_events
-    child_env.pop(obs_events.ENV_DIR, None)
-    repo_root = os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    child_env["PYTHONPATH"] = (repo_root + os.pathsep
-                               + child_env.get("PYTHONPATH", ""))
-    if token is not None:
-        child_env[ENV_TOKEN] = str(token)
-    if env:
-        child_env.update(env)
+    overrides = {} if token is None else {ENV_TOKEN: str(token)}
+    overrides.update(env or {})
+    child_env = child_process_env(overrides)
     proc = subprocess.Popen(
         [sys.executable, "-m", "tools.replica_agent",
          "--host", host, "--port", str(port)],
         stdin=subprocess.DEVNULL, stdout=subprocess.PIPE,
-        stderr=subprocess.PIPE, env=child_env, cwd=repo_root)
+        stderr=subprocess.PIPE, env=child_env, cwd=CHECKOUT)
     handle = AgentHandle(proc, host, port)
     deadline = time.monotonic() + spawn_timeout
     killer = threading.Timer(spawn_timeout, proc.kill)

@@ -74,22 +74,23 @@ def _mesh_key(mesh):
             tuple(int(d.id) for d in mesh.devices.flat))
 
 
-def _leaf_sharding(leaf):
+def _leaf_sharding(leaf, default_device):
     """Sharding component of one leaf's key: None for host numpy,
-    ShapeDtypeStructs and single-device jax arrays (those interconvert
-    freely — an AOT executable commits host inputs to its device), a
-    distinguishing string for MULTI-device shardings (an executable
-    lowered against mesh-sharded operands rejects differently-placed
-    inputs, so those must never collide with the single-device entry)."""
+    ShapeDtypeStructs and jax arrays on the default device (those
+    interconvert freely — an AOT executable commits host inputs to its
+    device); the device id for an array on ANOTHER single device (an
+    executable is built for one device, so replicas placed one per chip
+    each own their entries); a distinguishing string for MULTI-device
+    shardings (an executable lowered against mesh-sharded operands
+    rejects differently-placed inputs)."""
     s = getattr(leaf, "sharding", None)
     if s is None:
         return None
-    try:
-        if len(s.device_set) <= 1:
-            return None
+    devices = s.device_set
+    if len(devices) > 1:
         return str(s)
-    except Exception:  # pragma: no cover - exotic sharding objects
-        return None
+    (device,) = devices
+    return None if device == default_device else int(device.id)
 
 
 def _shapes_key(args):
@@ -98,12 +99,13 @@ def _shapes_key(args):
     import jax
 
     out = []
+    default_device = jax.devices()[0]
     for leaf in jax.tree_util.tree_leaves(args):
         dt = getattr(leaf, "dtype", None)
         if dt is None:
             dt = np.asarray(leaf).dtype
         out.append((tuple(np.shape(leaf)), str(dt),
-                    _leaf_sharding(leaf)))
+                    _leaf_sharding(leaf, default_device)))
     return tuple(out)
 
 

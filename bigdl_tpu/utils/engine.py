@@ -20,24 +20,31 @@ import numpy as np
 import jax
 
 
-def set_cpu_device_count(n: int):
-    """Pin ``n`` virtual CPU devices (must run before the first backend
-    touch).  Newer jax exposes ``jax_num_cpu_devices``; older jaxlibs only
-    read ``--xla_force_host_platform_device_count`` from XLA_FLAGS at
-    backend init — route through whichever this build supports so the
-    no-cluster test meshes (conftest, multiproc workers, BIGDL_CPU_MESH)
-    work on both."""
-    n = max(int(n), 1)
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        import re
-        opt = "--xla_force_host_platform_device_count"
-        # replace, don't append: a subprocess inherits its parent's flag
-        # (the 8-device test mesh) and must still be able to pin its own
-        flags = re.sub(opt + r"=\d+", "",
-                       os.environ.get("XLA_FLAGS", "")).strip()
-        os.environ["XLA_FLAGS"] = f"{flags} {opt}={n}".strip()
+#: the checkout that holds this package: where the compile cache lives
+#: unless the environment places it elsewhere, and what worker processes
+#: get on their PYTHONPATH
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.  Entry points (smoke, bench, profilers, perf
+    CLIs, serving workers, the test suite) call this once before their
+    first compile, so a second process pays a cache read, not a compile.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    no directory is set in code; otherwise the cache is
+    ``<checkout>/.xla_cache`` — a fixed path, because the path is part
+    of the cache key and a directory that moves never hits.  Either way
+    the size/time thresholds drop to zero so every program is cached."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(CHECKOUT, ".xla_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
 
 
 class _Engine:

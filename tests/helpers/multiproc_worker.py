@@ -103,19 +103,8 @@ def main():
 
     import jax
     jax.config.update("jax_platforms", "cpu")
-    from bigdl_tpu.utils.engine import set_cpu_device_count
-    set_cpu_device_count(2)
+    jax.config.update("jax_num_cpu_devices", 2)
     jax.config.update("jax_default_matmul_precision", "highest")
-    if nproc > 1:
-        try:
-            # older jax: multi-process CPU collectives need gloo selected
-            # explicitly ("Multiprocess computations aren't implemented
-            # on the CPU backend" otherwise; with one process the gloo
-            # factory instead crashes on the absent distributed client);
-            # newer jax defaults sensibly and dropped the knob
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except AttributeError:
-            pass
 
     import os
     os.environ["BIGDL_CHECK_SINGLETON"] = "0"

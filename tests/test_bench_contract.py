@@ -58,91 +58,125 @@ def test_every_config_builds_and_traces(bench):
         bt.set_policy(bt.FP32)
 
 
+_ENTRY = {"config": "Inception-v1 x", "unit": "images/sec", "value": 3000.0,
+          "step_time_ms": 42.0, "mfu": 0.14, "device": "TPU v5 lite"}
+
+
 def test_summary_line_contract(bench):
-    line = bench._summary_line(
-        [{"config": "Inception-v1 x", "unit": "images/sec", "value": 3000.0,
-          "step_time_ms": 42.0, "mfu": 0.14}],
-        None, 186.9, "TPU v5e")
-    d = json.loads(line)
+    d = json.loads(bench._summary_line([_ENTRY], _ENTRY, 186.9))
     for key in ("metric", "value", "unit", "vs_baseline"):
         assert key in d, key
     assert d["value"] == 3000.0
-
-
-def test_summary_line_survives_empty(bench):
-    d = json.loads(bench._summary_line([], None, None, "unknown"))
-    assert d["value"] == 0 and "vs_baseline" in d
-
-
-def test_roofline_sidecar_roundtrip(bench, tmp_path, monkeypatch):
-    """VERDICT r3 item 4: the artifact must never ship a null roofline —
-    a last-good sidecar backs the in-band and standalone probes."""
-    monkeypatch.setattr(bench, "_ROOFLINE_SIDECAR",
-                        str(tmp_path / "roof.json"))
-    # no sidecar file yet (fresh workspace): the committed last-good
-    # default answers, so the artifact is self-interpreting from run one
-    c0 = bench._load_roofline_sidecar("TPU v5 lite")
-    assert c0 == bench._ROOFLINE_LAST_GOOD
-    bench._save_roofline_sidecar(186.9, "TPU v5 lite")
-    c = bench._load_roofline_sidecar("TPU v5 lite")
-    assert c["roofline_tflops"] == 186.9
-    assert c["device"] == "TPU v5 lite"
-    assert "measured_at" in c
-    # the chip-match guard now lives INSIDE the loader (ADVICE r4): a
-    # different chip cannot be contextualized by this sidecar ...
-    assert bench._load_roofline_sidecar("TPU v6e") is None
-    # ... but an unknown run device still accepts the last-good entry
-    assert bench._load_roofline_sidecar("unknown") == c
-
-
-def test_summary_line_self_interpreting_without_probe(bench):
-    """Device comes from the config entries when the probe line never
-    arrived; roofline_source says 'unavailable' instead of silently
-    shipping null context."""
-    line = bench._summary_line(
-        [{"config": "Inception-v1 x", "unit": "images/sec", "value": 3.0,
-          "step_time_ms": 42.0, "mfu": 0.14, "device": "TPU v5 lite"}],
-        None, None, "unknown", "measured",
-        {"records_per_sec": 9000.0, "top1": 0.1})
-    d = json.loads(line)
+    assert d["vs_baseline"] == round(0.14 / 0.4, 4)
     assert d["detail"]["device"] == "TPU v5 lite"
-    assert d["detail"]["roofline_source"] == "unavailable"
-    assert d["detail"]["eval"]["records_per_sec"] == 9000.0
+    assert d["detail"]["measured_matmul_roofline_tflops"] == 186.9
 
 
-def test_subprocess_timeout_salvages_printed_entries(tmp_path, monkeypatch):
-    """A child that wedges AFTER printing a config entry (e.g. in the
-    in-band roofline probe) must not cost the measured config: the
-    timeout handler parses the captured partial stdout."""
+def test_summary_without_mfu_claims_no_baseline_ratio(bench):
+    """A run with no MFU (the CPU has no datasheet peak) reports none —
+    it used to print vs_baseline 1.0, i.e. "exactly on target"."""
+    cpu = dict(_ENTRY, mfu=None, device="cpu")
+    d = json.loads(bench._summary_line([cpu], cpu, None))
+    assert d["vs_baseline"] is None and d["detail"]["mfu"] is None
+    # an unmeasured roofline is null, never a number from another run
+    assert d["detail"]["measured_matmul_roofline_tflops"] is None
+
+
+def test_summary_line_carries_trimmed_eval(bench):
+    d = json.loads(bench._summary_line(
+        [_ENTRY], _ENTRY, None,
+        {"records_per_sec": 9000.0, "top1": 0.1, "config": "dropped"}))
+    assert d["detail"]["eval"] == {"records_per_sec": 9000.0, "top1": 0.1}
+
+
+def _fake_child(tmp_path, monkeypatch, body):
+    """Point ``bench._subprocess_json`` at a scripted child."""
+    import importlib
     import textwrap
     import bench as b
-    import importlib
     importlib.reload(b)
     fake = tmp_path / "fake_child.py"
-    fake.write_text(textwrap.dedent("""
-        import json, time
-        print(json.dumps({"config": "Inception-v1 fake", "value": 1.0}),
-              flush=True)
-        time.sleep(600)
-    """))
+    fake.write_text(textwrap.dedent(body))
     real = b.os.path.abspath(b.__file__)
     orig = b.os.path.abspath
     monkeypatch.setattr(
         b.os.path, "abspath",
         lambda p: str(fake) if orig(p) == real else orig(p))
     monkeypatch.setattr(b, "_BENCH_DEADLINE", b.time.monotonic() + 600)
+    return b
+
+
+def test_subprocess_timeout_keeps_printed_entries_and_fails(
+        tmp_path, monkeypatch):
+    """A child that hangs AFTER printing a config entry keeps the
+    measured entry — and the config still counts as failed."""
+    b = _fake_child(tmp_path, monkeypatch, """
+        import json, time
+        print(json.dumps({"config": "Inception-v1 fake", "value": 1.0}),
+              flush=True)
+        time.sleep(600)
+    """)
     # 20s: the child prints immediately then sleeps 600 — the timeout only
     # needs to cover interpreter startup, which can stretch under a loaded
     # host (this test once flaked at 3s while a bench ran concurrently)
-    out = b._subprocess_json("x", timeout_s=20, retries=0)
+    out, err = b._subprocess_json("x", timeout_s=20)
     assert out and out[0]["config"] == "Inception-v1 fake"
+    assert err and "timed out" in err
+
+
+@pytest.mark.parametrize("body,reason", [
+    ("import sys; print('boom', file=sys.stderr); sys.exit(3)",
+     "exit code 3"),
+    ("pass", "printed no result"),
+])
+def test_subprocess_failure_is_reported_not_retried(
+        tmp_path, monkeypatch, body, reason):
+    b = _fake_child(tmp_path, monkeypatch, body)
+    out, err = b._subprocess_json("x", timeout_s=60)
+    assert out == [] and reason in err
+
+
+def test_failed_config_exits_nonzero_and_names_it(bench, monkeypatch,
+                                                  capsys):
+    """The old bench printed ``value: 0`` and exited 0 when nothing was
+    measured.  Now a failed config is named on stderr, the exit code is
+    non-zero, and a run without its headline prints no summary line."""
+    monkeypatch.setattr(bench.sys, "argv", ["bench.py"])
+    monkeypatch.setattr(
+        bench, "_subprocess_json",
+        lambda arg, timeout_s: ([], "exit code 1: no chip"))
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code == 1
+    out, err = capsys.readouterr()
+    assert "metric" not in out
+    assert "'inception' failed: exit code 1: no chip" in err
+
+
+def test_one_failed_config_fails_a_run_with_a_headline(bench, monkeypatch,
+                                                       capsys):
+    def child(arg, timeout_s):
+        if arg == "inception":
+            return [dict(_ENTRY, config="Inception-v1 bs128")], None
+        return [], "timed out after 300s"
+
+    monkeypatch.setattr(bench.sys, "argv", ["bench.py"])
+    monkeypatch.setattr(bench, "_subprocess_json", child)
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code == 1
+    out, err = capsys.readouterr()
+    # the measured headline is still reported ...
+    assert json.loads(out.strip().splitlines()[-1])["value"] == 3000.0
+    # ... and every failed config is named
+    assert "'resnet' failed: timed out" in err
 
 
 def test_summary_line_fits_driver_tail_window(bench):
     """VERDICT r5 weak 1 (BENCH_r05 ``parsed: null``): the driver keeps
     only the last ~2000 bytes of stdout, so the FULLY-POPULATED summary
     — six configs with real-length names, bands, flops, losses, plus the
-    eval block with real_data — must serialize under 2000 bytes.  The
+    eval block — must serialize under 2000 bytes.  The
     full per-config detail now rides the per-config lines main()
     re-emits; the summary carries a config/value/mfu digest only."""
     names = [
@@ -165,20 +199,15 @@ def test_summary_line_fits_driver_tail_window(bench):
         "top1": 0.0, "top5": 0.0,
         "config": "Inception-v1 bs128 (ImageNet eval forward)",
         "unit": "images/sec",
-        "real_data": {"top1": 1.0, "top5": 1.0, "n_records": 7,
-                      "n_classes": 2, "loss": 0.000658,
-                      "iterations": 120,
-                      "dataset": "reference-shipped CIFAR PNG folders"},
     }
-    line = bench._summary_line(entries, entries[2], 186.9, "TPU v5 lite",
-                               "measured", eval_entry)
+    line = bench._summary_line(entries, entries[2], 186.9, eval_entry)
     assert len(line.encode()) < 2000, (len(line.encode()), line)
     d = json.loads(line)
     assert d["vs_baseline"] == round(0.2133 / 0.4, 4)
     assert len(d["detail"]["configs"]) == 6
     # the digest keeps each config addressable in the per-config lines
     assert {c["config"] for c in d["detail"]["configs"]} == set(names)
-    assert d["detail"]["eval"]["real_data"]["top1"] == 1.0
+    assert d["detail"]["eval"]["records_per_sec"] == 9925.15
     # headline keys the driver greps for
     for key in ("metric", "value", "unit", "vs_baseline"):
         assert key in d, key

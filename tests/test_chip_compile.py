@@ -1,0 +1,281 @@
+"""Ask the chip's compiler, without the chip (on-chip-measurement guide §2).
+
+The TPU compiler is installed in the sandbox and compiles for a chip that is
+described, not attached.  These tests compile — never run — the Mosaic
+kernels and step programs of the main paths at the shapes ``chip_smoke.py``
+drives them at, so a block shape the tiling refuses, a kernel that outgrows
+VMEM or a primitive Mosaic cannot lower fails here, on the CPU, at no chip
+time.  Interpret-mode tests (tests/test_pallas_ops.py,
+tests/test_paged_attention.py) hold the math; nothing here checks a value.
+
+The topology is described inside a module-scoped fixture that skips when it
+cannot be: only one process may hold the TPU library, so nothing in this
+file touches it at import or collection time, and every test of the file
+runs in the one worker that was handed the file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from bigdl_tpu.ops import pallas_kernels as pk
+
+pytestmark = pytest.mark.perf
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described (not attached) v5e 2x2 host.  The persistent compile
+    cache is off for the module: an executable compiled for a described
+    chip is written to it but cannot be read back without the chip.  And
+    matmul precision is jax's default, as on the chip, not the
+    full-f32 the suite's conftest pins for CPU value tests (Mosaic has
+    no f32-precision contraction of bf16 operands)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    cache = jax.config.jax_enable_compilation_cache
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache)
+    jax.config.update("jax_default_matmul_precision", precision)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_if_on_tpu(monkeypatch):
+    """Code that asks the backend still sees the CPU here; the whole-step
+    tests steer its kernel gates the way the chip would."""
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+
+
+def compile_for(sharding, fn, *shapes):
+    """Compile ``fn`` for the described chip; returns the optimized HLO."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def grad_of(fn, argnums):
+    return jax.grad(lambda *a: fn(*a).astype(jnp.float32).sum(),
+                    argnums=argnums)
+
+
+# the recurrent flagship's shapes: batch 128, T=500, hidden 128
+T, B, H = 500, 128, 128
+F32, BF16, I8, I32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
+
+RECURRENCES = {
+    "bilstm": (lambda *a: pk.bilstm_recurrence(*a, False, 1),
+               lambda d, dt: [((T, d, B, 4 * H), dt), ((d, H, 4 * H), dt)]),
+    "gru": (lambda *a: pk.gru_recurrence(*a, False, 1),
+            lambda d, dt: [((T, d, B, 2 * H), dt), ((T, d, B, H), dt),
+                           ((d, H, 2 * H), dt), ((d, H, H), dt)]),
+    "rnn": (lambda *a: pk.rnn_recurrence(*a, False, 1),
+            lambda d, dt: [((T, d, B, H), dt), ((d, H, H), dt)]),
+}
+
+
+@pytest.mark.parametrize("directions", [1, 2])
+@pytest.mark.parametrize("cell,dtype", [("bilstm", F32), ("bilstm", BF16),
+                                        ("gru", F32), ("rnn", F32)])
+@pytest.mark.parametrize("mode", ["fwd", "grad"])
+def test_recurrence_kernels(one_chip, cell, dtype, directions, mode):
+    fn, shapes = RECURRENCES[cell]
+    shapes = shapes(directions, dtype)
+    if mode == "grad":
+        fn = grad_of(fn, tuple(range(len(shapes))))
+    assert "tpu_custom_call" in compile_for(one_chip, fn, *shapes)
+
+
+def test_fused_sgd_multi_mb_vector(one_chip):
+    n = 7_000_003        # ragged: exercises the pad-to-block path
+    fn = functools.partial(pk._fused_sgd_flat, interpret=False,
+                           nesterov=False)
+    text = compile_for(one_chip, fn, ((n,), F32), ((n,), F32), ((n,), F32),
+                       ((4,), F32))
+    assert "tpu_custom_call" in text
+
+
+# Inception-v1's max pools at batch 128: the four 3x3/s2 ceil-mode pools
+# and the 3x3/s1 pool inside the inception modules
+POOLS = [((128, 64, 112, 112), (2, 2), ((0, 1), (0, 1))),
+         ((128, 192, 56, 56), (2, 2), ((0, 1), (0, 1))),
+         ((128, 480, 28, 28), (2, 2), ((0, 1), (0, 1))),
+         ((128, 832, 14, 14), (2, 2), ((0, 1), (0, 1))),
+         ((128, 192, 28, 28), (1, 1), ((1, 1), (1, 1)))]
+
+
+@pytest.mark.parametrize("shape,strides,pads", POOLS,
+                         ids=[f"{s[1]}x{s[2]}s{st[0]}" for s, st, _ in POOLS])
+@pytest.mark.parametrize("mode", ["fwd", "grad"])
+def test_mosaic_maxpool(one_chip, shape, strides, pads, mode):
+    def fn(x):
+        return pk.mosaic_maxpool2d(x, (3, 3), strides, pads, False)
+    if mode == "grad":
+        fn = grad_of(fn, 0)
+    # bf16: the dtype the pool runs in under the bf16-compute policy
+    assert "tpu_custom_call" in compile_for(one_chip, fn, (shape, BF16))
+
+
+@pytest.mark.parametrize("shape", [(128, 64, 56, 56), (128, 192, 56, 56)],
+                         ids=["c64", "c192"])
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("mode", ["fwd", "grad"])
+def test_lrn_channel(one_chip, shape, dtype, mode):
+    """Inception's two LRNs.  The C=192 backward used to ask for 21 MB of
+    the 16 MB scoped VMEM (a whole 192 x 3136 image per block)."""
+    def fn(x):
+        return pk.lrn_channel(x, 5, 1e-4, 0.75, 1.0, False)
+    if mode == "grad":
+        fn = grad_of(fn, 0)
+    assert "tpu_custom_call" in compile_for(one_chip, fn, (shape, dtype))
+
+
+# the decode kernels at the widest LM geometry: 4 heads x 256, 8 slots,
+# 288 positions of context in pages of 16 (18 pages a slot)
+SLOTS, HEADS, HD, PAGE, PAGES_PER_SLOT = 8, 4, 256, 16, 18
+N_PAGES = SLOTS * PAGES_PER_SLOT
+
+
+def paged_shapes(window, quantized):
+    pool = ((N_PAGES, PAGE, HEADS, HD), I8 if quantized else F32)
+    shapes = [((SLOTS, window, HEADS, HD), F32), pool, pool,
+              ((SLOTS, PAGES_PER_SLOT), I32), ((SLOTS, window), I32)]
+    if quantized:
+        shapes += [((N_PAGES, PAGE, HEADS), F32)] * 2
+    return shapes
+
+
+@pytest.mark.parametrize("kernel,window", [(pk.paged_attention, 1),
+                                           (pk.paged_spec_verify, 5)],
+                         ids=["paged_attention", "paged_spec_verify"])
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("precision", ["default", "highest"])
+def test_paged_attention_kernels(one_chip, kernel, window, quantized,
+                                 precision):
+    """PR 16's two kernels blocked ``pos`` one row at a time and K/V one
+    head at a time — block shapes the Mosaic tiling refuses, so only the
+    interpreter had ever taken them.  ``highest`` is what the smoke's
+    token-parity phase decodes under."""
+    fn = functools.partial(kernel, interpret=False)
+    with jax.default_matmul_precision(precision):
+        text = compile_for(one_chip, fn, *paged_shapes(window, quantized))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "mosaic"])
+def test_whole_decode_step_d1024(one_chip, as_if_on_tpu, monkeypatch,
+                                 kernels):
+    """One paged decode step of the widest LM the repo supports (d_model
+    1024, 4 heads, FFN 4096, 6 layers, vocab 4096), weights as arguments
+    like the tensor-parallel step takes them."""
+    from bigdl_tpu.models import transformer as tf
+    from bigdl_tpu.utils.random import set_seed
+
+    monkeypatch.setattr(tf, "_PALLAS_PAGED_ATTN", kernels)
+    set_seed(1)
+    lm = tf.TransformerLM(vocab_size=4096, d_model=1024, n_heads=HEADS,
+                          n_layers=6, hidden=4096, dropout=0.0)
+    handles = tf._lm_handles(lm)
+    weights = {"emb": handles.emb, "blocks": handles.blocks,
+               "ln_f": handles.ln_f, "head": handles.head}
+    pe = jnp.asarray(lm.modules[1].table(PAGES_PER_SLOT * PAGE))
+    pool = ((6, N_PAGES, PAGE, HEADS, HD), F32)
+
+    def step(weights, tok, pos, kpool, vpool, ptab):
+        logp, _ = tf._lm_forward_window(
+            tok, pos, (kpool, vpool), handles._replace(**weights), pe,
+            (ptab, PAGE))
+        return logp
+
+    abstract = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        weights)
+    others = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+              for shape, dtype in (((SLOTS, 1), I32), ((SLOTS, 1), I32),
+                                   pool, pool,
+                                   ((SLOTS, PAGES_PER_SLOT), I32))]
+    with jax.default_matmul_precision("highest"):   # as the smoke decodes
+        text = jax.jit(step).lower(abstract, *others).compile().as_text()
+    assert ("tpu_custom_call" in text) == kernels
+
+
+def test_paged_attention_sharded_over_four_chips(topo):
+    """The tensor-parallel decoder's layout on the described 2x2 host: heads
+    split over a 4-wide ``model`` axis, the kernel inside ``jax.shard_map``
+    with the vma check ON (its out_shape carries the operands' vma), one
+    psum merging the shards."""
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    heads = P(None, None, "model", None)
+    specs = (heads, heads, heads, P(), P())
+
+    def local(q, kpool, vpool, ptab, pos):
+        out = pk.paged_attention(q, kpool, vpool, ptab, pos,
+                                 interpret=False)
+        return jax.lax.psum(out.sum(axis=(2, 3)), "model")
+
+    sharded = jax.shard_map(local, mesh=mesh, in_specs=specs,
+                            out_specs=P())
+    args = [jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=NamedSharding(mesh, spec))
+            for (shape, dtype), spec in zip(paged_shapes(1, False), specs)]
+    compiled = jax.jit(sharded).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    # each chip holds its quarter of the pool, not a copy of it
+    per_chip = compiled.memory_analysis().argument_size_in_bytes
+    pool_bytes = 2 * N_PAGES * PAGE * HEADS * HD * 4
+    assert per_chip < pool_bytes / 2
+
+
+@pytest.mark.parametrize("batch", [1, 4, 8])
+@pytest.mark.parametrize("mode", ["eval", "train"])
+def test_conv_then_lrn_compiles_at_small_batch(one_chip, batch, mode):
+    """Inception's conv2 -> ReLU -> LRN.  Below batch 8 the TPU compiler's
+    space-to-batch rewrite of the conv ran into the LRN's channel-window
+    sum: the serving buckets 1/2/4 failed to compile ("Binary op with
+    incompatible shapes") and a small-batch training step aborted the
+    compiler.  nn/normalization.py now fences the window sum there."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu import tensor as bt
+    from bigdl_tpu.nn.module import Context
+
+    model = nn.Sequential(
+        nn.SpatialConvolution(64, 192, 3, 3, 1, 1, 1, 1), nn.ReLU(True),
+        nn.SpatialCrossMapLRN(5, 0.0001, 0.75))
+    params, state = model.params(), model.state()
+    training = mode == "train"
+
+    def forward(p, x):
+        y, _ = model.apply(p, x, state, Context(
+            training=training, key=jax.random.PRNGKey(0)))
+        return y
+
+    fn = grad_of(forward, 0) if training else forward
+    abstract = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        params)
+    x = jax.ShapeDtypeStruct((batch, 64, 56, 56), F32, sharding=one_chip)
+    before = bt.policy()
+    bt.set_policy(bt.BF16_COMPUTE)
+    try:
+        jax.jit(fn).lower(abstract, x).compile()
+    finally:
+        bt.set_policy(before)

@@ -8,7 +8,7 @@ Ref-optimizer oracle pattern, RefLocalOptimizer.scala:30), ring attention.
 import numpy as np
 import jax
 
-from bigdl_tpu.parallel.compat import shard_map
+from jax import shard_map
 import jax.numpy as jnp
 import pytest
 from functools import partial

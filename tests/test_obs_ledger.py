@@ -125,13 +125,6 @@ class TestCapture:
         assert entry.flops > 0
         assert entry.peak_bytes is None   # lowering-only capture
 
-    def test_cost_normalizer_accepts_list_and_dict(self):
-        assert obs_ledger._cost_dict(
-            [{"flops": 5.0}])["flops"] == 5.0
-        assert obs_ledger._cost_dict({"flops": 7.0})["flops"] == 7.0
-        assert obs_ledger._cost_dict(None) == {}
-        assert obs_ledger._cost_dict([]) == {}
-
     def test_master_switch_disables_capture(self, monkeypatch):
         import jax.numpy as jnp
 
@@ -172,8 +165,24 @@ class TestCapture:
 # live train MFU + the warm-path/cadence audit
 # ---------------------------------------------------------------------------
 
+@pytest.fixture
+def v5e_peak(monkeypatch):
+    """The CPU publishes no MFU (no datasheet peak), so the gauge tests
+    run as if the step were on a v5e: same code path, a real peak."""
+    monkeypatch.setattr(obs_ledger, "device_peak_flops",
+                        lambda device=None: obs_ledger.PEAK_FLOPS["TPU v5e"])
+
+
 class TestTrainMFU:
-    def test_windowed_gauges_finite_after_run(self):
+    def test_no_mfu_gauge_on_cpu(self):
+        """A CPU run has a step wall time but no utilization."""
+        _opt(steps=5).optimize()
+        snap = obs_metrics.get().snapshot()
+        assert not snap.get("train_mfu", {}).get("series")
+        assert obs_metrics.family_total(
+            snap, "train_step_wall_seconds", optimizer="local") > 0
+
+    def test_windowed_gauges_finite_after_run(self, v5e_peak):
         _opt(steps=5).optimize()
         snap = obs_metrics.get().snapshot()
         mfu = obs_metrics.family_total(snap, "train_mfu",
@@ -195,7 +204,8 @@ class TestTrainMFU:
         assert led["captures"] == xs["compiles"] > 0
         assert xs["hits"] >= 8      # the warm dispatches that captured 0
 
-    def test_mfu_gauge_set_at_flush_cadence_only(self, monkeypatch):
+    def test_mfu_gauge_set_at_flush_cadence_only(self, monkeypatch,
+                                                 v5e_peak):
         """Cadence audit: the train_mfu gauge is written once per host-
         sync window flush, never per step."""
         reg = obs_metrics.get()
@@ -235,24 +245,21 @@ name, build, recs, unit, aflops, n_disp = next(
 rate, step_ms, mfu, flops, loss, band, fetch = b.bench_config(
     build, recs, warmup=1, iters=1, windows=1, steps_per_dispatch=2)
 entry = obs_ledger.get().newest(("bench_chunk", recs, 2))
-ledger_mfu = (entry.flops / (step_ms / 1e3)
-              / obs_ledger.device_peak_flops(jax.devices()[0])
-              if entry else None)
-print(json.dumps({"mfu": mfu, "flops": flops,
-                  "entry_flops": entry.flops if entry else None,
-                  "ledger_mfu": ledger_mfu}))
+print(json.dumps({"mfu": mfu, "flops": flops, "step_ms": step_ms,
+                  "entry_flops": entry.flops if entry else None}))
 """
 
 
 class TestBenchCrossCheck:
-    def test_bench_mfu_matches_ledger_within_1pct(self):
-        """ISSUE 13 acceptance: bench.py's MFU and the MFU re-derived
-        from the ledger entry it captured agree within 1%.  Both
-        resolve flops through CostLedger.capture_compiled and peak
-        through device_peak_flops, so a divergence means a second cost
-        probe crept back in.  Runs in a subprocess like the real bench
-        CLI — bench_config's donated-buffer warmup is not safe inside
-        the suite's persistent-compile-cache process."""
+    def test_bench_flops_are_the_ledger_entry_and_cpu_has_no_mfu(self):
+        """ISSUE 13 acceptance, on a machine without a chip: bench.py's
+        per-step flops ARE the ledger entry it captured (one cost code
+        path — a divergence means a second cost probe crept back in),
+        and on the CPU, which has no datasheet peak, bench reports no
+        MFU instead of dividing by another device's.  Runs in a
+        subprocess like the real bench CLI — bench_config's
+        donated-buffer warmup is not safe inside the suite's
+        persistent-compile-cache process."""
         import subprocess
         import sys
 
@@ -263,10 +270,9 @@ class TestBenchCrossCheck:
             capture_output=True, text=True, timeout=300)
         assert out.returncode == 0, out.stderr[-2000:]
         res = json.loads(out.stdout.strip().splitlines()[-1])
-        assert res["mfu"] is not None and res["mfu"] > 0, \
-            "bench MFU must be finite via the ledger's normalizer"
         assert res["entry_flops"] == res["flops"] > 0
-        assert abs(res["ledger_mfu"] - res["mfu"]) <= 0.01 * res["mfu"]
+        assert res["step_ms"] > 0
+        assert res["mfu"] is None
 
 
 # ---------------------------------------------------------------------------
@@ -413,7 +419,7 @@ class TestTenants:
 # ---------------------------------------------------------------------------
 
 class TestDecodeUtilization:
-    def test_util_gauges_published_per_boundary(self):
+    def test_util_gauges_published_per_boundary(self, v5e_peak):
         from bigdl_tpu.models.transformer import TransformerLM, lm_decode
         from bigdl_tpu.serve.decode import ContinuousDecoder
         set_seed(1)

@@ -54,6 +54,23 @@ def _serial_fwd(model):
     return lambda x: np.asarray(fwd(x))
 
 
+def _serial_fwd_at(model, x, max_batch):
+    """The oracle at the SERVED batch shapes: ``x`` in chunks of
+    ``max_batch`` rows, each zero-padded to its bucket and trimmed —
+    what an engine does whose batches close on size (callers give it a
+    wait long enough that timing cannot split a batch).  XLA's CPU
+    backend may differ by one ulp between batch shapes, so an exact
+    comparison needs the reference computed at the shape served."""
+    fwd = _serial_fwd(model)
+    out = []
+    for i in range(0, len(x), max_batch):
+        chunk = x[i:i + max_batch]
+        padded, n = bucketing.pad_rows(
+            chunk, bucketing.bucket_for(len(chunk), max_batch))
+        out.append(fwd(padded)[:n])
+    return np.concatenate(out)
+
+
 class TestBucketing:
     def test_ladder(self):
         assert bucket_sizes(1) == (1,)
@@ -194,8 +211,8 @@ class TestServeEngine:
         try:
             x = np.random.RandomState(0).randn(5, 4).astype(np.float32)
             bad = np.full((4,), np.nan, np.float32)
-            ref = _serial_fwd(model)(x)
-            with ServeEngine(model, max_batch=8, max_wait_ms=20,
+            ref = _serial_fwd_at(model, x, 8)   # 5 good rows -> bucket 8
+            with ServeEngine(model, max_batch=8, max_wait_ms=300,
                              input_shape=(4,)) as eng:
                 futs = eng.submit_many(list(x[:3]) + [bad] + list(x[3:]))
                 with pytest.raises(PoisonedRequestError):
@@ -576,7 +593,9 @@ class TestPolicyDrift:
 class TestPredictorRegression:
     """First-ever regression coverage for the Predictor surface."""
 
-    def test_partial_batch_trim(self):
+    def test_partial_batch_trim(self, monkeypatch):
+        # batches close on size 8, the 4-row tail on this deadline
+        monkeypatch.setenv("BIGDL_SERVE_MAX_WAIT_MS", "300")
         model = _small_model()
         x = np.random.RandomState(0).randn(20, 4).astype(np.float32)
         pred = __import__("bigdl_tpu.optim.predictor",
@@ -585,7 +604,7 @@ class TestPredictorRegression:
         try:
             out = pred.predict(x)
             assert out.shape == (20, 3)           # tail trimmed, not padded
-            assert np.array_equal(out, _serial_fwd(model)(x))
+            assert np.array_equal(out, _serial_fwd_at(model, x, 8))
         finally:
             pred.close()
 

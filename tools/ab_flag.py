@@ -10,9 +10,9 @@ _sys.path.insert(0, _REPO); _sys.path.insert(0, _os.path.join(_REPO, "tools"))
 
 def main():
     from bigdl_tpu import tensor as bt
-    import bench
     from ab_device_clock import build_chunk, device_us_per_step
-    bench._enable_compile_cache()
+    from bigdl_tpu.utils.engine import enable_compile_cache
+    enable_compile_cache()
     bt.set_policy(getattr(bt, _os.environ.get("BIGDL_POLICY", "BF16_COMPUTE")))
     model_name, batch = _sys.argv[1], int(_sys.argv[2])
     mod, attr = importlib.import_module(_sys.argv[3]), _sys.argv[4]

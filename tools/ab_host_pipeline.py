@@ -4,8 +4,8 @@ The device clock (tools/ab_device_clock.py) cannot see this change: the
 prefetch pipeline and the cadenced host sync move work OFF the critical
 path of the host loop, so the instrument is per-step WALL time of the
 real ``LocalOptimizer.optimize`` loop over a real transformer-chain
-dataset — the quantity the relay's 80-120 ms sync round-trip and the
-serial Transformer chain were inflating (PERF_NOTES r1).
+dataset — the quantity a per-step blocking host sync and the serial
+Transformer chain inflate (PERF_NOTES r1).
 
 Staged for the on-chip run (host-side overlap is provable on CPU — see
 tests/test_prefetch.py::TestOverlap — so adoption is not gated on it):

@@ -1319,7 +1319,13 @@ def main():
                     help="fleet replica wire for the fleet sweep: "
                          "in-process threads, stdio subprocess "
                          "workers, or TCP-loopback replica agents "
-                         "(docs/serving.md 'Cross-host fleet')")
+                         "(docs/serving.md 'Cross-host fleet').  This "
+                         "process computes the oracle on its own jax "
+                         "runtime, and a chip belongs to one process: "
+                         "on a TPU the subprocess transports are "
+                         "refused (ReplicaSpawnError) unless "
+                         "BIGDL_SERVE_WORKER_PLATFORM sends the "
+                         "workers elsewhere")
     ap.add_argument("--traffic", action="store_true",
                     help="open-loop bursty/diurnal traffic run: seeded "
                          "Poisson arrivals with a declared burst "
@@ -1370,6 +1376,8 @@ def main():
                     help="fail unless batched >= 2x serial throughput")
     args = ap.parse_args()
     args.loads = [float(tok) for tok in str(args.loads).split(",") if tok]
+    from bigdl_tpu.utils.engine import enable_compile_cache
+    enable_compile_cache()
     from bigdl_tpu import quant as _quant
     if args.quant is None:
         args.quant = _quant.weight_mode_default()

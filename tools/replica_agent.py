@@ -474,15 +474,8 @@ def main(argv=None) -> int:
                         help="exit after the first session closes")
     args = parser.parse_args(argv)
 
-    import jax
-    platform = os.environ.get("BIGDL_SERVE_WORKER_PLATFORM", "cpu")
-    jax.config.update("jax_platforms", platform)
-    if platform == "cpu":
-        from bigdl_tpu.utils.engine import set_cpu_device_count
-        set_cpu_device_count(
-            int(os.environ.get("BIGDL_SERVE_WORKER_DEVICES", "1")))
-        jax.config.update("jax_default_matmul_precision", "highest")
-    os.environ.setdefault("BIGDL_CHECK_SINGLETON", "0")
+    from bigdl_tpu.serve.cluster import init_worker_runtime
+    init_worker_runtime()
 
     try:
         agent = ReplicaAgent(host=args.host, port=args.port,
