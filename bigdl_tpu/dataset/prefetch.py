@@ -27,8 +27,16 @@ loop ran the whole Transformer chain on the main thread inside the
 - ``to_device`` adds a second stage: a transfer thread double-buffers
   batches onto the device (the optimizer passes its own
   ``_device_put_batch``, so local, sharded and multi-host layouts all
-  overlap H2D with compute).  Its wall time is credited to the ``h2d``
-  span via :meth:`PipelineRunner.take_h2d_seconds`.
+  overlap H2D with compute).
+- Telemetry is taken where the work happens and drained by the consuming
+  loop through :meth:`PipelineRunner.take_spans`: the wall of every draw
+  (``data-load/fetch``), the self time of the source and of each
+  transformer stage inside it (``data-load/fetch/source:<DataSet>``,
+  ``data-load/fetch/stage/<i>:<Stage>``) and the wall of every
+  ``to_device`` call (``h2d/prefetch``).  The draw and the transfer each
+  run under a ``TraceAnnotation`` of that name, one name per thread, so a
+  profiler trace shows them on the device's clock.  Only work is
+  annotated: queue waits and whole iterations carry no span.
 - Checkpoint/resume: every produced item carries the stream snapshot
   taken right after its draws.  :meth:`rng_snapshot` splices the snapshot
   of the last CONSUMED item with the live device-key counter, so a resume
@@ -57,6 +65,7 @@ from collections import deque
 
 import numpy as np
 
+from bigdl_tpu.utils.profiler import annotation
 from bigdl_tpu.utils.random import RNG
 
 logger = logging.getLogger("bigdl_tpu.dataset")
@@ -66,6 +75,12 @@ ENV_SYNC_EVERY_STEP = "BIGDL_SYNC_EVERY_STEP"
 ENV_WORKERS = "BIGDL_PREFETCH_WORKERS"
 
 DEFAULT_DEPTH = 2
+
+#: span paths of the two background threads (docs/observability.md "host
+#: pipeline"); each is also the name of the thread's ``TraceAnnotation``,
+#: and is used on that thread only, so a trace reader finds the thread by it
+FETCH = "data-load/fetch"       # producer: one draw of the transformer chain
+H2D = "h2d/prefetch"            # transfer thread: one ``to_device`` call
 
 
 def enabled() -> bool:
@@ -179,6 +194,30 @@ def _decompose(dataset):
     return dataset, stages
 
 
+class _Timed:
+    """Iterator wrapper that adds the time spent inside its upstream's
+    ``next()`` to ``clock[0]`` (inclusive of everything further up; the
+    runner subtracts the upstream's clock to get a stage's self time).
+    Two ``perf_counter`` reads and one float add per element, no lock and
+    no profiler event: the producer thread alone touches the clocks."""
+
+    __slots__ = ("_it", "_clock")
+
+    def __init__(self, it, clock):
+        self._it = iter(it)
+        self._clock = clock
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        t0 = time.perf_counter()
+        try:
+            return next(self._it)
+        finally:
+            self._clock[0] += time.perf_counter() - t0
+
+
 def _is_pure_map(stage) -> bool:
     """A stage eligible for worker fan-out: declared 1-to-1 per record
     (``pure_per_record``) and free of RNG draws (not ``stochastic``)."""
@@ -229,14 +268,11 @@ class PipelineRunner:
         self._closed = False
         self._count = 0          # records into the current epoch
         self._pool = None
-        self._split = None       # (base, pure_prefix, rest) when fanning out
+        base, stages = _decompose(dataset)
+        n_prefix = 0             # leading stages fanned out over the pool
         if self._train and self._n_workers > 0:
-            base, stages = _decompose(dataset)
-            prefix = []
-            i = 0
-            while i < len(stages) and _is_pure_map(stages[i]):
-                prefix.append(stages[i])
-                i += 1
+            while n_prefix < len(stages) and _is_pure_map(stages[n_prefix]):
+                n_prefix += 1
             # records per base-iterator cycle: the looped iterator draws
             # its shuffle permutation at each cycle start, so the
             # fan-out window must drain before crossing a boundary or
@@ -254,29 +290,40 @@ class PipelineRunner:
                 cycle = base.size()
             else:
                 cycle = None
-                if prefix:
+                if n_prefix:
                     logger.info(
                         "prefetch worker fan-out disabled: %s has no "
                         "knowable shuffle-cycle length, so read-ahead "
                         "could reorder its RNG draws",
                         type(base).__name__)
-            if prefix and cycle:
+            if n_prefix and cycle:
                 from concurrent.futures import ThreadPoolExecutor
                 self._pool = ThreadPoolExecutor(
                     max_workers=self._n_workers,
                     thread_name_prefix="bigdl-prefetch-worker")
                 self._cycle = cycle
-                self._split = (base, prefix, stages[i:])
+            else:
+                n_prefix = 0
+        # the chain as the producer builds it, link by link: the source,
+        # the fanned-out prefix as one link (if any), then each stage
+        self._base = base
+        self._prefix = stages[:n_prefix]
+        self._rest = stages[n_prefix:]
+        names = ["source:" + type(base).__name__]
+        if n_prefix:
+            names.append("stage/0:" + "+".join(
+                type(s).__name__ for s in self._prefix))
+        names += [f"stage/{n_prefix + k}:{type(s).__name__}"
+                  for k, s in enumerate(self._rest)]
+        self._link_paths = [FETCH + "/" + n for n in names]
+        self._clocks = [[0.0] for _ in names]   # see _Timed
 
         # telemetry drained by the consuming loop
         self.consumed = 0
         self.produced = 0
         self.epochs_rolled = 0
         self.stall_seconds = 0.0
-        self._h2d_seconds = 0.0
-        self._h2d_count = 0
-        self._fetch_seconds = 0.0
-        self._fetch_count = 0
+        self._spans = {}         # path -> [seconds, count], see take_spans
 
         self._start_snap = RNG.snapshot() if self._own_rng else None
         self._last_rng = None    # snapshot of the last CONSUMED item
@@ -295,13 +342,33 @@ class PipelineRunner:
 
     # -- producer side -----------------------------------------------------
     def _make_iter(self):
-        if self._split is None:
-            return self._dataset.data(train=self._train)
-        base, prefix, rest = self._split
-        it = self._parallel_map(base.data(train=self._train), prefix)
-        for stage in rest:
-            it = stage(it)
+        """``dataset.data(train)`` built link by link (``TransformedDataSet
+        .data`` is exactly ``transformer(base.data(train))``, so the draws
+        and the RNG stream are the same), each link under its clock."""
+        clocks = iter(self._clocks)
+        it = _Timed(self._base.data(train=self._train), next(clocks))
+        if self._prefix:
+            it = _Timed(self._parallel_map(it, self._prefix), next(clocks))
+        for stage in self._rest:
+            it = _Timed(stage(it), next(clocks))
         return it
+
+    def _book(self, *entries):
+        """Add one booking of ``seconds`` to each ``(path, seconds)``."""
+        with self._stats_lock:
+            for path, seconds in entries:
+                acc = self._spans.setdefault(path, [0.0, 0])
+                acc[0] += seconds
+                acc[1] += 1
+
+    def _book_draw(self, wall):
+        """One draw is done: its wall, and each link's self time (time
+        inside its ``next()`` minus time inside its upstream's)."""
+        entries, upstream = [(FETCH, wall)], 0.0
+        for path, clock in zip(self._link_paths, self._clocks):
+            entries.append((path, clock[0] - upstream))
+            upstream, clock[0] = clock[0], 0.0
+        self._book(*entries)
 
     def _parallel_map(self, records, prefix):
         """Ordered fan-out of the pure per-record stage prefix across the
@@ -383,25 +450,24 @@ class PipelineRunner:
                     if self._pause.is_set():  # re-check under the lock
                         continue
                     t0 = time.perf_counter()
-                    if self._chunk <= 1:
-                        try:
-                            b = next(self._it)
-                        except StopIteration:
-                            self._put(self._host_q, _END)
-                            return
-                        x, y = b.data, b.labels
-                        records = int(np.asarray(x).shape[0])
-                    else:
-                        x, y = stack_chunk(
-                            [next(self._it) for _ in range(self._chunk)])
-                        records = int(x.shape[0] * x.shape[1])
-                    self._advance_epoch(records * self._records_scale)
-                    snap = RNG.snapshot() if self._own_rng else None
+                    with annotation(FETCH):
+                        if self._chunk <= 1:
+                            try:
+                                b = next(self._it)
+                            except StopIteration:
+                                self._put(self._host_q, _END)
+                                return
+                            x, y = b.data, b.labels
+                            records = int(np.asarray(x).shape[0])
+                        else:
+                            x, y = stack_chunk(
+                                [next(self._it) for _ in range(self._chunk)])
+                            records = int(x.shape[0] * x.shape[1])
+                        self._advance_epoch(records * self._records_scale)
+                        snap = RNG.snapshot() if self._own_rng else None
                     wall = time.perf_counter() - t0
                 item = Item(x, y, rng=snap, seq=seq, fetch_wall=wall)
-                with self._stats_lock:
-                    self._fetch_seconds += wall
-                    self._fetch_count += 1
+                self._book_draw(wall)
                 if not self._put(self._host_q, item):
                     return
                 self.produced += 1
@@ -431,11 +497,9 @@ class PipelineRunner:
                 return
             try:
                 t0 = time.perf_counter()
-                item.device = self._to_device(item.x, item.y)
-                dt = time.perf_counter() - t0
-                with self._stats_lock:
-                    self._h2d_seconds += dt
-                    self._h2d_count += 1
+                with annotation(H2D):
+                    item.device = self._to_device(item.x, item.y)
+                self._book((H2D, time.perf_counter() - t0))
             except BaseException as e:
                 self._put(self._out_q, _Error(e))
                 return
@@ -468,21 +532,15 @@ class PipelineRunner:
             except StopIteration:
                 return
 
-    def take_h2d(self):
-        """Drain the transfer thread's accumulated (seconds, batches) —
-        credited to the ``h2d`` span by the consuming loop."""
+    def take_spans(self):
+        """Drain what the two threads booked since the last call:
+        ``{span path: (seconds, count)}`` — ``data-load/fetch`` (the wall
+        of each draw), its links' self times and ``h2d/prefetch`` (the
+        wall of each ``to_device`` call).  The consuming loop credits them
+        to its span tree."""
         with self._stats_lock:
-            out = (self._h2d_seconds, self._h2d_count)
-            self._h2d_seconds, self._h2d_count = 0.0, 0
-        return out
-
-    def take_fetch(self):
-        """Drain the producer's accumulated (seconds, batches) of
-        transform-chain wall — the ``data-load/fetch`` span."""
-        with self._stats_lock:
-            out = (self._fetch_seconds, self._fetch_count)
-            self._fetch_seconds, self._fetch_count = 0.0, 0
-        return out
+            out, self._spans = self._spans, {}
+        return {path: (sec, n) for path, (sec, n) in out.items()}
 
     def rng_snapshot(self) -> dict:
         """Host-stream state as of the last CONSUMED batch, with the

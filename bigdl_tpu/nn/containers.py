@@ -9,6 +9,7 @@ MapTable, Bottle (Bottle.scala).  Recurrent/TimeDistributed live in
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from bigdl_tpu.nn.module import Container, Module
@@ -16,9 +17,15 @@ from bigdl_tpu.utils.table import Table
 
 
 def _child_apply(container, i, params, x, state, ctx):
+    """The one place a container calls a child through.  The child's class
+    name goes into the ``op_name`` of every operation it traces (forward
+    as ``jvp(..)/SpatialMaxPooling/reduce_window_max``, backward under
+    ``transpose(jvp(..))/..``): metadata only, the compiled instructions
+    are the same, and a profile can be summed by module kind."""
     name = str(i)
     m = container.modules[i]
-    y, ns = m.apply(params[name], x, state[name], ctx)
+    with jax.named_scope(type(m).__name__):
+        y, ns = m.apply(params[name], x, state[name], ctx)
     return y, ns
 
 
@@ -94,7 +101,9 @@ class Concat(Container):
         heads = [params[str(i)]["0"]["~"] for i in plan]
         w = jnp.concatenate([h["weight"] for h in heads], axis=0)
         b = jnp.concatenate([h["bias"] for h in heads], axis=0)
-        merged = bias_add(_conv(x, w, (1, 1), [(0, 0), (0, 0)]), b)
+        # the heads' own scope, as if each had run through _child_apply
+        with jax.named_scope("SpatialConvolution"):
+            merged = bias_add(_conv(x, w, (1, 1), [(0, 0), (0, 0)]), b)
         sizes = [h["weight"].shape[0] for h in heads]
         offs = np.cumsum([0] + sizes)
         slices = {i: merged[:, offs[k]:offs[k + 1]]
@@ -108,10 +117,11 @@ class Concat(Container):
                 bparams, bstate = params[str(i)], state[str(i)]
                 y = slices[i]
                 ns = dict(bstate)
-                for j in range(1, len(br.modules)):
-                    y, s_j = br.modules[j].apply(bparams[str(j)], y,
-                                                 bstate[str(j)], ctx)
-                    ns[str(j)] = s_j
+                with jax.named_scope(type(br).__name__):
+                    for j in range(1, len(br.modules)):
+                        y, s_j = _child_apply(br, j, bparams, y, bstate,
+                                              ctx)
+                        ns[str(j)] = s_j
             else:
                 y, ns = _child_apply(self, i, params, x, state, ctx)
             outs.append(y)

@@ -28,9 +28,12 @@ from contextlib import contextmanager
 #: is the host→device batch transfer (inline, or credited from the
 #: prefetch transfer thread via :meth:`SpanTracker.record`); ``host-wait``
 #: is the cadence-boundary device→host sync the loops pay instead of a
-#: per-step ``float(loss)`` (docs/observability.md "host pipeline").
-PHASES = ("data-load", "h2d", "dispatch", "host-wait", "aggregate",
-          "validate", "checkpoint")
+#: per-step ``float(loss)``; ``flush`` is the host work that follows it
+#: (logging, the finite ledger, step events, gauges) and ``bookkeep`` the
+#: loop's own counters, epoch rollover and trigger probes
+#: (docs/observability.md "host pipeline").
+PHASES = ("data-load", "h2d", "dispatch", "host-wait", "flush", "bookkeep",
+          "aggregate", "validate", "checkpoint")
 
 _PREFIX = "span: "
 

@@ -1287,16 +1287,17 @@ class DistriOptimizer(LocalOptimizer):
             self._elastic_offer(params, net_state, opt_state, state, count)
 
         try:
+            # the end trigger stays outside every span and outside the
+            # ``loop`` counter (see LocalOptimizer.optimize)
             while not self.end_when(state):
+                fetch_start = time.perf_counter()   # the iteration's top
                 if self._elastic is not None:
                     elastic_mod.check()   # raises PeerLossRecovery on trip
                 neval0 = int(state["neval"])
                 epoch0 = int(state["epoch"])
                 self._window.arm()
-                fetch_start = time.perf_counter()
                 dev = qdepth = None
-                with self.spans.span("data-load"), \
-                        self.metrics.timer("data fetch time"):
+                with self.spans.span("data-load"):
                     if pipeline is not None:
                         # the span measures the CONSUMER's wait only; the
                         # producer's transform wall rides data-load/fetch
@@ -1334,6 +1335,8 @@ class DistriOptimizer(LocalOptimizer):
                         # iteration rejected: batch consumed, no update, no
                         # neval advance (ref DistriOptimizer.scala:224 guard)
                         straggler.reject(drop_mask)
+                        self.spans.record(
+                            "loop", time.perf_counter() - fetch_start)
                         continue
 
                 # distributed: summary() adds the per-process breakdown,
@@ -1383,21 +1386,23 @@ class DistriOptimizer(LocalOptimizer):
                         # stackSize, accumulateCount += recordsNum :236)
                         global_b = int(global_b * float(drop_mask.sum())
                                        / len(drop_mask))
-                count += global_b
-                state["neval"] = neval0 + n_disp
-                state["evalCounter"] = state.get("evalCounter", 0) + n_disp
-                extra = {}
-                if n_dropped:
-                    extra["straggler_dropped"] = n_dropped
-                if qdepth is not None:
-                    extra["queue_depth"] = int(qdepth)
-                self._window.push(_PendingStep(
-                    neval0, epoch0, count, loss, finite, taps, lr,
-                    global_b, fetch_wall, train_time, extra))
-
-                rolled = count >= epoch_size
-                count, data_iter = self._advance_epochs(
-                    state, count, epoch_size, n_disp, data_iter, pipeline)
+                with self.spans.span("bookkeep"):
+                    count += global_b
+                    state["neval"] = neval0 + n_disp
+                    state["evalCounter"] = \
+                        state.get("evalCounter", 0) + n_disp
+                    extra = {}
+                    if n_dropped:
+                        extra["straggler_dropped"] = n_dropped
+                    if qdepth is not None:
+                        extra["queue_depth"] = int(qdepth)
+                    self._window.push(_PendingStep(
+                        neval0, epoch0, count, loss, finite, taps, lr,
+                        global_b, fetch_wall, train_time, extra))
+                    rolled = count >= epoch_size
+                    count, data_iter = self._advance_epochs(
+                        state, count, epoch_size, n_disp, data_iter,
+                        pipeline)
                 if self._elastic is not None and \
                         neval0 % self._elastic["cadence"] == 0:
                     # consistent post-step snapshot (post-rollover: the
@@ -1407,11 +1412,12 @@ class DistriOptimizer(LocalOptimizer):
                 if self._window.due() or rolled:
                     self._flush_window(state, monitor,
                                        "epoch" if rolled else "cadence")
-                ne_val = self._fired_within(self.validation_trigger, state,
-                                            n_disp)
-                ne_ck = self._fired_within(self.checkpoint_trigger, state,
-                                           n_disp)
-                preempt = self._preemption_pending()
+                with self.spans.span("bookkeep"):
+                    ne_val = self._fired_within(self.validation_trigger,
+                                                state, n_disp)
+                    ne_ck = self._fired_within(self.checkpoint_trigger,
+                                               state, n_disp)
+                    preempt = self._preemption_pending()
                 if preempt or ne_val is not None or ne_ck is not None:
                     self._flush_window(state, monitor,
                                        "preempt" if preempt else "trigger")
@@ -1425,8 +1431,13 @@ class DistriOptimizer(LocalOptimizer):
                 if preempt:
                     self._checkpoint_and_stop(params, net_state, opt_state,
                                               state)
+                self.spans.record("loop", time.perf_counter() - fetch_start)
+                if preempt:
                     break
+            flush_start = time.perf_counter()
             self._flush_window(state, monitor, "run-end")
+            self.spans.record("loop", time.perf_counter() - flush_start,
+                              count=0)
         finally:
             try:
                 # see LocalOptimizer.optimize: crash-adjacent steps must

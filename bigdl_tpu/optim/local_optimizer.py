@@ -338,7 +338,13 @@ class LocalOptimizer:
         remat = self.remat
         taps_on = obs_taps.enabled(self._taps_enabled)
 
-        def step(params, net_state, opt_state, x, y, lr, key, lr_scales):
+        # XLA names the module after this function, and the name is part
+        # of the persistent compile cache's key while op metadata (the
+        # scopes below, the stack frames) is not: an executable cached by
+        # a commit whose step carried other scopes is served with THAT
+        # commit's names.  The step got its scopes under this name.
+        def train_step(params, net_state, opt_state, x, y, lr, key,
+                       lr_scales):
             hyper = dict(static_hyper, lr=lr)
             if has_scales:
                 hyper["lr_scales"] = lr_scales
@@ -355,15 +361,22 @@ class LocalOptimizer:
                 return criterion.apply_loss(out, y), ns
 
             (loss, new_net_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
-            finite = _finite_all(loss, grads)
-            new_params, new_opt_state = method.update(grads, opt_state, params, hyper)
-            new_params = _where_finite(finite, new_params, params)
-            new_opt_state = _where_finite(finite, new_opt_state, opt_state)
-            new_net_state = _where_finite(finite, new_net_state, net_state)
+            # the scopes name the update's and the taps' operations in a
+            # profile (metadata only, as the module scopes of nn/containers)
+            with jax.named_scope("optim-update"):
+                finite = _finite_all(loss, grads)
+                new_params, new_opt_state = method.update(
+                    grads, opt_state, params, hyper)
+                new_params = _where_finite(finite, new_params, params)
+                new_opt_state = _where_finite(finite, new_opt_state,
+                                              opt_state)
+                new_net_state = _where_finite(finite, new_net_state,
+                                              net_state)
             # in-jit taps: extra outputs of the SAME dispatch, post-skip-
             # select so update_ratio reads 0 on a skipped step
-            taps = (obs_taps.compute(grads, params, new_params)
-                    if taps_on else {})
+            with jax.named_scope("obs-taps"):
+                taps = (obs_taps.compute(grads, params, new_params)
+                        if taps_on else {})
             return (new_params, new_net_state, new_opt_state, loss, finite,
                     taps)
 
@@ -378,9 +391,10 @@ class LocalOptimizer:
                   type(self.optim_method).__name__)
         n = self.iters_per_dispatch
         if n <= 1:
-            return xcache.tracked_jit(step, fn_key, key_argnums=(3, 4),
+            return xcache.tracked_jit(train_step, fn_key,
+                                      key_argnums=(3, 4),
                                       donate_argnums=(0, 1, 2))
-        return xcache.tracked_jit(self._scan_chunk(step, n),
+        return xcache.tracked_jit(self._scan_chunk(train_step, n),
                                   fn_key + ("chunk%d" % n,),
                                   key_argnums=(3, 4),
                                   donate_argnums=(0, 1, 2))
@@ -461,14 +475,16 @@ class LocalOptimizer:
 
     def _drain_pipeline_obs(self, pipeline, item, waited, neval0):
         """Book the background threads' telemetry onto the main-thread
-        spans/events: producer fetch + H2D walls, and a prefetch_stall
-        event when the queue failed to hide the fetch."""
-        sec, n = pipeline.take_h2d()
-        if n:
-            self.spans.record("h2d", sec, count=n)
-        sec, n = pipeline.take_fetch()
-        if n:
-            self.spans.record("data-load/fetch", sec, count=n)
+        spans/events: the producer's draws with their per-stage self
+        times and the transfer thread's ``h2d/prefetch``, and a
+        prefetch_stall event when the queue failed to hide the fetch.
+        The transfers are credited to the top-level ``h2d`` phase as
+        well, which the per-host table shows."""
+        drained = pipeline.take_spans()
+        for path, (sec, n) in drained.items():
+            self.spans.record(path, sec, count=n)
+        if prefetch_mod.H2D in drained:
+            self.spans.record("h2d", *drained[prefetch_mod.H2D])
         if waited > 0.01 and item.seq >= pipeline.depth:
             obs_events.emit("prefetch_stall", step=int(neval0),
                             seconds=round(waited, 6),
@@ -476,37 +492,40 @@ class LocalOptimizer:
 
     def _flush_window(self, state, monitor, reason: str):
         """Materialize the pending window: one blocking device→host sync
-        (the ``host-wait`` span), then the per-step host work the serial
-        loop did eagerly — loss logging, the non-finite ledger, step
-        events and TensorBoard scalars.  An abort raised by the ledger is
-        deferred until every pending step's events are out."""
+        (the ``host-wait`` span), then (the ``flush`` span) the per-step
+        host work the serial loop did eagerly — loss logging, the
+        non-finite ledger, step events and TensorBoard scalars.  An abort
+        raised by the ledger is deferred until every pending step's events
+        are out."""
         w = self._window
         if w is None or not w.pending:
             return
         with self.spans.span("host-wait"):
             entries, losses, finites, wall = w.flush()
-        w.flush_steps.append(entries[-1].neval0)
-        w.flush_reasons.append(reason)
-        records = sum(e.records for e in entries)
-        rate = records / max(wall, 1e-9)
-        self._note_window_utilization(entries, wall)
-        epoch_size = self.dataset.size()
-        abort = None
-        for e, lv, fv in zip(entries, losses, finites):
-            loss_f = float(lv.reshape(-1)[-1])
-            state["loss"] = loss_f
-            logger.info(
-                "Epoch %d %d/%d loss %.6f lr %.5g throughput %.1f "
-                "records/s (fetch %.4fs dispatch %.4fs, synced %s)",
-                e.epoch, e.count, epoch_size, loss_f, e.lr, rate,
-                e.fetch_t, e.train_t, reason)
-            if abort is None:
-                try:
-                    self._note_finite(fv, state)
-                except NonFiniteGradError as exc:
-                    abort = exc  # emit the remaining step events first
-            self._emit_step_event(e.neval0, loss_f, e.lr, rate,
-                                  monitor.push(e.neval0, e.taps), **e.extra)
+        with self.spans.span("flush"):
+            w.flush_steps.append(entries[-1].neval0)
+            w.flush_reasons.append(reason)
+            records = sum(e.records for e in entries)
+            rate = records / max(wall, 1e-9)
+            self._note_window_utilization(entries, wall)
+            epoch_size = self.dataset.size()
+            abort = None
+            for e, lv, fv in zip(entries, losses, finites):
+                loss_f = float(lv.reshape(-1)[-1])
+                state["loss"] = loss_f
+                logger.info(
+                    "Epoch %d %d/%d loss %.6f lr %.5g throughput %.1f "
+                    "records/s (fetch %.4fs dispatch %.4fs, synced %s)",
+                    e.epoch, e.count, epoch_size, loss_f, e.lr, rate,
+                    e.fetch_t, e.train_t, reason)
+                if abort is None:
+                    try:
+                        self._note_finite(fv, state)
+                    except NonFiniteGradError as exc:
+                        abort = exc  # emit the remaining step events first
+                self._emit_step_event(e.neval0, loss_f, e.lr, rate,
+                                      monitor.push(e.neval0, e.taps),
+                                      **e.extra)
         if abort is not None:
             raise abort
 
@@ -580,11 +599,16 @@ class LocalOptimizer:
         wall_start = time.perf_counter()
 
         try:
+            # the user's end trigger stays the loop's condition, outside
+            # every span and outside the ``loop`` counter: a caller may
+            # read the span totals from inside it (the benchmark opens its
+            # window there), and a span open around that read would book
+            # its whole wall after the read
             while not self.end_when(state):
+                fetch_start = time.perf_counter()   # the iteration's top
                 neval0 = int(state["neval"])
                 epoch0 = int(state["epoch"])
                 self._window.arm()
-                fetch_start = time.perf_counter()
                 dev = qdepth = None
                 with self.spans.span("data-load"):
                     if pipeline is not None:
@@ -624,35 +648,38 @@ class LocalOptimizer:
                                 jnp.float32(lr), key, self._lr_scales_arg)
                 train_time = time.perf_counter() - train_start
 
-                b = x.shape[0] * x.shape[1] if n_disp > 1 else x.shape[0]
-                count += b
-                state["neval"] = neval0 + n_disp
-                state["evalCounter"] = state.get("evalCounter", 0) + n_disp
-                self.metrics.add("data fetch time", fetch_time)
-                self.metrics.add("train time", train_time)
-                extra = ({"queue_depth": int(qdepth)}
-                         if qdepth is not None else {})
-                # loss/finite/taps stay ON DEVICE; the window materializes
-                # them at the next cadence/boundary flush (no per-step
-                # device→host sync — the tentpole of this layer)
-                self._window.push(_PendingStep(
-                    neval0, epoch0, count, loss, finite, taps, lr, b,
-                    fetch_time, train_time, extra))
-
-                rolled = count >= epoch_size
-                count, data_iter = self._advance_epochs(
-                    state, count, epoch_size, n_disp, data_iter, pipeline)
+                with self.spans.span("bookkeep"):
+                    b = (x.shape[0] * x.shape[1] if n_disp > 1
+                         else x.shape[0])
+                    count += b
+                    state["neval"] = neval0 + n_disp
+                    state["evalCounter"] = \
+                        state.get("evalCounter", 0) + n_disp
+                    extra = ({"queue_depth": int(qdepth)}
+                             if qdepth is not None else {})
+                    # loss/finite/taps stay ON DEVICE; the window
+                    # materializes them at the next cadence/boundary flush
+                    # (no per-step device→host sync — the tentpole of this
+                    # layer)
+                    self._window.push(_PendingStep(
+                        neval0, epoch0, count, loss, finite, taps, lr, b,
+                        fetch_time, train_time, extra))
+                    rolled = count >= epoch_size
+                    count, data_iter = self._advance_epochs(
+                        state, count, epoch_size, n_disp, data_iter,
+                        pipeline)
                 if self._window.due() or rolled:
                     self._flush_window(state, monitor,
                                        "epoch" if rolled else "cadence")
                 # trigger predicates are host-only (no device sync); a
                 # firing one forces its own flush below so validation/
                 # checkpoint always see materialized loss + finite ledger
-                ne_val = self._fired_within(self.validation_trigger, state,
-                                            n_disp)
-                ne_ck = self._fired_within(self.checkpoint_trigger, state,
-                                           n_disp)
-                preempt = self._preemption_pending()
+                with self.spans.span("bookkeep"):
+                    ne_val = self._fired_within(self.validation_trigger,
+                                                state, n_disp)
+                    ne_ck = self._fired_within(self.checkpoint_trigger,
+                                               state, n_disp)
+                    preempt = self._preemption_pending()
                 if preempt or ne_val is not None or ne_ck is not None:
                     self._flush_window(state, monitor,
                                        "preempt" if preempt else "trigger")
@@ -666,8 +693,17 @@ class LocalOptimizer:
                 if preempt:
                     self._checkpoint_and_stop(params, net_state, opt_state,
                                               state)
+                # the iteration's wall, so that time under no span is
+                # measured (loop minus the spans above) and not inferred
+                self.spans.record("loop", time.perf_counter() - fetch_start)
+                if preempt:
                     break
+            # the closing flush belongs to the loop's wall, as its spans
+            # belong to the loop's thread (count 0: no iteration)
+            flush_start = time.perf_counter()
             self._flush_window(state, monitor, "run-end")
+            self.spans.record("loop", time.perf_counter() - flush_start,
+                              count=0)
         finally:
             try:
                 # best-effort: an exception between cadence boundaries
@@ -932,18 +968,19 @@ class LocalOptimizer:
                           or not self.validation_trigger(state)):
             return
         pipeline = self._train_pipeline
-        if pipeline is not None:
-            # hold the producer before its next draw: validation may
-            # iterate the same backing store an epoch shuffle mutates
-            pipeline.pause()
-        try:
-            with self.spans.span("validate"):
+        with self.spans.span("validate"):
+            if pipeline is not None:
+                # hold the producer before its next draw: validation may
+                # iterate the same backing store an epoch shuffle mutates
+                # (the wait for a draw in flight is validation's cost)
+                pipeline.pause()
+            try:
                 results = validate(self.model, params, net_state,
                                    self.validation_dataset,
                                    self.validation_methods)
-        finally:
-            if pipeline is not None:
-                pipeline.resume()
+            finally:
+                if pipeline is not None:
+                    pipeline.resume()
         for method, result in results:
             logger.info("%s is %s", method, result)
             val = result.result()[0]

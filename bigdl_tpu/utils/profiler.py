@@ -4,7 +4,7 @@ The reference stacks per-module wall-clock timers (AbstractModule
 forwardTime/backwardTime), phase metrics (optim/Metrics.scala) and
 throughput logs.  Those exist here too (Module.get_times, optim.Metrics);
 this module adds the TPU-native layer: ``jax.profiler`` device traces and
-annotated step ranges viewable in XProf/TensorBoard.
+annotated host ranges viewable in XProf/TensorBoard.
 """
 from __future__ import annotations
 
@@ -32,17 +32,10 @@ def trace(log_dir: str):
 
 
 @contextmanager
-def step_annotation(name: str):
-    """Annotate a host range so steps are findable in the trace viewer."""
-    with jax.profiler.StepTraceAnnotation(name):
-        yield
-
-
-@contextmanager
 def annotation(name: str):
-    """Plain named trace range (non-step): phase spans (obs/spans.py)
-    use this so data-load/dispatch/validate line up in XProf under the
-    same names as the event log."""
+    """Plain named trace range: phase spans (obs/spans.py) and the
+    prefetch threads (dataset/prefetch.py) use this so data-load/dispatch/
+    validate line up in XProf under the same names as the event log."""
     with jax.profiler.TraceAnnotation(name):
         yield
 

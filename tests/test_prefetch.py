@@ -500,6 +500,90 @@ class TestPipelineRunner:
         for a, b in zip(serial, got):
             np.testing.assert_array_equal(a, b)
 
+    @pytest.mark.parametrize("n_workers", [0, 2])
+    def test_wrapped_chain_keeps_batch_order_across_epochs(self, n_workers):
+        """The producer builds the chain link by link, each link under a
+        clock: the batches, epoch shuffles included, are those of
+        ``dataset.data()`` driven by the serial loop's arithmetic — for a
+        nested chain (``a >> (b >> c)``) and with the pure prefix fanned
+        out as one link."""
+        from bigdl_tpu.dataset.image import ImgNormalizer
+
+        def make_ds():
+            tail = ImgRdmCropper(6, 6) >> (HFlip() >> ImgToBatch(8))
+            return (DataSet.array(_grey_images(n=16))
+                    >> ImgNormalizer(0.5, 2.0) >> tail)
+
+        set_seed(19)
+        ds, serial = make_ds(), []
+        it = ds.data(train=True)
+        for k in range(6):              # 2 batches an epoch: 3 shuffles
+            serial.append(np.array(next(it).data))
+            if k % 2 == 1:
+                ds.shuffle()
+                it = ds.data(train=True)
+        set_seed(19)
+        runner = pf.PipelineRunner(make_ds(), train=True, epoch_size=16,
+                                   n_workers=n_workers)
+        got = [np.array(runner.get()[0].x) for _ in range(6)]
+        spans = runner.take_spans()
+        runner.close()
+        for a, b in zip(serial, got):
+            np.testing.assert_array_equal(a, b)
+        links = [p for p in spans if p.startswith(pf.FETCH + "/")]
+        assert pf.FETCH + "/source:LocalArrayDataSet" in links
+        assert pf.FETCH + "/stage/3:ImgToBatch" in links
+        # fanned out or not, the pure prefix is the link after the source
+        assert pf.FETCH + "/stage/0:ImgNormalizer" in links
+        assert len(links) == 5
+        assert spans[pf.FETCH][1] >= 6 and pf.H2D not in spans
+
+    def test_stage_self_times_sum_to_the_draw_and_name_the_stage(self):
+        """Each link's self time is the time inside its ``next()`` minus
+        the time inside its upstream's; over a run they sum to the draws'
+        wall (what is left is the epoch rollover and the RNG snapshot),
+        and on a chain whose cost is the stacking of records they name
+        ``SampleToBatch``."""
+        rs = np.random.RandomState(3)
+        samples = [Sample(rs.rand(3, 128, 128).astype(np.float32),
+                          np.asarray([1.0], np.float32))
+                   for _ in range(128)]
+        # a 25 MB batch: the draw's fixed costs are ~0.1 ms of several
+        ds = (DataSet.array(samples) >> FuncTransformer(lambda s: s)
+              >> SampleToBatch(128))
+        runner = pf.PipelineRunner(ds, train=True, epoch_size=128)
+        for _ in range(12):
+            runner.get()
+        spans = runner.take_spans()
+        runner.close()
+        wall, draws = spans.pop(pf.FETCH)
+        assert draws >= 12
+        assert all(n == draws for _, n in spans.values())
+        assert sorted(spans) == [
+            pf.FETCH + "/source:LocalArrayDataSet",
+            pf.FETCH + "/stage/0:FuncTransformer",
+            pf.FETCH + "/stage/1:SampleToBatch"]
+        self_total = sum(sec for sec, _ in spans.values())
+        assert self_total == pytest.approx(wall, rel=0.05)
+        assert max(spans, key=lambda p: spans[p][0]).endswith(
+            ":SampleToBatch")
+        assert all(sec >= 0 for sec, _ in spans.values())
+
+    def test_take_spans_drains_and_books_the_transfer(self):
+        ds = DataSet.array(_samples(n=32)) >> SampleToBatch(8)
+        runner = pf.PipelineRunner(
+            ds, train=True, epoch_size=32,
+            to_device=lambda x, y: (np.asarray(x), np.asarray(y)))
+        for _ in range(4):
+            runner.get()
+        first = runner.take_spans()
+        assert first[pf.H2D][1] >= 4 and first[pf.H2D][0] > 0
+        assert first[pf.FETCH][1] >= 4
+        runner.close()
+        # drained: what the threads booked before the drain is gone
+        later = runner.take_spans()
+        assert later.get(pf.H2D, (0, 0))[1] <= 3
+
     def test_close_restores_consumed_rng_state(self):
         def make_ds():
             # fresh images per pass: the croppers mutate records in
