@@ -28,12 +28,33 @@ loop ran the whole Transformer chain on the main thread inside the
   batches onto the device (the optimizer passes its own
   ``_device_put_batch``, so local, sharded and multi-host layouts all
   overlap H2D with compute).
+- Such a training runner **owns the host batch buffers**.  It borrows the
+  slots of the chain's ``SampleToBatch`` (``depth + 2`` of them: one being
+  filled, ``depth`` queued, one in transfer), lends the stage one free
+  slot per draw, and takes it back on the transfer thread once
+  ``to_device`` has returned AND the device arrays are ready
+  (``jax.block_until_ready``: the copy reads the host buffer after the
+  call returns, measured on the v5e, PERF.md PR 27); ``item.x``/``item.y``
+  are dropped then, the loop reads ``item.device``.  The producer takes
+  the slot BEFORE the draw and waits for one outside every span, clock and
+  lock (``feed/slot-wait``), so the wait is never booked as draw time; it
+  can cost a stall and never a wrong batch.  With ``depth + 2`` slots a
+  producer ahead of the device blocks on the full queue (``_put``, which
+  no span counts either) before it runs out of slots; the wait shows when
+  a transfer holds its slot for long.  Batches
+  that do not fit a slot, and every other reader of the dataset (direct
+  iteration, ``BIGDL_PREFETCH=0``, a runner without ``to_device``,
+  validation, chunked dispatch whose ``stack_chunk`` copies anyway), get
+  fresh arrays as before.  :meth:`close` hands the slots back to the
+  stage, which keeps the buffers for the next runner over the dataset.
 - Telemetry is taken where the work happens and drained by the consuming
   loop through :meth:`PipelineRunner.take_spans`: the wall of every draw
   (``data-load/fetch``), the self time of the source and of each
   transformer stage inside it (``data-load/fetch/source:<DataSet>``,
-  ``data-load/fetch/stage/<i>:<Stage>``) and the wall of every
-  ``to_device`` call (``h2d/prefetch``).  The draw and the transfer each
+  ``data-load/fetch/stage/<i>:<Stage>``), the wall of every
+  ``to_device`` call up to its arrays being ready (``h2d/prefetch``) and
+  ``feed/slot-wait`` (seconds the producer waited for a free slot; count
+  = draws assembled into a recycled slot).  The draw and the transfer each
   run under a ``TraceAnnotation`` of that name, one name per thread, so a
   profiler trace shows them on the device's clock.  Only work is
   annotated: queue waits and whole iterations carry no span.
@@ -63,6 +84,7 @@ import threading
 import time
 from collections import deque
 
+import jax
 import numpy as np
 
 from bigdl_tpu.utils.profiler import annotation
@@ -81,6 +103,10 @@ DEFAULT_DEPTH = 2
 #: and is used on that thread only, so a trace reader finds the thread by it
 FETCH = "data-load/fetch"       # producer: one draw of the transformer chain
 H2D = "h2d/prefetch"            # transfer thread: one ``to_device`` call
+#: no ``TraceAnnotation`` (a wait, not work) and outside ``data-load/fetch/``,
+#: whose sub-paths are the chain's links: seconds the producer waited for a
+#: free host slot, count = draws assembled into a recycled slot
+SLOT_WAIT = "feed/slot-wait"
 
 
 def enabled() -> bool:
@@ -150,7 +176,7 @@ class Item:
     stream snapshot taken after its draws, and fetch-side telemetry."""
 
     __slots__ = ("x", "y", "device", "rng", "seq", "fetch_wall",
-                 "queue_depth")
+                 "queue_depth", "slot")
 
     def __init__(self, x, y, rng=None, seq=0, fetch_wall=0.0):
         self.x = x
@@ -160,6 +186,7 @@ class Item:
         self.seq = seq
         self.fetch_wall = fetch_wall
         self.queue_depth = 0
+        self.slot = None         # the BatchSlot x/y live in, if recycled
 
 
 class _End:
@@ -225,6 +252,27 @@ def _is_pure_map(stage) -> bool:
         not bool(getattr(stage, "stochastic", False))
 
 
+def _reads_host(device, slot) -> bool:
+    """Whether anything ``to_device`` returned still reads the slot's
+    memory once it is ready.  A device's own memory never does; the CPU
+    backend adopts a 64-byte-aligned host array, or a contiguous shard of
+    one, without a copy, and a stub may hand back the host arrays."""
+    spans = [(b.ctypes.data, b.ctypes.data + b.nbytes)
+             for b in (slot.x, slot.y)]
+    for leaf in jax.tree_util.tree_leaves(device):
+        if isinstance(leaf, np.ndarray):
+            if any(np.may_share_memory(leaf, b) for b in (slot.x, slot.y)):
+                return True
+        elif isinstance(leaf, jax.Array):
+            for shard in leaf.addressable_shards:
+                if shard.device.platform != "cpu":
+                    continue
+                at = shard.data.unsafe_buffer_pointer()
+                if any(lo <= at < hi for lo, hi in spans):
+                    return True
+    return False
+
+
 class PipelineRunner:
     """Bounded background input pipeline over one dataset.
 
@@ -236,8 +284,9 @@ class PipelineRunner:
 
     ``to_device(xh, yh) -> (x, y)`` arms the second stage: a transfer
     thread that double-buffers batches onto the device ahead of
-    consumption.  ``own_rng`` (default: ``train``) moves the process seed
-    stream onto the producer — see the module docstring.
+    consumption; a training runner with one also owns the host batch
+    buffers (module docstring).  ``own_rng`` (default: ``train``) moves
+    the process seed stream onto the producer — see the module docstring.
     """
 
     def __init__(self, dataset, *, train: bool = True, chunk: int = 1,
@@ -309,6 +358,23 @@ class PipelineRunner:
         self._base = base
         self._prefix = stages[:n_prefix]
         self._rest = stages[n_prefix:]
+        # the host batch buffers (module docstring): borrowed from the
+        # chain's batcher where this runner copies every batch to the
+        # device itself.  A chunk is stacked into a fresh array anyway.
+        self._batcher = self._slots = None
+        self._lent = None        # the slot of the draw in progress
+        self._free = queue.Queue()
+        if train and to_device is not None and self._chunk <= 1:
+            from bigdl_tpu.dataset.transformer import SampleToBatch
+            for stage in self._rest:
+                if isinstance(stage, SampleToBatch):
+                    self._slots = stage.borrow_slots(self.depth + 2)
+                    if self._slots is not None:
+                        self._batcher = stage
+                    break
+            for slot in self._slots or ():
+                slot.filled = False
+                self._free.put(slot)
         names = ["source:" + type(base).__name__]
         if n_prefix:
             names.append("stage/0:" + "+".join(
@@ -350,24 +416,38 @@ class PipelineRunner:
         if self._prefix:
             it = _Timed(self._parallel_map(it, self._prefix), next(clocks))
         for stage in self._rest:
-            it = _Timed(stage(it), next(clocks))
+            it = _Timed(stage.assemble_into(it, self._lent_slot)
+                        if stage is self._batcher else stage(it),
+                        next(clocks))
         return it
 
+    def _lent_slot(self):
+        """The slot of the draw in progress, to the producer's thread
+        alone: a background stage downstream of the batcher (``PreFetch``)
+        pulls batches at its own pace, and those are assembled fresh."""
+        if threading.current_thread() is self._producer:
+            return self._lent
+        return None
+
     def _book(self, *entries):
-        """Add one booking of ``seconds`` to each ``(path, seconds)``."""
+        """Add ``seconds`` and ``count`` bookings to each ``(path,
+        seconds, count)``."""
         with self._stats_lock:
-            for path, seconds in entries:
+            for path, seconds, count in entries:
                 acc = self._spans.setdefault(path, [0.0, 0])
                 acc[0] += seconds
-                acc[1] += 1
+                acc[1] += count
 
-    def _book_draw(self, wall):
-        """One draw is done: its wall, and each link's self time (time
-        inside its ``next()`` minus time inside its upstream's)."""
-        entries, upstream = [(FETCH, wall)], 0.0
+    def _book_draw(self, wall, slot_wait, recycled):
+        """One draw is done: its wall, each link's self time (time inside
+        its ``next()`` minus time inside its upstream's) and, where the
+        runner lends slots, the wait for one that preceded the draw."""
+        entries, upstream = [(FETCH, wall, 1)], 0.0
         for path, clock in zip(self._link_paths, self._clocks):
-            entries.append((path, clock[0] - upstream))
+            entries.append((path, clock[0] - upstream, 1))
             upstream, clock[0] = clock[0], 0.0
+        if self._batcher is not None:
+            entries.append((SLOT_WAIT, slot_wait, int(recycled)))
         self._book(*entries)
 
     def _parallel_map(self, records, prefix):
@@ -442,14 +522,27 @@ class PipelineRunner:
                 RNG.own_seed_stream()
             self._it = self._make_iter()
             seq = 0
+            slot, slot_wait = None, 0.0
             while not self._stop.is_set():
                 if self._pause.is_set():
                     time.sleep(0.002)
                     continue
+                if self._batcher is not None and slot is None:
+                    # before the draw, outside its lock, annotation and
+                    # clocks: a wait is not work, and booked as a stage's
+                    # time it would blame the feed for the device's pace
+                    t0 = time.perf_counter()
+                    try:
+                        slot = self._free.get(timeout=0.1)
+                    except queue.Empty:
+                        continue
+                    finally:
+                        slot_wait += time.perf_counter() - t0
                 with self._work_lock:
                     if self._pause.is_set():  # re-check under the lock
                         continue
                     t0 = time.perf_counter()
+                    self._lent = slot
                     with annotation(FETCH):
                         if self._chunk <= 1:
                             try:
@@ -467,7 +560,10 @@ class PipelineRunner:
                         snap = RNG.snapshot() if self._own_rng else None
                     wall = time.perf_counter() - t0
                 item = Item(x, y, rng=snap, seq=seq, fetch_wall=wall)
-                self._book_draw(wall)
+                if slot is not None and slot.filled:
+                    item.slot, slot = slot, None    # else: kept for the next
+                self._book_draw(wall, slot_wait, item.slot is not None)
+                slot_wait = 0.0
                 if not self._put(self._host_q, item):
                     return
                 self.produced += 1
@@ -499,12 +595,29 @@ class PipelineRunner:
                 t0 = time.perf_counter()
                 with annotation(H2D):
                     item.device = self._to_device(item.x, item.y)
-                self._book((H2D, time.perf_counter() - t0))
+                    if item.slot is not None:
+                        # the copy reads the host buffer after the call
+                        # has returned (PERF.md PR 27, probe e)
+                        jax.block_until_ready(item.device)
+                self._book((H2D, time.perf_counter() - t0, 1))
+                if item.slot is not None:
+                    self._release(item)
             except BaseException as e:
                 self._put(self._out_q, _Error(e))
                 return
             if not self._put(self._out_q, item):
                 return
+
+    def _release(self, item):
+        """The transfer is over: the slot goes back to the free list and
+        the item lets go of the host arrays.  Where the device arrays ARE
+        the host memory, they keep it and the slot gets new buffers."""
+        slot, item.slot = item.slot, None
+        if _reads_host(item.device, slot):
+            slot.x = slot.y = None
+        item.x = item.y = None
+        slot.filled = False
+        self._free.put(slot)
 
     # -- consumer side -----------------------------------------------------
     def get(self):
@@ -591,5 +704,8 @@ class PipelineRunner:
             self._pool.shutdown(wait=False)
         if self._producer.is_alive():  # pragma: no cover - defensive
             logger.warning("prefetch producer did not stop within 5s")
+        if self._batcher is not None:
+            self._batcher.return_slots(self._slots)
+            self._batcher = None
         if self._own_rng and restore_rng:
             RNG.restore(self.rng_snapshot())

@@ -65,6 +65,18 @@ class FuncTransformer(Transformer):
         return (self.fn(x) for x in iterator)
 
 
+class BatchSlot:
+    """One recycled pair of host batch buffers, allocated by the first batch
+    assembled into it.  ``filled`` says that a draw was assembled into it
+    (the lender clears it when it takes the slot back)."""
+
+    __slots__ = ("x", "y", "filled")
+
+    def __init__(self):
+        self.x = self.y = None
+        self.filled = False
+
+
 class SampleToBatch(Transformer):
     """Sample -> MiniBatch with optional fixed-length padding
     (ref Transformer.scala:99-241).
@@ -74,26 +86,26 @@ class SampleToBatch(Transformer):
     (keeps one static shape for jit instead of per-batch max).
     ``partition_num``: drop the tail so every partition yields whole batches.
 
-    ``reuse_buffers=N`` (N >= 2) assembles batches into a ring of N
-    preallocated arrays instead of a fresh ``np.stack`` allocation per
-    batch — sample rows are copied straight into the slot.  A yielded
-    MiniBatch is then only valid until N-1 more batches have been drawn:
-    use it with consumers that copy promptly (the training loops convert
-    to device arrays immediately; a prefetch pipeline of depth d needs
-    ``N >= d + 2`` to cover queued + in-flight batches).  Off (0) by
-    default because collecting batches into a list is a valid use of the
-    default path.
+    **Who owns a batch's arrays.**  Called as a transformer
+    (``dataset.data()`` iterated directly, ``list(...)`` of batches, the
+    serial training loop, validation, a prefetch runner that keeps batches
+    on the host) every batch is a fresh allocation that belongs to the
+    caller, for as long as the caller likes.  A training
+    ``prefetch.PipelineRunner`` that copies batches to the device borrows
+    this stage's slots (:meth:`borrow_slots`) and draws through
+    :meth:`assemble_into`: a full batch whose rows match the slot in shape
+    and dtype is copied into that recycled buffer, which belongs to the
+    runner and is valid until its transfer to the device is over; a stage
+    downstream of this one sees the batch during the draw and must not keep
+    it beyond.  Anything else (the partial tail batch, a padded side
+    without ``fixed_length``, rows that drift in shape or dtype) is
+    assembled fresh, as above.  The buffers stay with the stage, so runners
+    that follow one another over one dataset share them.
     """
 
     def __init__(self, batch_size: int = None, feature_padding=None,
                  label_padding=None, fixed_length: int = None,
-                 drop_last: bool = False, reuse_buffers: int = 0,
-                 global_batch_size: int = None):
-        if reuse_buffers and reuse_buffers < 2:
-            raise ValueError(
-                f"reuse_buffers needs a ring of >= 2 slots, got "
-                f"{reuse_buffers} (the consumer still holds the previous "
-                "batch while the next is assembled)")
+                 drop_last: bool = False, global_batch_size: int = None):
         if (batch_size is None) == (global_batch_size is None):
             raise ValueError("pass exactly one of batch_size (per-process)"
                              " or global_batch_size (divided over the live"
@@ -111,57 +123,64 @@ class SampleToBatch(Transformer):
         self.label_padding = label_padding
         self.fixed_length = fixed_length
         self.drop_last = drop_last
-        self.reuse_buffers = int(reuse_buffers)
-        self._ring = None
-        self._ring_i = 0
+        # the slots, on the shelf while no runner has them: pop and append
+        # of a list are atomic, so two runners cannot both take them
+        self._shelf = [[]]
+        self._slot_like = None   # what the slots look like, _slot_fits
 
-    def _ring_slot(self, feats, labels):
-        """The next preallocated (feature, label) buffer pair, or None
-        when the batch doesn't fit the ring (partial tail batch, shape
-        drift) — those fall back to a fresh allocation."""
-        if not self.reuse_buffers:
+    def __getstate__(self):
+        # the slots are scratch memory, not configuration: a copy or a
+        # pickle of the stage starts with none
+        return dict(self.__dict__, _shelf=[[]], _slot_like=None)
+
+    def borrow_slots(self, n: int):
+        """The stage's ``n`` slots (made on first demand, kept with their
+        buffers from one borrower to the next), or None while another
+        borrower has them.  Give them back with :meth:`return_slots`."""
+        try:
+            slots = self._shelf.pop()
+        except IndexError:
             return None
-        if self._ring is None:
-            f0, l0 = np.asarray(feats[0]), np.asarray(labels[0])
-            # global mode: batch_size is None; size the ring from the
-            # batch being assembled (== the resolved local batch)
-            rows = (self.batch_size if self.batch_size is not None
-                    else len(feats))
+        slots.extend(BatchSlot() for _ in range(n - len(slots)))
+        return slots
+
+    def return_slots(self, slots):
+        self._shelf.append(slots)
+
+    def _slot_fits(self, slot, feats, labels):
+        """Make ``slot`` ready for this full batch, or say that the batch
+        has to be assembled fresh.  The first full batch decides what the
+        slots look like; a batch of another row count (an elastic re-form
+        changed the local batch) decides it anew."""
+        rows = len(feats)
+        like = self._slot_like      # [(shape, dtype)] of a slot's x and y
+        if like is None or like[0][0][0] != rows:
+            padded = [p is not None
+                      for p in (self.feature_padding, self.label_padding)]
             # padded sides have data-dependent dim 1 unless pinned
-            if self.feature_padding is not None:
-                if self.fixed_length is None:
-                    return None
-                fshape = (rows, self.fixed_length) + f0.shape[1:]
-            else:
-                fshape = (rows,) + f0.shape
-            if self.label_padding is not None:
-                if self.fixed_length is None:
-                    return None
-                lshape = (rows, self.fixed_length) + l0.shape[1:]
-            else:
-                lshape = (rows,) + l0.shape
-            self._ring = [
-                (np.empty(fshape, f0.dtype), np.empty(lshape, l0.dtype))
-                for _ in range(self.reuse_buffers)]
-        fbuf, lbuf = self._ring[self._ring_i]
-        if len(feats) != fbuf.shape[0] \
-                or not self._rows_fit(fbuf, feats, self.feature_padding) \
-                or not self._rows_fit(lbuf, labels, self.label_padding):
-            return None
-        self._ring_i = (self._ring_i + 1) % len(self._ring)
-        return fbuf, lbuf
+            if any(padded) and self.fixed_length is None:
+                return False
+            like = self._slot_like = [
+                ((rows, self.fixed_length) + row.shape[1:] if pad
+                 else (rows,) + row.shape, row.dtype)
+                for row, pad in zip((feats[0], labels[0]), padded)]
+        if slot.x is None \
+                or [(b.shape, b.dtype) for b in (slot.x, slot.y)] != like:
+            slot.x, slot.y = (np.empty(*side) for side in like)
+        return self._rows_fit(slot.x, feats, self.feature_padding) \
+            and self._rows_fit(slot.y, labels, self.label_padding)
 
     @staticmethod
     def _rows_fit(buf, rows, pad_value):
-        """Every row must match the buffer's row shape exactly (padded
-        sides: the trailing dims; dim 0 is clipped/padded) — a drifting
-        shape falls back to fresh allocation instead of crashing on the
-        copy or, worse, broadcasting silently into wrong data."""
-        if pad_value is None:
-            want = buf.shape[1:]
-            return all(np.shape(r) == want for r in rows)
-        want = buf.shape[2:]
-        return all(np.shape(r)[1:] == want for r in rows)
+        """Every row must match the buffer's dtype and row shape exactly
+        (padded sides: the trailing dims; dim 0 is clipped/padded) — a
+        drifting shape falls back to fresh allocation instead of crashing
+        on the copy or, worse, broadcasting silently into wrong data, and
+        a drifting dtype would be cast where ``np.stack`` promotes."""
+        skip = 0 if pad_value is None else 1
+        want = buf.shape[1 + skip:]
+        return all(r.dtype == buf.dtype and r.shape[skip:] == want
+                   for r in rows)
 
     @staticmethod
     def _fill(buf, arrays, pad_value):
@@ -174,17 +193,20 @@ class SampleToBatch(Transformer):
         buf.fill(pad_value)
         max_len = buf.shape[1]
         for i, a in enumerate(arrays):
-            n = min(np.shape(a)[0], max_len)
+            n = min(a.shape[0], max_len)
             buf[i, :n] = a[:n]
         return buf
 
-    def _assemble(self, samples):
+    def _assemble(self, samples, slot=None):
+        """One MiniBatch of ``samples``: into ``slot`` where one is lent,
+        not yet filled in this draw, and fits; else into fresh arrays."""
         feats = [s.feature for s in samples]
         labels = [s.label for s in samples]
-        slot = self._ring_slot(feats, labels)
-        if slot is not None:
-            return MiniBatch(self._fill(slot[0], feats, self.feature_padding),
-                             self._fill(slot[1], labels, self.label_padding))
+        if slot is not None and not slot.filled \
+                and self._slot_fits(slot, feats, labels):
+            slot.filled = True
+            return MiniBatch(self._fill(slot.x, feats, self.feature_padding),
+                             self._fill(slot.y, labels, self.label_padding))
         if self.feature_padding is not None:
             feats = _pad_stack(feats, self.feature_padding, self.fixed_length)
         else:
@@ -203,16 +225,18 @@ class SampleToBatch(Transformer):
         return get_batch_size(self.global_batch_size, jax.process_count())
 
     def __call__(self, iterator):
+        return self.assemble_into(iterator, lambda: None)
+
+    def assemble_into(self, iterator, lend):
+        """The batches of ``iterator``, each full one assembled into the
+        slot ``lend()`` returns when it is drawn (a ``BatchSlot`` of
+        :meth:`borrow_slots`, or None for a fresh allocation)."""
         batch = self._local_batch()
-        if self.reuse_buffers and self.global_batch_size is not None \
-                and self._ring is not None \
-                and self._ring[0][0].shape[0] != batch:
-            self._ring = None  # world changed: old slots have stale rows
         buf = []
         for s in iterator:
             buf.append(s)
             if len(buf) == batch:
-                yield self._assemble(buf)
+                yield self._assemble(buf, lend())
                 buf = []
         if buf and not self.drop_last:
             yield self._assemble(buf)

@@ -669,14 +669,32 @@ class TestDatasetReshard:
         with pytest.raises(RuntimeError, match="BIGDL_ELASTIC"):
             ds.reshard(n_shards=3, shard_index=1)
 
-    def test_global_batch_with_reuse_buffers(self):
-        # the preallocated ring must size itself from the RESOLVED local
-        # batch (batch_size is None in global mode)
+    def test_global_batch_with_lent_slots(self):
+        # the slots a prefetching runner lends must size themselves from
+        # the RESOLVED local batch (batch_size is None in global mode),
+        # and anew when a re-form changes it
+        import unittest.mock as mock
+        from bigdl_tpu.dataset import prefetch as pf
         samples = _data(n=16)
-        tb = SampleToBatch(global_batch_size=8, reuse_buffers=2)
-        batches = list(tb(iter(samples)))
-        assert [b.data.shape[0] for b in batches] == [8, 8]
-        assert tb._ring is not None
+        tb = SampleToBatch(global_batch_size=8)
+        ds = DataSet.array(samples) >> tb
+
+        def local_batches(n):
+            runner = pf.PipelineRunner(
+                ds, train=True, to_device=lambda x, y: (x.copy(), y.copy()))
+            try:
+                return [runner.get()[0].device[0].shape[0]
+                        for _ in range(n)]
+            finally:
+                runner.close()
+
+        assert local_batches(4) == [8, 8, 8, 8]
+        (slots,) = tb._shelf
+        assert {s.x.shape[0] for s in slots if s.x is not None} == {8}
+        with mock.patch.object(jax, "process_count", return_value=2):
+            assert local_batches(6) == [4] * 6
+        (slots,) = tb._shelf
+        assert {s.x.shape[0] for s in slots} == {4}
 
     def test_sample_to_batch_needs_exactly_one_size(self):
         with pytest.raises(ValueError, match="exactly one"):

@@ -18,6 +18,7 @@ The contract under test, in order of importance:
    last consumed batch).
 """
 import os
+import threading
 import time
 
 import numpy as np
@@ -28,6 +29,7 @@ import jax
 import bigdl_tpu.nn as nn
 from bigdl_tpu.dataset import DataSet, Sample
 from bigdl_tpu.dataset import prefetch as pf
+from bigdl_tpu.dataset.dataset import AbstractDataSet
 from bigdl_tpu.dataset.image import (HFlip, ImgRdmCropper, ImgToBatch,
                                      LabeledImage)
 from bigdl_tpu.dataset.transformer import FuncTransformer, SampleToBatch
@@ -66,6 +68,52 @@ def _grey_images(n=16, hw=8, seed=1):
     rs = np.random.RandomState(seed)
     return [LabeledImage(rs.rand(hw, hw).astype(np.float32),
                          float(i % 3 + 1)) for i in range(n)]
+
+
+class _InOrder(AbstractDataSet):
+    """The records in the order given, one pass: which batch holds which
+    record is then the test's to say."""
+
+    def __init__(self, records):
+        self._records = list(records)
+
+    def size(self):
+        return len(self._records)
+
+    def shuffle(self):
+        return self
+
+    def data(self, train):
+        return iter(self._records)
+
+
+def _lent_run(ds, depth=None):
+    """Drive ``ds`` through a training runner whose ``to_device`` copies
+    (as a device does) and holds each batch for a moment.  Returns
+    the batches as delivered, whether each lived in one of the runner's
+    slots, and the address of each batch's host array."""
+    recycled, seen = [], []
+    bound = threading.Event()       # the runner's threads start in __init__
+
+    def to_device(x, y):
+        assert bound.wait(5)
+        recycled.append(any(x is slot.x for slot in runner._slots))
+        seen.append(x.ctypes.data)
+        before = x.copy(), y.copy()
+        time.sleep(0.002)
+        # nobody rewrote the host arrays while the transfer had them
+        np.testing.assert_array_equal(x, before[0])
+        np.testing.assert_array_equal(y, before[1])
+        return before
+
+    runner = pf.PipelineRunner(ds, train=True, depth=depth,
+                               to_device=to_device)
+    bound.set()
+    try:
+        got = [item.device for item in runner]
+    finally:
+        runner.close()
+    return got, recycled, seen
 
 
 def _step_events(log):
@@ -665,6 +713,213 @@ class TestPipelineRunner:
         assert run(True) == run(False)
 
 
+class TestLentSlots:
+    """A training runner that copies to the device owns the host batch
+    buffers: it lends ``SampleToBatch`` a recycled slot per draw and takes
+    it back when the transfer is over.  Everyone else gets fresh arrays."""
+
+    def test_no_slot_is_rewritten_before_its_release(self):
+        """Two slots, a transfer that holds every batch for 10 ms and
+        checks it again, a producer that could run far ahead: every batch
+        arrives as the serial path draws it, over three epochs with their
+        shuffles."""
+        def make_ds():
+            return DataSet.array(_samples(n=32)) >> SampleToBatch(8)
+
+        set_seed(23)
+        ds, serial = make_ds(), []
+        it = ds.data(train=True)
+        for k in range(12):             # 4 batches an epoch
+            b = next(it)
+            serial.append((np.array(b.data), np.array(b.labels)))
+            if k % 4 == 3:
+                ds.shuffle()
+                it = ds.data(train=True)
+        set_seed(23)
+        held = []
+
+        def to_device(x, y):
+            before = x.copy(), y.copy()
+            time.sleep(0.01)
+            held.append(np.array_equal(x, before[0])
+                        and np.array_equal(y, before[1]))
+            return before
+
+        runner = pf.PipelineRunner(make_ds(), train=True, epoch_size=32,
+                                   depth=1, to_device=to_device)
+        runner._free.get(timeout=5)     # depth + 2 = 3 slots: leave two
+        items = [runner.get()[0] for _ in range(12)]
+        spans = runner.take_spans()
+        runner.close()
+        assert all(held) and len(held) >= 12
+        for (x, y), item in zip(serial, items):
+            np.testing.assert_array_equal(x, item.device[0])
+            np.testing.assert_array_equal(y, item.device[1])
+            # the host arrays went back with the slot
+            assert item.x is None and item.y is None and item.slot is None
+        assert spans[pf.SLOT_WAIT][1] >= 12
+
+    def test_direct_iteration_yields_distinct_arrays(self):
+        """``dataset.data()`` iterated directly (what the serial loop
+        under ``BIGDL_PREFETCH=0`` does too) gives every batch its own
+        arrays, also while a runner has the stage's slots."""
+        tb = SampleToBatch(8)
+        ds = DataSet.array(_samples(n=32)) >> tb
+        runner = pf.PipelineRunner(ds, train=True,
+                                   to_device=lambda x, y: (x.copy(),
+                                                           y.copy()))
+        try:
+            runner.get()
+            batches = [b for b, _ in zip(ds.data(train=True), range(6))]
+            kept = [(b.data.copy(), b.labels.copy()) for b in batches]
+            for _ in range(6):
+                runner.get()            # the slots turn over meanwhile
+        finally:
+            runner.close()
+        for i, a in enumerate(batches):
+            for b in batches[i + 1:]:
+                assert not np.shares_memory(a.data, b.data)
+                assert not np.shares_memory(a.labels, b.labels)
+        for b, (x, y) in zip(batches, kept):
+            np.testing.assert_array_equal(b.data, x)
+            np.testing.assert_array_equal(b.labels, y)
+
+    def test_serial_loop_leaves_the_slots_alone(self, monkeypatch, ring_log):
+        monkeypatch.setenv(pf.ENV_PREFETCH, "0")
+        tb = SampleToBatch(8)
+        opt = LocalOptimizer(_mlp(), DataSet.array(_samples()) >> tb,
+                             nn.ClassNLLCriterion())
+        opt.set_end_when(max_iteration(4))
+        opt.optimize()
+        assert tb._shelf == [[]]
+        monkeypatch.setenv(pf.ENV_PREFETCH, "1")
+        opt.set_end_when(max_iteration(8))
+        opt.optimize()                  # the front door borrows them ...
+        (slots,) = tb._shelf            # ... and close() gave them back
+        assert len(slots) == pf.DEFAULT_DEPTH + 2
+
+    @pytest.mark.parametrize("case", ["padded", "pinned", "shape", "dtype"])
+    def test_batches_that_do_not_fit_are_assembled_fresh(self, case):
+        rs = np.random.RandomState(2)
+        label = np.asarray([1.0], np.float32)
+        kw = {}
+        if case in ("padded", "pinned"):
+            # variable-length rows: without fixed_length the batch's width
+            # is data-dependent, so no slot can hold it
+            samples = [Sample(rs.rand(int(n), 3).astype(np.float32), label)
+                       for n in rs.randint(2, 7, 16)]
+            kw = dict(feature_padding=0.0,
+                      fixed_length=6 if case == "pinned" else None)
+            want = [case == "pinned"] * 4
+        elif case == "shape":
+            # the third batch's rows are wider than the slots'
+            samples = [Sample(rs.rand(6 if 8 <= i < 12 else 5)
+                              .astype(np.float32), label) for i in range(16)]
+            want = [True, True, False, True]
+        else:
+            # one float64 row: ``np.stack`` promotes its batch, a copy
+            # into the float32 slot would have rounded it
+            samples = [Sample(rs.rand(5).astype(
+                np.float64 if i == 9 else np.float32), label)
+                for i in range(16)]
+            want = [True, True, False, True]
+        plain = list(SampleToBatch(4, **kw)(iter(samples)))
+        got, recycled, _ = _lent_run(
+            _InOrder(samples) >> SampleToBatch(4, **kw))
+        assert recycled == want
+        for a, (x, y) in zip(plain, got):
+            assert x.dtype == a.data.dtype and x.shape == a.data.shape
+            np.testing.assert_array_equal(a.data, x)
+            np.testing.assert_array_equal(a.labels, y)
+
+    def test_host_side_and_eval_runners_assemble_fresh(self):
+        """Without ``to_device`` (the optimizers' mode under a
+        ``FaultInjector``) the consumer reads ``item.x`` whenever it
+        likes; a validation pass borrows the dataset for a moment.  Both
+        get fresh arrays, and neither touches the slots."""
+        tb = SampleToBatch(8)
+        ds = DataSet.array(_samples(n=32)) >> tb
+        runner = pf.PipelineRunner(ds, train=True)
+        items = [runner.get()[0] for _ in range(6)]
+        spans = runner.take_spans()
+        runner.close()
+        assert pf.SLOT_WAIT not in spans
+        for i, a in enumerate(items):
+            assert a.slot is None
+            for b in items[i + 1:]:
+                assert not np.shares_memory(a.x, b.x)
+        evalr = pf.PipelineRunner(ds, train=False,
+                                  to_device=lambda x, y: (x, y))
+        assert len(list(evalr)) == 4
+        assert pf.SLOT_WAIT not in evalr.take_spans()
+        evalr.close()
+        assert tb._shelf == [[]]
+
+    def test_second_runner_over_one_dataset_assembles_fresh(self):
+        ds = DataSet.array(_samples(n=32)) >> SampleToBatch(8)
+        copy = lambda x, y: (x.copy(), y.copy())
+        first = pf.PipelineRunner(ds, train=True, to_device=copy)
+        second = pf.PipelineRunner(ds, train=True, to_device=copy,
+                                   own_rng=False)
+        try:
+            for _ in range(4):
+                first.get(), second.get()
+            assert pf.SLOT_WAIT in first.take_spans()
+            assert pf.SLOT_WAIT not in second.take_spans()
+        finally:
+            first.close()
+            second.close()
+        # the slots are back: the next runner has them
+        third = pf.PipelineRunner(ds, train=True, to_device=copy)
+        third.get()
+        third.close()
+        assert pf.SLOT_WAIT in third.take_spans()
+
+    def test_device_arrays_that_are_the_host_memory_keep_it(self):
+        """The CPU backend adopts an aligned host array without a copy,
+        and a stub may return the host arrays: the slot then lets go of
+        its buffers, so no delivered batch is ever rewritten."""
+        samples = _samples(n=64)
+        plain = list(SampleToBatch(8)(iter(samples)))
+        runner = pf.PipelineRunner(
+            _InOrder(samples) >> SampleToBatch(8), train=True, depth=1,
+            to_device=lambda x, y: (x, y))
+        items = list(runner)
+        runner.close()
+        assert len(items) == 8
+        for a, item in zip(plain, items):
+            np.testing.assert_array_equal(a.data, item.device[0])
+            np.testing.assert_array_equal(a.labels, item.device[1])
+
+    def test_slot_wait_is_booked_outside_the_draw(self):
+        """Slots scarcer than the queues (two, and a transfer that holds
+        each for 30 ms): the producer's wait for a free one shows under
+        ``feed/slot-wait``, outside ``data-load/fetch/`` (whose sub-paths
+        are the chain's links), and the draw's wall and its links do not
+        grow by it."""
+        ds = DataSet.array(_samples(n=32)) >> SampleToBatch(8)
+
+        def to_device(x, y):
+            time.sleep(0.03)
+            return x.copy(), y.copy()
+
+        runner = pf.PipelineRunner(ds, train=True, epoch_size=32, depth=1,
+                                   to_device=to_device)
+        runner._free.get(timeout=5)     # depth + 2 = 3 slots: leave two
+        for _ in range(10):
+            runner.get()
+        spans = runner.take_spans()
+        runner.close()
+        assert not pf.SLOT_WAIT.startswith(pf.FETCH)
+        wait, recycled = spans[pf.SLOT_WAIT]
+        fetch, draws = spans[pf.FETCH]
+        assert recycled == draws >= 10      # reuse share 100%
+        assert wait > 0.15                  # ~8 draws waited ~30 ms each
+        links = sum(sec for path, (sec, _) in spans.items()
+                    if path.startswith(pf.FETCH + "/"))
+        assert fetch < 0.25 * wait and links <= fetch * 1.001
+
+
 class TestSatellites:
     def test_stack_chunk_converts_once_and_checks_shapes(self):
         from bigdl_tpu.dataset.sample import MiniBatch
@@ -691,29 +946,26 @@ class TestSatellites:
             ds.shuffle()
             assert sorted(ds.data(train=False)) == list(range(10))
 
-    def test_sampletobatch_reuse_buffers_ring(self):
-        samples = _samples(n=32)
+    def test_lent_slots_recycle_in_turn(self):
+        """The runner's slots really recycle (3 of them carry 12 batches)
+        and every batch reads as the plain path's."""
+        samples = _samples(n=96)
         plain = list(SampleToBatch(8)(iter(samples)))
-        ring = SampleToBatch(8, reuse_buffers=2)
-        reused = []
-        ids = []
-        for b in ring(iter(samples)):
-            reused.append(np.array(b.data))    # copy before reuse
-            ids.append(id(b.data))
-        assert len(reused) == 4
-        for a, b in zip(plain, reused):
-            np.testing.assert_array_equal(a.data, b)
-        # the ring really recycles: slot 0 backs batches 0 and 2
-        assert ids[0] == ids[2] and ids[1] == ids[3]
-        assert ids[0] != ids[1]
+        got, recycled, seen = _lent_run(
+            _InOrder(samples) >> SampleToBatch(8), depth=1)
+        assert len(got) == len(plain) == 12 and all(recycled)
+        for a, (x, y) in zip(plain, got):
+            np.testing.assert_array_equal(a.data, x)
+            np.testing.assert_array_equal(a.labels, y)
+        assert len(set(seen)) == 3          # depth + 2 buffers, no more
 
-    def test_sampletobatch_reuse_tail_falls_back(self):
+    def test_lent_slots_tail_falls_back(self):
         samples = _samples(n=20)               # 8 + 8 + 4 tail
-        ring = SampleToBatch(8, reuse_buffers=2)
-        batches = list(ring(iter(samples)))
-        assert [b.data.shape[0] for b in batches] == [8, 8, 4]
-        with pytest.raises(ValueError, match="ring of >= 2"):
-            SampleToBatch(8, reuse_buffers=1)
+        got, recycled, _ = _lent_run(_InOrder(samples) >> SampleToBatch(8))
+        assert [x.shape[0] for x, _ in got] == [8, 8, 4]
+        assert recycled == [True, True, False]
+        np.testing.assert_array_equal(
+            got[2][0], np.stack([s.feature for s in samples[16:]]))
 
     def test_transformer_purity_attrs(self):
         from bigdl_tpu.dataset.image import (BytesToImg, ColorJitter,
