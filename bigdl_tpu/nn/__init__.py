@@ -7,6 +7,7 @@ from bigdl_tpu.nn import init
 from bigdl_tpu.nn.init import InitializationMethod, Default, Xavier, BilinearFiller, MSRA
 from bigdl_tpu.nn.containers import (
     Sequential, Concat, ConcatTable, ParallelTable, MapTable, Bottle,
+    Recompute,
 )
 from bigdl_tpu.nn.activations import (
     ReLU, ReLU6, PReLU, RReLU, LeakyReLU, ELU, Tanh, TanhShrink, Sigmoid,
@@ -16,7 +17,7 @@ from bigdl_tpu.nn.activations import (
 )
 from bigdl_tpu.nn.linear import (
     Linear, Bilinear, CMul, CAdd, Mul, Add, MulConstant, AddConstant, MM, MV,
-    Cosine, Euclidean, LookupTable,
+    Cosine, Euclidean, LookupTable, GatedLinearUnit, LmHead,
 )
 from bigdl_tpu.nn.conv import (
     SpatialConvolution, SpatialShareConvolution, SpatialDilatedConvolution,
@@ -28,7 +29,7 @@ from bigdl_tpu.nn.pooling import (
 from bigdl_tpu.nn.normalization import (
     BatchNormalization, SpatialBatchNormalization, SpatialCrossMapLRN,
     SpatialSubtractiveNormalization, SpatialDivisiveNormalization,
-    SpatialContrastiveNormalization, LayerNorm,
+    SpatialContrastiveNormalization, LayerNorm, RMSNorm,
 )
 from bigdl_tpu.nn.shape_ops import (
     Reshape, InferReshape, View, Transpose, Replicate, Squeeze, Unsqueeze,
@@ -47,9 +48,10 @@ from bigdl_tpu.nn.nms import Nms, nms_mask, nms_indices
 from bigdl_tpu.nn.recurrent import (
     Cell, RnnCell, LSTMCell, GRUCell, Recurrent, BiRecurrent, TimeDistributed,
 )
-from bigdl_tpu.nn.moe import MoE
+from bigdl_tpu.nn.moe import MoE, DroplessMoE
 from bigdl_tpu.nn.attention import (MultiHeadSelfAttention,
-                                    SinusoidalPositionalEncoding)
+                                    SinusoidalPositionalEncoding,
+                                    GatedGroupedQueryAttention)
 from bigdl_tpu.nn.criterion import (
     ClassNLLCriterion, CrossEntropyCriterion, MSECriterion, AbsCriterion,
     BCECriterion, DistKLDivCriterion, ClassSimplexCriterion,

@@ -17,10 +17,13 @@ exists for.  The layer has TWO execution paths with identical math:
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from bigdl_tpu.nn.module import TensorModule
 from bigdl_tpu.nn import init as init_
+from bigdl_tpu.nn.linear import dot32
+from bigdl_tpu.nn.normalization import rms_norm
 from bigdl_tpu.tensor import policy
 
 
@@ -128,3 +131,86 @@ class SinusoidalPositionalEncoding(TensorModule):
 
     def __repr__(self):
         return f"SinusoidalPositionalEncoding({self.d_model})"
+
+
+def rotary(x, base: float = 10000.0):
+    """Rotary positions on (B, T, H, D), position t = the index along T:
+    the two halves of D rotate as pairs (i, i + D/2) by t * base^(-2i/D),
+    the rotate-half convention.  float32."""
+    t, d = x.shape[1], x.shape[-1]
+    freq = base ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * freq[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1)
+
+
+class GatedGroupedQueryAttention(TensorModule):
+    """(B, T, D) -> (B, T, D) causal self-attention with grouped heads
+    (``n_heads`` query heads share ``n_kv_heads`` key/value heads), an
+    RMSNorm on every head's query and key, a sigmoid gate on the joined
+    heads before the output projection, and bias-free projections.
+
+    ``window``: query i sees keys i - window < j <= i (None: every key up
+    to i).  ``rotary_base``: rotary positions on q and k (None: no
+    positions).  The core is ``parallel.ring_attention.
+    blockwise_attention``: no (T, T) array, and a window layer skips the
+    key blocks outside its window."""
+
+    quant_spec = {"wq": (1, 0), "wk": (1, 0), "wv": (1, 0), "wg": (1, 0),
+                  "wo": (1, 0)}
+
+    def __init__(self, d_model: int, n_heads: int, n_kv_heads: int,
+                 head_dim: int, window: int = None,
+                 rotary_base: float = None, eps: float = 1e-5):
+        super().__init__()
+        if n_heads % n_kv_heads:
+            raise ValueError(f"n_heads ({n_heads}) must divide by "
+                             f"n_kv_heads ({n_kv_heads})")
+        self.d_model = d_model
+        self.n_heads = n_heads
+        self.n_kv_heads = n_kv_heads
+        self.head_dim = head_dim
+        self.window = window
+        self.rotary_base = rotary_base
+        self.eps = eps
+        self.block = 512        # rows of a query block and of a key block
+        self.reset()
+
+    def reset(self):
+        d, hd = self.d_model, self.head_dim
+        q_out, kv_out = self.n_heads * hd, self.n_kv_heads * hd
+        for name, shape in (("wq", (d, q_out)), ("wk", (d, kv_out)),
+                            ("wv", (d, kv_out)), ("wg", (d, q_out)),
+                            ("wo", (q_out, d))):
+            self._add_param(name, init_.normal_on_device(shape))
+        self._add_param("q_norm", np.ones((hd,), np.float32))
+        self._add_param("k_norm", np.ones((hd,), np.float32))
+        return self
+
+    def _forward(self, P, x, S, ctx):
+        from bigdl_tpu.parallel.ring_attention import blockwise_attention
+        b, t, _ = x.shape
+        hd = self.head_dim
+        q = dot32(x, P["wq"]).reshape(b, t, self.n_heads, hd)
+        k = dot32(x, P["wk"]).reshape(b, t, self.n_kv_heads, hd)
+        v = dot32(x, P["wv"]).reshape(b, t, self.n_kv_heads, hd)
+        q = rms_norm(q, P["q_norm"], self.eps)
+        k = rms_norm(k, P["k_norm"], self.eps)
+        if self.rotary_base is not None:
+            q, k = rotary(q, self.rotary_base), rotary(k, self.rotary_base)
+        cc = policy().cast_compute
+        core = ("FullAttentionCore" if self.window is None
+                else "WindowAttentionCore")
+        with jax.named_scope(core):
+            o = blockwise_attention(cc(q), cc(k), cc(v), self.window,
+                                    self.block)
+        o = o.reshape(b, t, -1) * jax.nn.sigmoid(dot32(x, P["wg"]))
+        return dot32(o, P["wo"]), None
+
+    def __repr__(self):
+        kind = "full" if self.window is None else f"window={self.window}"
+        return (f"GatedGroupedQueryAttention({self.d_model}, heads="
+                f"{self.n_heads}/{self.n_kv_heads}x{self.head_dim}, {kind})")

@@ -40,6 +40,24 @@ class Sequential(Container):
         return x, new_state
 
 
+class Recompute(Container):
+    """Run the one child under ``jax.checkpoint``: the backward pass keeps
+    the child's input and computes its inner activations again.  A model
+    whose layers are wrapped one by one holds one layer's activations at a
+    time (``LocalOptimizer.set_gradient_checkpointing`` wraps the whole
+    model, which bounds nothing by layer).  Same parameters, same result."""
+
+    def __init__(self, module: Module):
+        super().__init__(module)
+
+    def apply(self, params, x, state, ctx):
+        inner = jax.checkpoint(
+            lambda p, x_, s: _child_apply(self, 0, {"0": p}, x_, {"0": s},
+                                          ctx))
+        y, ns = inner(params["0"], x, state["0"])
+        return y, dict(state, **{"0": ns})
+
+
 _MERGE_1X1 = True  # kill switch for the merged-pointwise-head execution
 
 

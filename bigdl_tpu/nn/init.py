@@ -32,6 +32,18 @@ def normal(shape, mean, stdv):
     return RNG.normal(mean, stdv, size=shape).astype(np.float32)
 
 
+#: the language-model layers' initialiser: N(0, 0.02), what decoder
+#: families that publish an ``initializer_range`` mostly give
+LM_INIT_STD = 0.02
+
+
+def normal_on_device(shape, stdv=LM_INIT_STD):
+    """N(0, stdv) drawn on the device from the global key stream: a layer of
+    hundreds of millions of weights is not drawn on the host and copied."""
+    import jax
+    return jax.random.normal(RNG.next_key(), shape, "float32") * stdv
+
+
 def default_linear(shape, fan_in):
     """Torch nn.Linear default: U(-1/sqrt(fanIn), 1/sqrt(fanIn))."""
     stdv = 1.0 / np.sqrt(fan_in)

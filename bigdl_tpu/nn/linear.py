@@ -11,6 +11,7 @@ MXU via XLA dot_general.
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from bigdl_tpu.nn.module import TensorModule, Module
@@ -24,6 +25,15 @@ def _dot(a, b):
     accumulation is f32 inside the MXU), output cast back."""
     p = policy()
     return jnp.matmul(p.cast_compute(a), p.cast_compute(b)).astype(p.output_dtype)
+
+
+def dot32(a, b):
+    """The chokepoint with a float32 result: operands in the policy's
+    compute dtype, the accumulator handed back unrounded (``_dot`` rounds
+    a bf16 product to bf16 before widening it)."""
+    p = policy()
+    return jnp.matmul(p.cast_compute(a), p.cast_compute(b),
+                      preferred_element_type=jnp.float32)
 
 
 class Linear(TensorModule):
@@ -65,6 +75,35 @@ class Linear(TensorModule):
 
     def __repr__(self):
         return f"Linear({self.input_size} -> {self.output_size})"
+
+
+class GatedLinearUnit(TensorModule):
+    """SwiGLU feed-forward, bias-free: (…, D) -> (…, D),
+    ``(silu(x Wg) * (x Wu)) Wd`` with ``Wg``, ``Wu`` (D, H) and ``Wd``
+    (H, D).  No counterpart in the reference."""
+
+    def __init__(self, d_model: int, hidden: int):
+        super().__init__()
+        self.d_model = d_model
+        self.hidden = hidden
+        self.reset()
+
+    def reset(self):
+        d, h = self.d_model, self.hidden
+        for name, shape in (("w_gate", (d, h)), ("w_up", (d, h)),
+                            ("w_down", (h, d))):
+            self._add_param(name, init_.normal_on_device(shape))
+        return self
+
+    def _forward(self, P, x, S, ctx):
+        return swiglu(x, P["w_gate"], P["w_up"], P["w_down"]), None
+
+    def __repr__(self):
+        return f"GatedLinearUnit({self.d_model}, hidden={self.hidden})"
+
+
+def swiglu(x, w_gate, w_up, w_down):
+    return dot32(jax.nn.silu(dot32(x, w_gate)) * dot32(x, w_up), w_down)
 
 
 class Bilinear(TensorModule):
@@ -265,17 +304,24 @@ class LookupTable(TensorModule):
 
     def __init__(self, n_index: int, n_output: int, padding_value: float = 0,
                  max_norm: float = None, norm_type: float = 2.0,
-                 should_scale_grad_by_freq: bool = False):
+                 should_scale_grad_by_freq: bool = False,
+                 init_std: float = None):
         super().__init__()
         self.n_index = n_index
         self.n_output = n_output
         self.padding_value = padding_value
         self.max_norm = max_norm
         self.norm_type = norm_type
+        self.init_std = init_std
         self.reset()
 
     def reset(self):
-        self._add_param("weight", init_.normal((self.n_index, self.n_output), 0, 1))
+        shape = (self.n_index, self.n_output)
+        # Torch's N(0, 1) from the host stream, or N(0, init_std) drawn on
+        # the device (a language model's table is large)
+        self._add_param("weight", init_.normal(shape, 0, 1)
+                        if self.init_std is None
+                        else init_.normal_on_device(shape, self.init_std))
         return self
 
     def _forward(self, P, x, S, ctx):
@@ -286,3 +332,26 @@ class LookupTable(TensorModule):
             w = w * scale
         idx = jnp.asarray(x, jnp.int32) - 1  # 1-based -> 0-based
         return jnp.take(w, idx, axis=0), None
+
+
+class LmHead(TensorModule):
+    """Untied, bias-free output head of a language model: (…, D) ->
+    (…, V) log-probabilities, ``log_softmax(x W)`` with ``W`` (D, V); the
+    logits and the softmax are float32."""
+
+    def __init__(self, d_model: int, vocab_size: int):
+        super().__init__()
+        self.d_model = d_model
+        self.vocab_size = vocab_size
+        self.reset()
+
+    def reset(self):
+        self._add_param("weight", init_.normal_on_device(
+            (self.d_model, self.vocab_size)))
+        return self
+
+    def _forward(self, P, x, S, ctx):
+        return jax.nn.log_softmax(dot32(x, P["weight"]), axis=-1), None
+
+    def __repr__(self):
+        return f"LmHead({self.d_model} -> {self.vocab_size})"

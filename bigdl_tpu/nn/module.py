@@ -122,8 +122,17 @@ class Module:
     def _add_param(self, name, value):
         value = jnp.asarray(value, dtype=default_dtype())
         self._params[name] = value
-        self._grads[name] = jnp.zeros_like(value)
+        # made on first use (``_own_grads``): only the eager backward()
+        # accumulates into it, and a model that an Optimizer trains would
+        # hold a zero copy of itself on the device for nothing
+        self._grads[name] = None
         return value
+
+    def _own_grads(self):
+        for name, g in self._grads.items():
+            if g is None:
+                self._grads[name] = jnp.zeros_like(self._params[name])
+        return self._grads
 
     def _add_buffer(self, name, value):
         value = jnp.asarray(value)
@@ -163,7 +172,7 @@ class Module:
         return tree
 
     def grads(self):
-        tree = {"~": dict(self._grads)}
+        tree = {"~": dict(self._own_grads())}
         for name, m in self._modules.items():
             tree[name] = m.grads()
         return tree
@@ -280,7 +289,7 @@ class Module:
     def parameters(self):
         """(list of weight arrays, list of grad arrays), depth-first."""
         ws = list(self._params.values())
-        gs = list(self._grads.values())
+        gs = list(self._own_grads().values())
         for m in self._modules.values():
             w2, g2 = m.parameters()
             ws += w2
@@ -301,7 +310,7 @@ class Module:
                 jnp.concatenate([g.reshape(-1) for g in gs]))
 
     def zero_grad_parameters(self):
-        self._grads = OrderedDict((k, jnp.zeros_like(v)) for k, v in self._grads.items())
+        self._grads = OrderedDict((k, None) for k in self._grads)
         for m in self._modules.values():
             m.zero_grad_parameters()
         return self

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from bigdl_tpu.nn.module import TensorModule
 from bigdl_tpu.nn import init as init_
+from bigdl_tpu.nn.linear import swiglu
 from bigdl_tpu.tensor import policy
 
 
@@ -78,3 +79,112 @@ class MoE(TensorModule):
     def __repr__(self):
         return (f"MoE({self.d_model}, hidden={self.hidden}, "
                 f"experts={self.n_experts})")
+
+
+class DroplessMoE(TensorModule):
+    """Sigmoid top-k routed SwiGLU experts with an optional shared expert,
+    told which experts it holds: (…, D) -> (…, D).
+
+    The router scores all ``n_experts`` (``parallel.moe.
+    sigmoid_topk_routing``); this layer computes the part of the result
+    that the experts in ``experts_held`` give (all of them by default),
+    plus the shared expert's, and leaves out what the absent ones would
+    add: one chip's share under expert parallelism, without the exchange.
+    No capacity and no dropped token: the assignments are sorted by expert
+    and run as grouped products (``parallel.moe.grouped_experts``),
+    ``chunk_rows`` sorted assignments at a time for as many passes as hold
+    one.  A pass costs nearly the same however full it is and a second one
+    costs as much again, so the default is ``CHUNK_OF_EVEN_SHARE`` times
+    what the layer would hold were the routing even: a layer trained with
+    a balance term never comes near it and pays for the empty rows; a
+    builder who knows its routing passes its own.
+
+    Params: ``router`` (D, E); ``w_gate``, ``w_up`` (n_held, D, H) and
+    ``w_down`` (n_held, H, D); ``shared_gate``/``shared_up`` (D, Hs) and
+    ``shared_down`` (Hs, D) where ``shared_hidden``.  Buffers:
+    ``route_bias`` (E,), added to the scores for the choice only, no
+    gradient; ``tap_assignments_held`` and ``tap_expert_max``, the last
+    call's count of assignments held here and its busiest expert's count
+    (``obs.taps.module_counters`` hands them to the step's taps)."""
+
+    # from a v5e at hidden 2048 x 1024, 16 of 128 experts held, top-8,
+    # 16,384 tokens (PERF.md section 6, PR 28): randomly initialised layers
+    # with no balance term held 0.23 to 2.7 times the even share by seed
+    # and step; twice it was crossed, three times it was not, and its
+    # padded rows cost 7% of that model's step
+    CHUNK_OF_EVEN_SHARE = 3
+
+    def __init__(self, d_model: int, hidden: int, n_experts: int,
+                 top_k: int, experts_held=None, route_norm: bool = True,
+                 route_scale: float = 1.0, shared_hidden: int = 0,
+                 chunk_rows=None):
+        super().__init__()
+        self.d_model = d_model
+        self.hidden = hidden
+        self.n_experts = n_experts
+        self.top_k = top_k
+        self.experts_held = tuple(range(n_experts) if experts_held is None
+                                  else experts_held)
+        if len(set(self.experts_held)) != len(self.experts_held) or not all(
+                0 <= e < n_experts for e in self.experts_held):
+            raise ValueError(f"experts_held {self.experts_held} are not "
+                             f"distinct ids under {n_experts}")
+        self.route_norm = route_norm
+        self.route_scale = route_scale
+        self.shared_hidden = shared_hidden
+        self.chunk_rows = chunk_rows
+        self.reset()
+
+    def reset(self):
+        d, h, held = self.d_model, self.hidden, len(self.experts_held)
+        shapes = [("router", (d, self.n_experts)), ("w_gate", (held, d, h)),
+                  ("w_up", (held, d, h)), ("w_down", (held, h, d))]
+        if self.shared_hidden:
+            hs = self.shared_hidden
+            shapes += [("shared_gate", (d, hs)), ("shared_up", (d, hs)),
+                       ("shared_down", (hs, d))]
+        for name, shape in shapes:
+            self._add_param(name, init_.normal_on_device(shape))
+        self._add_buffer("route_bias", np.zeros((self.n_experts,),
+                                                np.float32))
+        self._add_buffer("tap_assignments_held", np.zeros((), np.float32))
+        self._add_buffer("tap_expert_max", np.zeros((), np.float32))
+        return self
+
+    def _forward(self, P, x, S, ctx):
+        from bigdl_tpu.parallel.moe import (grouped_experts,
+                                            sigmoid_topk_routing,
+                                            sort_assignments)
+        xt = x.reshape(-1, x.shape[-1])
+        n_held, k = len(self.experts_held), self.top_k
+        local_of = np.full((self.n_experts,), n_held, np.int32)
+        local_of[list(self.experts_held)] = np.arange(n_held)
+        # the most assignments this share can get: every token's choices
+        # among the experts held
+        most = xt.shape[0] * min(k, n_held)
+        even = xt.shape[0] * k * n_held / self.n_experts
+        chunk = min(most, self.chunk_rows or -(-int(
+            self.CHUNK_OF_EVEN_SHARE * even) // 256) * 256)
+        with jax.named_scope("MoeRoute"):
+            idx, weights = sigmoid_topk_routing(
+                xt, P["router"], S["route_bias"], k, self.route_norm,
+                self.route_scale)
+            order, sizes = sort_assignments(idx, jnp.asarray(local_of),
+                                            n_held)
+            order = jnp.pad(order[:most], (0, -most % chunk))
+            y = grouped_experts(xt, P["w_gate"], P["w_up"], P["w_down"],
+                                weights, order, sizes, chunk, k,
+                                policy().cast_compute)
+        if self.shared_hidden:
+            with jax.named_scope("MoeShared"):
+                y = y + swiglu(xt, P["shared_gate"], P["shared_up"],
+                               P["shared_down"])
+        counts = sizes.astype(jnp.float32)
+        new_s = dict(S, tap_assignments_held=counts.sum(),
+                     tap_expert_max=counts.max())
+        return y.reshape(x.shape), new_s
+
+    def __repr__(self):
+        return (f"DroplessMoE({self.d_model}, hidden={self.hidden}, "
+                f"top{self.top_k} of {self.n_experts}, holds "
+                f"{len(self.experts_held)})")

@@ -392,3 +392,31 @@ class SpatialContrastiveNormalization(TensorModule):
     def _forward(self, P, x, S, ctx):
         y, _ = self.sub._forward(P, x, S, ctx)
         return self.div._forward(P, y, S, ctx)
+
+
+class RMSNorm(TensorModule):
+    """x / sqrt(mean(x^2) + eps) * weight over the trailing dim, in
+    float32: (…, D) -> (…, D).  Per token, like ``LayerNorm``, without the
+    mean and the shift."""
+
+    def __init__(self, d_model: int, eps: float = 1e-5):
+        super().__init__()
+        self.d_model = d_model
+        self.eps = eps
+        self.reset()
+
+    def reset(self):
+        self._add_param("weight", np.ones((self.d_model,), np.float32))
+        return self
+
+    def _forward(self, P, x, S, ctx):
+        return rms_norm(x, P["weight"], self.eps), None
+
+    def __repr__(self):
+        return f"RMSNorm({self.d_model})"
+
+
+def rms_norm(x, weight, eps):
+    x32 = x.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return x32 * inv * weight

@@ -83,6 +83,29 @@ def compute(grads, params, new_params):
     }
 
 
+def module_counters(net_state):
+    """Counters that modules leave in their buffers, as taps: every scalar
+    buffer named ``tap_<name>`` becomes ``<name>/<i>``, ``i`` counting the
+    modules that hold one in the model's own order (``DroplessMoE`` keeps
+    ``assignments_held`` and ``expert_max``).  They ride the step's
+    outputs with the taps above, materialized at the same cadence."""
+    out, seen = {}, {}
+
+    def walk(tree):
+        for key, value in tree.get("~", {}).items():
+            if key.startswith("tap_"):
+                name = key[len("tap_"):]
+                out[f"{name}/{seen.setdefault(name, 0)}"] = value
+                seen[name] += 1
+        for key in sorted((k for k in tree if k != "~"),
+                          key=lambda k: (not k.isdigit(),
+                                         int(k) if k.isdigit() else k)):
+            walk(tree[key])
+
+    walk(net_state)
+    return out
+
+
 class TapsMonitor:
     """Host-side cadence gate for the device tap scalars.
 

@@ -358,7 +358,8 @@ class LocalOptimizer:
                     out, ns = apply(p, x)
                 else:
                     out, ns = apply(p, x, net_state, Context(training=True, key=key))
-                return criterion.apply_loss(out, y), ns
+                with jax.named_scope(type(criterion).__name__):
+                    return criterion.apply_loss(out, y), ns
 
             (loss, new_net_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
             # the scopes name the update's and the taps' operations in a
@@ -375,7 +376,8 @@ class LocalOptimizer:
             # in-jit taps: extra outputs of the SAME dispatch, post-skip-
             # select so update_ratio reads 0 on a skipped step
             with jax.named_scope("obs-taps"):
-                taps = (obs_taps.compute(grads, params, new_params)
+                taps = (dict(obs_taps.compute(grads, params, new_params),
+                             **obs_taps.module_counters(new_net_state))
                         if taps_on else {})
             return (new_params, new_net_state, new_opt_state, loss, finite,
                     taps)
@@ -569,6 +571,18 @@ class LocalOptimizer:
 
     # -- main loop (ref LocalOptimizer.optimize :77) ----------------------
     def optimize(self):
+        """Train until the end trigger fires and return the model, which
+        then holds the trained parameters.
+
+        A model whose parameters take more than ``_TAKEOVER_SHARE`` of the
+        device's memory is not copied but **taken over**: the compiled step
+        donates the model's own arrays, so during the run the model holds
+        deleted arrays and must not be read (checkpoints and validation
+        are made from the loop's state, not from the model).  The loop
+        hands its latest parameters back when it ends, by an exception too:
+        the model then holds the last step's result, and its initial
+        weights are gone.  Smaller models are copied first and keep their
+        initial weights until the run ends cleanly."""
         state = self.state
         state.get_or_update("epoch", 1)
         state.get_or_update("neval", 1)
@@ -578,8 +592,13 @@ class LocalOptimizer:
 
         # copy the model's arrays: the jit step donates its carried state,
         # and donating the module's own buffers would leave the user's model
-        # holding deleted arrays mid-training
-        params = jax.tree_util.tree_map(jnp.copy, self.model.params())
+        # holding deleted arrays mid-training.  A model that fills a large
+        # share of the device cannot be held twice: the loop takes its
+        # arrays over and hands the trained ones back when it ends, however
+        # it ends (the ``finally`` below)
+        taken_over = _fills_device(self.model.params())
+        params = self.model.params() if taken_over else \
+            jax.tree_util.tree_map(jnp.copy, self.model.params())
         net_state = jax.tree_util.tree_map(jnp.copy, self.model.state())
         opt_state = self._initial_opt_state(params)
         step_fn = self._build_step()
@@ -722,6 +741,13 @@ class LocalOptimizer:
             # leaving optimize() with snapshots still in flight would
             # let the process exit before they are durable
             self._flush_ckpt_writer("run end")
+            if taken_over:
+                if any(leaf.is_deleted()
+                       for leaf in jax.tree_util.tree_leaves(params)):
+                    logger.error("the step that failed had consumed the "
+                                 "model's parameters: the model is left "
+                                 "without them")
+                self.model.load_params(params)
 
         self.model.load_params(params)
         self.model.load_state(net_state)
@@ -1139,6 +1165,24 @@ class LocalOptimizer:
                 from bigdl_tpu.optim.optimizer import prune_checkpoints
                 prune_checkpoints(self.checkpoint_path, meta["keep"],
                                   just_written=meta.get("step"))
+
+
+# a model over this share of the device's memory is taken over by
+# ``optimize()`` instead of copied: with its gradient and one optimizer
+# buffer of the same size a second copy would pass half the device
+_TAKEOVER_SHARE = 0.125
+
+
+def _fills_device(tree) -> bool:
+    """Whether the arrays of ``tree`` take more than ``_TAKEOVER_SHARE`` of
+    the first local device's memory (False where the backend does not say
+    how much it has, as the CPU's)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    if not limit:
+        return False
+    held = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(tree))
+    return held > _TAKEOVER_SHARE * limit
 
 
 def _model_fingerprint(model):

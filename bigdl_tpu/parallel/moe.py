@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from functools import partial
 
+import numpy as np
 import jax
 
 from jax import shard_map
@@ -141,3 +142,154 @@ def moe_apply_sharded_tokens(router_w, expert_w1, expert_b1, expert_w2,
         in_specs=(P(), pspec_e, pspec_e, pspec_e, pspec_e, P(data_axis)),
         out_specs=P(data_axis), check_vma=False)
     return f(router_w, expert_w1, expert_b1, expert_w2, expert_b2, x)
+
+
+# ---------------------------------------------------------------------------
+# dropless top-k routing over the experts one chip holds
+# ---------------------------------------------------------------------------
+
+def sigmoid_topk_routing(x, router_w, bias, top_k: int, route_norm: bool,
+                         route_scale: float):
+    """x: (T, D) -> (idx (T, k) int32 expert ids, weights (T, k) float32).
+    Scores are sigmoid(x W) in true float32 (the product is D x E: free);
+    the experts are the top k of score + bias, the bias entering the
+    choice only; the weights are the chosen scores, normalised to sum 1
+    where ``route_norm``, times ``route_scale``."""
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    if route_norm:
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    return idx, weights * route_scale
+
+
+def sort_assignments(idx, local_of, n_held: int):
+    """The (token, choice) assignments grouped by the expert held here.
+    idx: (T, k) global expert ids; ``local_of``: (E,) the local index of a
+    held expert, ``n_held`` for one that lives elsewhere.  Returns
+    (order, sizes): ``order`` (T*k,) lists the flat assignment indices
+    (token * k + choice), held experts first in local order, the absent
+    ones' last; ``sizes`` (n_held,) counts each held expert's."""
+    local = local_of[idx].reshape(-1)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    sizes = jnp.sum(local[:, None] == jnp.arange(n_held), axis=0,
+                    dtype=jnp.int32)
+    return order, sizes
+
+
+def _chunk_rows(order, sizes, start, chunk, top_k):
+    """The sorted assignments [start, start + chunk): (rows, their tokens,
+    which of them are held here, how many fall to each held expert)."""
+    ends = jnp.cumsum(sizes)
+    clip = lambda a: jnp.clip(a - start, 0, chunk)
+    rows = lax.dynamic_slice(order, (start,), (chunk,))
+    valid = start + jnp.arange(chunk) < ends[-1]
+    return rows, rows // top_k, valid, clip(ends) - clip(ends - sizes)
+
+
+def _experts_of_rows(xs, w_gate, w_up, w_down, chunk_sizes, valid):
+    """Rows that lie expert by expert through their experts' SwiGLU: three
+    grouped products.  xs: (chunk, D) and the weights in the compute
+    dtype; float32 (chunk, D), zero in the rows past the groups."""
+    def ragged(a, w):
+        # a grouped product says nothing of the rows past its groups, in
+        # its result or in its operand's gradient: both sides are masked,
+        # so that neither pass reads what the kernel left there
+        a = jnp.where(valid[:, None], a, jnp.zeros((), a.dtype))
+        out = lax.ragged_dot(a, w, chunk_sizes,
+                             preferred_element_type=jnp.float32)
+        return jnp.where(valid[:, None], out, 0.0)
+
+    with jax.named_scope("MoeExperts"):
+        hidden = jax.nn.silu(ragged(xs, w_gate)) * ragged(xs, w_up)
+        return ragged(hidden.astype(xs.dtype), w_down)
+
+
+def _float0(a):
+    return np.zeros(a.shape, jax.dtypes.float0)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def grouped_experts(x, w_gate, w_up, w_down, weights, order, sizes, chunk,
+                    top_k, cast):
+    """sum over the assignments held here of weight * Expert(x_token), with
+    no capacity: the sorted assignments are walked ``chunk`` rows at a
+    time for as many chunks as hold one (a loop whose length the routing
+    decides), so the memory is one chunk's and no imbalance drops a token.
+    A chunk gathers its tokens' rows, runs them through their experts and
+    adds the weighted result to its tokens' rows of the output, in place;
+    it costs nearly the same however full it is (the gathers and
+    scatter-adds take all its rows), so the caller sizes it to hold a
+    usual step's assignments at once.
+
+    x: (T, D); w_gate, w_up: (n_held, D, H); w_down: (n_held, H, D);
+    weights: (T, k); order, sizes: ``sort_assignments``'; ``order`` is
+    padded to a multiple of ``chunk``."""
+    return _grouped_fwd(x, w_gate, w_up, w_down, weights, order, sizes,
+                        chunk, top_k, cast)
+
+
+def _n_chunks(sizes, chunk):
+    return (jnp.sum(sizes) + chunk - 1) // chunk
+
+
+def _grouped_fwd(x, w_gate, w_up, w_down, weights, order, sizes, chunk,
+                 top_k, cast):
+    xc, wg, wu, wd = cast(x), cast(w_gate), cast(w_up), cast(w_down)
+    flat = weights.reshape(-1)
+
+    def body(c, out):
+        rows, tok, valid, chunk_sizes = _chunk_rows(order, sizes, c * chunk,
+                                                    chunk, top_k)
+        y = _experts_of_rows(xc[tok], wg, wu, wd, chunk_sizes, valid)
+        w = jnp.where(valid, flat[rows], 0.0)
+        return out.at[tok].add(y * w[:, None])
+
+    return lax.fori_loop(0, _n_chunks(sizes, chunk), body,
+                         jnp.zeros(x.shape, jnp.float32))
+
+
+def _grouped_vjp_fwd(x, w_gate, w_up, w_down, weights, order, sizes, chunk,
+                     top_k, cast):
+    out = _grouped_fwd(x, w_gate, w_up, w_down, weights, order, sizes,
+                       chunk, top_k, cast)
+    return out, (x, w_gate, w_up, w_down, weights, order, sizes)
+
+
+def _grouped_vjp_bwd(chunk, top_k, cast, res, dout):
+    """Chunk by chunk again: a chunk's rows go through their experts once
+    more (nothing of the forward pass is kept but its inputs), the
+    gradient of the output's rows comes back through them, and every sum
+    is kept in float32 and added to in place."""
+    x, w_gate, w_up, w_down, weights, order, sizes = res
+    xc, wg, wu, wd = cast(x), cast(w_gate), cast(w_up), cast(w_down)
+    flat = weights.reshape(-1)
+    f32 = lambda a: a.astype(jnp.float32)
+
+    def body(c, grads):
+        dx, dwg, dwu, dwd, dflat = grads
+        rows, tok, valid, chunk_sizes = _chunk_rows(order, sizes, c * chunk,
+                                                    chunk, top_k)
+        y, vjp = jax.vjp(
+            lambda *a: _experts_of_rows(*a, chunk_sizes, valid),
+            xc[tok], wg, wu, wd)
+        w = jnp.where(valid, flat[rows], 0.0)
+        dyw = dout[tok]
+        dxs, g, u, d = vjp(dyw * w[:, None])
+        # the rows past the groups are padding that names assignment 0:
+        # they add zeros
+        dw_rows = jnp.where(valid, jnp.sum(dyw * y, axis=-1), 0.0)
+        return (dx.at[tok].add(f32(dxs)), dwg + f32(g), dwu + f32(u),
+                dwd + f32(d), dflat.at[rows].add(dw_rows))
+
+    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
+    dx, dwg, dwu, dwd, dflat = lax.fori_loop(
+        0, _n_chunks(sizes, chunk), body,
+        (zeros(x), zeros(w_gate), zeros(w_up), zeros(w_down), zeros(flat)))
+    return (dx, dwg, dwu, dwd, dflat.reshape(weights.shape),
+            _float0(order), _float0(sizes))
+
+
+grouped_experts.defvjp(_grouped_vjp_fwd, _grouped_vjp_bwd)

@@ -123,3 +123,167 @@ def full_attention(q, k, v, causal: bool = False):
         s = jnp.where(mask, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+
+
+# ---------------------------------------------------------------------------
+# blockwise causal attention on one device: never holds T x T, and a window
+# layer's work grows with T x W
+# ---------------------------------------------------------------------------
+
+def _pair_scores(q_blk, k_blk, q_off, k_off, window, scale):
+    """Masked scores of one (query block, key block) pair with grouped
+    heads.  q_blk: (B, Tq, Hk, G, D), k_blk: (B, Tk, Hk, D) -> float32
+    (B, Hk, G, Tq, Tk), -inf where query i may not see key j (j > i, or
+    j <= i - window)."""
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", q_blk, k_blk,
+                   preferred_element_type=jnp.float32) * scale
+    qi = q_off + jnp.arange(q_blk.shape[1])[:, None]
+    ki = k_off + jnp.arange(k_blk.shape[1])[None, :]
+    seen = qi >= ki
+    if window is not None:
+        seen = seen & (qi - ki < window)
+    return jnp.where(seen, s, -jnp.inf)
+
+
+def _first_key_block(i, block, window):
+    """The first key block that any query of query block ``i`` sees."""
+    if window is None:
+        return jnp.zeros((), jnp.int32)
+    return jnp.maximum(i * block - (window - 1), 0) // block
+
+
+def _blockwise_fwd(q, k, v, window, block):
+    """q: (B, T, Hk, G, D), k/v: (B, T, Hk, D), T a multiple of ``block``.
+    Returns (out like q in float32, logsumexp (B, Hk, G, T))."""
+    b, t, hk, g, d = q.shape
+    scale = 1.0 / (d ** 0.5)
+    n = t // block
+
+    def q_block(_, i):
+        q_blk = lax.dynamic_slice_in_dim(q, i * block, block, axis=1)
+
+        def k_block(j, carry):
+            m, l, acc = carry
+            k_blk = lax.dynamic_slice_in_dim(k, j * block, block, axis=1)
+            v_blk = lax.dynamic_slice_in_dim(v, j * block, block, axis=1)
+            s = _pair_scores(q_blk, k_blk, i * block, j * block, window,
+                             scale)
+            m_new = jnp.maximum(m, s.max(axis=-1))
+            # a window's first block may hold no key that a late query
+            # of the block sees: its row is all -inf until a later block
+            safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            p = jnp.exp(s - safe[..., None])
+            alpha = jnp.exp(jnp.where(jnp.isfinite(m), m - safe, -jnp.inf))
+            l = l * alpha + p.sum(axis=-1)
+            pv = jnp.einsum("bhgqk,bkhd->bhgqd", p.astype(v.dtype), v_blk,
+                            preferred_element_type=jnp.float32)
+            return m_new, l, acc * alpha[..., None] + pv
+
+        m0 = jnp.full((b, hk, g, block), -jnp.inf, jnp.float32)
+        l0 = jnp.zeros((b, hk, g, block), jnp.float32)
+        acc0 = jnp.zeros((b, hk, g, block, d), jnp.float32)
+        m, l, acc = lax.fori_loop(_first_key_block(i, block, window), i + 1,
+                                  k_block, (m0, l0, acc0))
+        out = acc / l[..., None]
+        return None, (out, m + jnp.log(l))
+
+    _, (out, lse) = lax.scan(q_block, None, jnp.arange(n))
+    # (n, B, Hk, G, block, D) -> (B, T, Hk, G, D)
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, t, hk, g, d)
+    lse = lse.transpose(1, 2, 3, 0, 4).reshape(b, hk, g, t)
+    return out, lse
+
+
+def _blockwise_bwd(q, k, v, out, lse, dout, window, block):
+    """One pass over the visible (query block, key block) pairs: the
+    probabilities come back from the saved logsumexp, dq is complete after
+    a query block's inner loop, dk and dv are accumulated in place."""
+    b, t, hk, g, d = q.shape
+    scale = 1.0 / (d ** 0.5)
+    n = t // block
+    delta = jnp.sum(dout * out, axis=-1).transpose(0, 2, 3, 1)  # float32
+    cd = q.dtype
+    dout = dout.astype(cd)
+
+    def q_block(carry, i):
+        dk, dv = carry
+        q_blk = lax.dynamic_slice_in_dim(q, i * block, block, axis=1)
+        do_blk = lax.dynamic_slice_in_dim(dout, i * block, block, axis=1)
+        lse_blk = lax.dynamic_slice_in_dim(lse, i * block, block, axis=3)
+        delta_blk = lax.dynamic_slice_in_dim(delta, i * block, block, axis=3)
+
+        def k_block(j, inner):
+            dq_blk, dk, dv = inner
+            k_blk = lax.dynamic_slice_in_dim(k, j * block, block, axis=1)
+            v_blk = lax.dynamic_slice_in_dim(v, j * block, block, axis=1)
+            s = _pair_scores(q_blk, k_blk, i * block, j * block, window,
+                             scale)
+            p = jnp.exp(s - lse_blk[..., None])
+            dp = jnp.einsum("bqhgd,bkhd->bhgqk", do_blk, v_blk,
+                            preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_blk[..., None]) * scale).astype(cd)
+            dv_j = jnp.einsum("bhgqk,bqhgd->bkhd", p.astype(cd), do_blk,
+                              preferred_element_type=jnp.float32)
+            dk_j = jnp.einsum("bhgqk,bqhgd->bkhd", ds, q_blk,
+                              preferred_element_type=jnp.float32)
+            dq_blk = dq_blk + jnp.einsum(
+                "bhgqk,bkhd->bqhgd", ds, k_blk,
+                preferred_element_type=jnp.float32)
+            add = lambda full, part: lax.dynamic_update_slice_in_dim(
+                full, lax.dynamic_slice_in_dim(full, j * block, block, 1)
+                + part, j * block, axis=1)
+            return dq_blk, add(dk, dk_j), add(dv, dv_j)
+
+        dq0 = jnp.zeros((b, block, hk, g, d), jnp.float32)
+        dq_blk, dk, dv = lax.fori_loop(
+            _first_key_block(i, block, window), i + 1, k_block,
+            (dq0, dk, dv))
+        return (dk, dv), dq_blk
+
+    zeros = jnp.zeros((b, t, hk, d), jnp.float32)
+    (dk, dv), dq = lax.scan(q_block, (zeros, zeros), jnp.arange(n))
+    dq = dq.transpose(1, 0, 2, 3, 4, 5).reshape(b, t, hk, g, d)
+    return dq, dk, dv
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _blockwise(q, k, v, window, block):
+    return _blockwise_fwd(q, k, v, window, block)[0]
+
+
+def _blockwise_vjp_fwd(q, k, v, window, block):
+    out, lse = _blockwise_fwd(q, k, v, window, block)
+    return out, (q, k, v, out, lse)
+
+
+def _blockwise_vjp_bwd(window, block, res, dout):
+    q, k, v, out, lse = res
+    dq, dk, dv = _blockwise_bwd(q, k, v, out, lse, dout, window, block)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_blockwise.defvjp(_blockwise_vjp_fwd, _blockwise_vjp_bwd)
+
+
+def blockwise_attention(q, k, v, window=None, block=512):
+    """Causal attention with grouped heads, block by block with an online
+    softmax: no (T, T) array exists, forward or backward, and key blocks
+    that no query of a block may see are skipped, so a window layer costs
+    T x (window + block) and a full one T x T / 2.
+
+    q: (B, T, Hq, D); k, v: (B, T, Hk, D) with Hq a multiple of Hk (query
+    head h reads key head h // (Hq // Hk)); ``window``: query i sees keys
+    i - window < j <= i (None: every j <= i).  Operands multiply in their
+    own dtype and accumulate in float32; the softmax statistics are
+    float32.  Returns float32 (B, T, Hq, D)."""
+    b, t, hq, d = q.shape
+    hk = k.shape[2]
+    block = min(block, t)
+    pad = -t % block
+    if pad:
+        # padded keys lie after every real query; padded queries are cut
+        widen = lambda a: jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        q, k, v = widen(q), widen(k), widen(v)
+    out = _blockwise(q.reshape(b, t + pad, hk, hq // hk, d), k, v, window,
+                     block)
+    return out.reshape(b, t + pad, hq, d)[:, :t]

@@ -64,6 +64,19 @@ def _seed():
     yield
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _default_dtype_policy():
+    """The dtype policy is process-global, and a benchmark runner sets its
+    configuration's (BF16_COMPUTE): a rehearsal of one left it set, so the
+    value tests that the same worker ran afterwards (test_moe_layer,
+    test_perf_rewrites, test_pipeline_optimizer under ``--dist loadfile``)
+    compared bf16 products with float32 ones.  Every test file starts from
+    the default policy."""
+    from bigdl_tpu import tensor as bt
+    bt.set_policy(bt.FP32)
+    yield
+
+
 @pytest.fixture
 def obs_run_dir(tmp_path):
     """A configured obs run directory (JSONL sink under tmp_path), torn
@@ -78,3 +91,4 @@ def obs_run_dir(tmp_path):
 @pytest.fixture
 def rng():
     return np.random.RandomState(0)
+

@@ -1,0 +1,454 @@
+"""The afmoe decoder (``models/afmoe.py`` and the modules it forced) against
+the plain reference (``benchmark/reference/afmoe.py``) at a small size:
+seeded weights, float32 policy."""
+import copy
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import bigdl_tpu.nn as nn
+from benchmark.program import from_program_tree, to_program_tree
+from benchmark.reference import afmoe as ref
+from bigdl_tpu import tensor as bt
+from bigdl_tpu.models.afmoe import AfmoeLM
+from bigdl_tpu.nn.module import Context
+
+CFG = {
+    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
+    "head_dim": 16, "intermediate_size": 96, "moe_intermediate_size": 32,
+    "router_experts": 16, "num_experts_per_tok": 4, "num_shared_experts": 1,
+    "experts_held": [0, 1], "num_hidden_layers": 5, "num_dense_layers": 1,
+    "layer_types": ["sliding_attention", "sliding_attention",
+                    "full_attention", "sliding_attention",
+                    "sliding_attention"],
+    "sliding_window": 8, "rope_theta": 10000, "rms_norm_eps": 1e-5,
+    "route_norm": True, "route_scale": 2.826, "vocab_size": 50,
+    "assumed": {"initializer_std": 0.02},
+    "optimizer": {"learning_rate": 0.05, "momentum": 0.9, "dampening": 0.0,
+                  "weight_decay": 0.0},
+}
+T = 32
+TOL = 2e-5
+
+
+@pytest.fixture(autouse=True)
+def _float32_policy():
+    before = bt.policy()
+    bt.set_policy(bt.FP32)
+    yield
+    bt.set_policy(before)
+
+
+def build(cfg=CFG):
+    return AfmoeLM(
+        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
+        layer_types=cfg["layer_types"],
+        num_dense_layers=cfg["num_dense_layers"],
+        num_attention_heads=cfg["num_attention_heads"],
+        num_key_value_heads=cfg["num_key_value_heads"],
+        head_dim=cfg["head_dim"],
+        intermediate_size=cfg["intermediate_size"],
+        moe_intermediate_size=cfg["moe_intermediate_size"],
+        num_experts=cfg["router_experts"],
+        num_experts_per_tok=cfg["num_experts_per_tok"],
+        experts_held=cfg["experts_held"],
+        sliding_window=cfg["sliding_window"], rope_theta=cfg["rope_theta"],
+        rms_norm_eps=cfg["rms_norm_eps"], route_norm=cfg["route_norm"],
+        route_scale=cfg["route_scale"])
+
+
+def tokens(seed, n=2, t=T, vocab=CFG["vocab_size"]):
+    rng = np.random.RandomState(seed)
+    ids = rng.randint(1, vocab + 1, (n, t + 1)).astype(np.float32)
+    return ids[:, :-1], ids[:, 1:]
+
+
+def run(module, params, x, state=None):
+    y, _ = module.apply(params, x, module.state() if state is None else state,
+                        Context(training=True, key=jax.random.PRNGKey(0)))
+    return y
+
+
+def close(a, b, tol=TOL):
+    a, b = np.asarray(a), np.asarray(b)
+    scale = max(float(np.abs(b).max()), 1e-6)
+    assert float(np.abs(a - b).max()) <= tol * scale, \
+        (float(np.abs(a - b).max()), scale)
+
+
+def test_rms_norm_matches_reference():
+    m = nn.RMSNorm(24, eps=1e-5)
+    w = jax.random.normal(jax.random.PRNGKey(1), (24,)) + 1.0
+    x = jax.random.normal(jax.random.PRNGKey(2), (3, 5, 24)) * 3.0
+    params = {"~": {"weight": w}}
+    f = lambda p, x_: jnp.sum(jnp.sin(run(m, p, x_)))
+    g = lambda w_, x_: jnp.sum(jnp.sin(ref.rms_norm(x_, w_, 1e-5)))
+    close(run(m, params, x), ref.rms_norm(x, w, 1e-5))
+    gp, gx = jax.grad(f, (0, 1))(params, x)
+    rw, rx = jax.grad(g, (0, 1))(w, x)
+    close(gp["~"]["weight"], rw)
+    close(gx, rx)
+
+
+def test_rotary_matches_reference():
+    from bigdl_tpu.nn.attention import rotary
+    x = jax.random.normal(jax.random.PRNGKey(3), (2, 19, 3, 16))
+    for b in range(2):
+        close(rotary(x, 10000.0)[b], ref.rotate(x[b], 10000.0))
+    # position 0 is left as it is, and a rotation keeps every pair's norm
+    close(rotary(x)[:, 0], x[:, 0])
+    close(jnp.linalg.norm(rotary(x), axis=-1), jnp.linalg.norm(x, axis=-1))
+
+
+def _attention_pair(kind, t, block):
+    cfg = CFG
+    window = cfg["sliding_window"] if kind == "sliding_attention" else None
+    m = nn.GatedGroupedQueryAttention(
+        cfg["hidden_size"], cfg["num_attention_heads"],
+        cfg["num_key_value_heads"], cfg["head_dim"], window=window,
+        rotary_base=cfg["rope_theta"] if window else None)
+    m.block = block
+    key = jax.random.PRNGKey(7)
+    own = {}
+    for n, (name, leaf) in enumerate(m.params()["~"].items()):
+        std = 1.0 if leaf.ndim == 1 else 0.2
+        own[name] = std * jax.random.normal(jax.random.fold_in(key, n),
+                                            leaf.shape)
+    x = jax.random.normal(jax.random.fold_in(key, 99),
+                          (2, t, cfg["hidden_size"]))
+    return m, own, x
+
+
+@pytest.mark.parametrize("kind,t,block", [
+    ("sliding_attention", 37, 8),       # T several windows, not a multiple
+    ("sliding_attention", 32, 16),
+    ("full_attention", 37, 8),
+    ("full_attention", 24, 512),        # one block
+])
+def test_attention_matches_reference(kind, t, block):
+    m, own, x = _attention_pair(kind, t, block)
+    c = jax.random.normal(jax.random.PRNGKey(11), x.shape)
+    f = lambda p, x_: jnp.sum(run(m, {"~": p}, x_) * c)
+    g = lambda p, x_: sum(
+        jnp.sum(ref.attention(p, x_[b], CFG, kind) * c[b]) for b in range(2))
+    for b in range(2):
+        close(run(m, {"~": own}, x)[b], ref.attention(own, x[b], CFG, kind))
+    gp, gx = jax.jit(jax.grad(f, (0, 1)))(own, x)
+    rp, rx = jax.grad(g, (0, 1))(own, x)
+    close(gx, rx)
+    for name in ref.ATTENTION_PARTS:
+        close(gp[name], rp[name])
+
+
+def test_window_layer_ignores_keys_outside_the_window():
+    m, own, x = _attention_pair("sliding_attention", 40, 8)
+    y = run(m, {"~": own}, x)
+    moved = x.at[:, :8].add(5.0)        # keys 0..7: unseen from query 15 on
+    y2 = run(m, {"~": own}, moved)
+    close(y2[:, 15:], y[:, 15:])
+    assert float(jnp.abs(y2[:, :15] - y[:, :15]).max()) > 1e-3
+
+
+def _moe(held, chunk=None, cfg=CFG):
+    m = nn.DroplessMoE(cfg["hidden_size"], cfg["moe_intermediate_size"],
+                       cfg["router_experts"], cfg["num_experts_per_tok"],
+                       experts_held=held, route_scale=cfg["route_scale"],
+                       shared_hidden=cfg["moe_intermediate_size"],
+                       chunk_rows=chunk)
+    return m
+
+
+def _whole_moe_params(key, cfg=CFG, router_std=1.0):
+    d, h, e = (cfg["hidden_size"], cfg["moe_intermediate_size"],
+               cfg["router_experts"])
+    k = lambda n: jax.random.fold_in(key, n)
+    return {"router": router_std * jax.random.normal(k(0), (d, e)),
+            "w_gate": 0.3 * jax.random.normal(k(1), (e, d, h)),
+            "w_up": 0.3 * jax.random.normal(k(2), (e, d, h)),
+            "w_down": 0.3 * jax.random.normal(k(3), (e, h, d)),
+            "shared_gate": 0.3 * jax.random.normal(k(4), (d, h)),
+            "shared_up": 0.3 * jax.random.normal(k(5), (d, h)),
+            "shared_down": 0.3 * jax.random.normal(k(6), (h, d))}
+
+
+def _share(whole, held):
+    take = jnp.asarray(list(held))
+    return dict(whole, w_gate=whole["w_gate"][take],
+                w_up=whole["w_up"][take], w_down=whole["w_down"][take])
+
+
+@pytest.mark.parametrize("held,chunk", [
+    ((0, 1), None), ((3, 9, 4, 15), 16), (tuple(range(16)), 24)])
+def test_expert_layer_matches_reference(held, chunk):
+    whole = _whole_moe_params(jax.random.PRNGKey(5))
+    own = _share(whole, held)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, T, CFG["hidden_size"]))
+    m = _moe(held, chunk)
+    c = jax.random.normal(jax.random.PRNGKey(8), x.shape)
+    f = lambda p, x_: jnp.sum(run(m, {"~": p}, x_) * c)
+    g = lambda p, x_: jnp.sum(ref.expert_layer(
+        p, x_.reshape(-1, x_.shape[-1]), CFG,
+        experts_held=held).reshape(x_.shape) * c)
+    close(run(m, {"~": own}, x), ref.expert_layer(
+        own, x.reshape(-1, x.shape[-1]), CFG,
+        experts_held=held).reshape(x.shape))
+    gp, gx = jax.jit(jax.grad(f, (0, 1)))(own, x)
+    rp, rx = jax.grad(g, (0, 1))(own, x)
+    close(gx, rx)
+    for name in own:
+        close(gp[name], rp[name])
+
+
+def test_the_shares_add_up_to_the_whole_layer():
+    """The 8 shares' outputs, the shared expert counted once, sum to the
+    uncut reference's output for the whole layer."""
+    whole = _whole_moe_params(jax.random.PRNGKey(15))
+    x = jax.random.normal(jax.random.PRNGKey(16), (T, CFG["hidden_size"]))
+    shared = ref.swiglu(x, whole["shared_gate"], whole["shared_up"],
+                        whole["shared_down"])
+    total = shared
+    for chip in range(8):
+        held = (2 * chip, 2 * chip + 1)
+        y = run(_moe(held), {"~": _share(whole, held)}, x[None])[0]
+        total = total + (y - shared)
+    uncut = ref.expert_layer(whole, x, CFG, experts_held=range(16))
+    close(total, uncut, 5e-5)
+
+
+def test_no_token_is_dropped_under_total_imbalance():
+    """A router that sends every token to one held expert: that expert
+    gets all T assignments (16 times the even share) and none is lost."""
+    whole = _whole_moe_params(jax.random.PRNGKey(25), router_std=0.01)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(26),
+                                  (T, CFG["hidden_size"])))
+    whole["router"] = whole["router"].at[:, 1].set(1.0)
+    held = (0, 1)
+    m = _moe(held, chunk=8)
+    y, state = m.apply({"~": _share(whole, held)}, x[None], m.state(),
+                       Context(training=True))
+    close(y[0], ref.expert_layer(_share(whole, held), x, CFG,
+                                 experts_held=held))
+    assert float(state["~"]["tap_expert_max"]) == T
+    dropped = ref.expert_layer(_share(whole, held), x, CFG,
+                               fault="capacity", experts_held=held)
+    assert float(jnp.abs(dropped - y[0]).max()) > 1e-2
+
+
+def test_route_bias_enters_the_choice_only():
+    whole = _whole_moe_params(jax.random.PRNGKey(35))
+    held = tuple(range(16))
+    x = jax.random.normal(jax.random.PRNGKey(36), (1, T, CFG["hidden_size"]))
+    m = _moe(held)
+    state = m.state()
+    y0 = run(m, {"~": whole}, x, state)
+    biased = {"~": dict(state["~"], route_bias=jnp.zeros((16,)).at[5].set(
+        10.0))}
+    y1, ns = m.apply({"~": whole}, x, biased, Context(training=True))
+    assert float(jnp.abs(y1 - y0).max()) > 1e-3      # expert 5 is chosen
+    # ... with its own score as the weight, and the bias takes no gradient
+    assert float(ns["~"]["tap_assignments_held"]) == T * 4
+    from bigdl_tpu.parallel.moe import sigmoid_topk_routing
+    idx, w = sigmoid_topk_routing(x[0], whole["router"],
+                                  biased["~"]["route_bias"], 4, True, 1.0)
+    assert bool((idx == 5).any(axis=-1).all())
+    close(w.sum(axis=-1), jnp.ones((T,)))
+
+
+def _laid_in(model, cfg, key):
+    p0 = ref.init_params(key, cfg)
+    names = list(ref.param_shapes(cfg))
+    model.load_params(to_program_tree(model.params(), p0, names))
+    return p0, names
+
+
+def _reference_loss_and_grad(p, ids, targets, cfg=CFG, **kw):
+    block = ref.make_block_grad(cfg, **kw)
+    outs = [block(p, jnp.asarray(ids[b]), jnp.asarray(targets[b]))
+            for b in range(len(ids))]
+    loss = sum(o[0] for o in outs) / len(outs)
+    grad = jax.tree_util.tree_map(lambda *g: sum(g) / len(outs),
+                                  *[o[1] for o in outs])
+    return float(loss), grad
+
+
+def test_whole_model_matches_reference():
+    model = build()
+    p0, names = _laid_in(model, CFG, jax.random.PRNGKey(42))
+    ids, targets = tokens(0)
+    crit = nn.TimeDistributedCriterion(nn.ClassNLLCriterion(), True)
+    for b in range(2):
+        close(run(model, model.params(), ids)[b],
+              ref.forward(p0, jnp.asarray(ids[b]), CFG), 1e-4)
+
+    def loss(p):
+        return crit.apply_loss(run(model, p, ids), targets)
+
+    value, grads = jax.jit(jax.value_and_grad(loss))(model.params())
+    ref_loss, ref_grad = _reference_loss_and_grad(p0, ids, targets)
+    assert abs(float(value) - ref_loss) < 1e-5 * ref_loss
+    got = from_program_tree(grads, names)
+    for name in names:
+        for part in ref_grad[name]:
+            close(got[name][part], ref_grad[name][part], 2e-4)
+
+
+def test_recompute_changes_nothing():
+    inner = nn.Sequential(nn.RMSNorm(8), nn.GatedLinearUnit(8, 12))
+    wrapped = nn.Recompute(inner)
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, 8))
+    params = {"0": inner.params(), "~": {}}
+    f = lambda p: jnp.sum(run(wrapped, p, x) ** 2)
+    g = lambda p: jnp.sum(run(inner, p, x) ** 2)
+    close(f(params), g(params["0"]))
+    a, b = jax.grad(f)(params)["0"], jax.grad(g)(params["0"])
+    for x_, y_ in zip(jax.tree_util.tree_leaves(a),
+                      jax.tree_util.tree_leaves(b)):
+        close(x_, y_)
+    assert "checkpoint" in str(jax.make_jaxpr(jax.grad(f))(params)) or \
+        "remat" in str(jax.make_jaxpr(jax.grad(f))(params))
+
+
+def test_three_steps_through_the_optimizer_match_reference():
+    from bigdl_tpu.dataset import DataSet, Sample
+    from bigdl_tpu.dataset.transformer import SampleToBatch, Transformer
+    from bigdl_tpu.obs import events
+    from bigdl_tpu.optim import SGD, Optimizer
+    from bigdl_tpu.optim import trigger as Trigger
+    from bigdl_tpu.utils.table import T as Tbl
+
+    model = build()
+    p0, names = _laid_in(model, CFG, jax.random.PRNGKey(43))
+    ids, targets = tokens(3, n=6)
+    samples = [Sample(ids[i], targets[i]) for i in range(6)]
+    seen = []
+
+    class Tap(Transformer):             # which sequences each batch held
+        def __call__(self, iterator):
+            for batch in iterator:
+                seen.append([int(np.flatnonzero(
+                    (ids == row).all(axis=1))[0])
+                    for row in np.asarray(batch.data)])
+                yield batch
+
+    opt_cfg = CFG["optimizer"]
+    log = events.configure(None, ring=1000)
+    try:
+        opt = Optimizer(
+            model, DataSet.array(samples) >> SampleToBatch(2) >> Tap(),
+            nn.TimeDistributedCriterion(nn.ClassNLLCriterion(), True),
+            optim_method=SGD(),
+            state=Tbl(learningRate=opt_cfg["learning_rate"],
+                      momentum=opt_cfg["momentum"],
+                      dampening=opt_cfg["dampening"]),
+            end_trigger=Trigger.max_iteration(3))
+        opt.set_taps(cadence=1)
+        opt.optimize()
+        steps = [e for e in log.ring_events() if e["type"] == "step"]
+    finally:
+        events.configure(None)
+    losses = [e["loss"] for e in steps]
+    assert len(losses) == 3
+    assert any("assignments_held/3" in e.get("taps", {}) for e in steps)
+
+    params = p0
+    velocity = jax.tree_util.tree_map(jnp.zeros_like, p0)
+    for k, rows in enumerate(seen[:3]):
+        loss, grad = _reference_loss_and_grad(params, ids[rows],
+                                              targets[rows])
+        assert abs(losses[k] - loss) < 2e-5 * loss
+        params, velocity = ref.sgd_update(params, velocity, grad, opt_cfg)
+    got = from_program_tree(model.params(), names)
+    for name in names:
+        for part in params[name]:
+            close(got[name][part], params[name][part], 1e-4)
+
+
+@pytest.mark.parametrize("fault", ["full_window", "top_k_less",
+                                   "no_route_scale"])
+def test_the_window_and_the_routing_matter(fault):
+    """The reference with the fault differs from the sound one by far more
+    than the tolerance the comparisons above use."""
+    p0 = ref.init_params(jax.random.PRNGKey(44), CFG)
+    ids, _ = tokens(5, n=1)
+    sound = ref.forward(p0, jnp.asarray(ids[0]), CFG)
+    other = ref.forward(p0, jnp.asarray(ids[0]), CFG, fault=fault)
+    gap = float(jnp.abs(sound - other).max() / jnp.abs(sound).max())
+    assert gap > 100 * TOL, gap
+
+
+def test_reference_in_chunks_and_recomputed_is_the_same_reference():
+    p0 = ref.init_params(jax.random.PRNGKey(45), CFG)
+    ids, targets = tokens(6, n=1)
+    a = _reference_loss_and_grad(p0, ids, targets)
+    b = _reference_loss_and_grad(p0, ids, targets, query_chunk=8, remat=True)
+    assert abs(a[0] - b[0]) < 1e-6 * a[0]
+    for x, y in zip(jax.tree_util.tree_leaves(a[1]),
+                    jax.tree_util.tree_leaves(b[1])):
+        close(x, y, 1e-5)
+
+
+def test_gradient_buffers_are_made_on_first_use():
+    m = nn.GatedLinearUnit(8, 12)
+    assert all(g is None for g in m._grads.values())
+    x = jax.random.normal(jax.random.PRNGKey(1), (3, 8))
+    m.backward(x, jnp.ones_like(m.forward(x)))
+    assert all(g is not None and g.shape == m._params[k].shape
+               for k, g in m._grads.items())
+    assert float(jnp.abs(m.parameters()[1][0]).sum()) > 0
+    m.zero_grad_parameters()
+    assert float(jnp.abs(m.get_parameters()[1]).sum()) == 0
+
+
+def test_a_model_that_fills_the_device_is_taken_over_not_copied(monkeypatch):
+    from bigdl_tpu.dataset import DataSet, Sample
+    from bigdl_tpu.dataset.transformer import SampleToBatch
+    from bigdl_tpu.optim import SGD, Optimizer, local_optimizer
+    from bigdl_tpu.optim import trigger as Trigger
+    from bigdl_tpu.utils.table import T as Tbl
+
+    from bigdl_tpu.utils.random import set_seed
+
+    ids, targets = tokens(9, n=4, t=8)
+
+    def fails_before_step_3(state):
+        if state.get("neval", 0) > 2:
+            raise RuntimeError("the run breaks off")
+        return False
+
+    def train(fills, end=Trigger.max_iteration(2)):
+        monkeypatch.setattr(local_optimizer, "_fills_device",
+                            lambda tree: fills)
+        set_seed(1)             # every run draws the same batches
+        samples = [Sample(ids[i], targets[i]) for i in range(4)]
+        cfg = dict(CFG, num_hidden_layers=2,
+                   layer_types=CFG["layer_types"][:2])
+        model = build(cfg)
+        _laid_in(model, cfg, jax.random.PRNGKey(46))
+        before = jax.tree_util.tree_leaves(model.params())
+        try:
+            Optimizer(model, DataSet.array(samples) >> SampleToBatch(2),
+                      nn.TimeDistributedCriterion(nn.ClassNLLCriterion(),
+                                                  True),
+                      optim_method=SGD(), state=Tbl(learningRate=0.05),
+                      end_trigger=end).optimize()
+        except RuntimeError as e:
+            assert "breaks off" in str(e)
+        return before, jax.tree_util.tree_leaves(model.params())
+
+    kept, copied = train(False)
+    gone, taken = train(True)
+    assert not any(a.is_deleted() for a in kept)
+    assert all(a.is_deleted() for a in gone if a.size > 64)
+    for a, b in zip(copied, taken):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # a run that breaks off after two steps: a model taken over holds the
+    # two steps' result, usable; a copied one still its initial weights
+    started, left = train(False, Trigger.Trigger(fails_before_step_3))
+    for a, b in zip(started, left):
+        assert a is b
+    gone, left = train(True, Trigger.Trigger(fails_before_step_3))
+    assert all(a.is_deleted() for a in gone if a.size > 64)
+    for a, b in zip(taken, left):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

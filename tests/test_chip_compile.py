@@ -279,3 +279,53 @@ def test_conv_then_lrn_compiles_at_small_batch(one_chip, batch, mode):
         jax.jit(fn).lower(abstract, x).compile()
     finally:
         bt.set_policy(before)
+
+
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_afmoe_expert_layer_compiles_at_published_widths(one_chip, kind):
+    """One decoder layer of ``models/afmoe.py`` at Trinity-Mini's widths
+    (hidden 2048, 32/4 heads of 128, window 2048, 16 of 128 experts of
+    width 1024, top-8), one 8,192-token sequence, forward and backward
+    under bf16 compute: the block loops of the attention core hold no
+    T x T array, and the grouped products become the TPU's own
+    ragged-dot kernels."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu import tensor as bt
+    from bigdl_tpu.models.afmoe import afmoe_layer
+    from bigdl_tpu.nn import init as init_
+    from bigdl_tpu.nn.module import Context
+
+    t, d = 8192, 2048
+    drawn = init_.normal_on_device
+    init_.normal_on_device = lambda shape, std=None: np.broadcast_to(
+        np.float32(0), shape)           # shapes only: nothing is run
+    try:
+        ffn = nn.DroplessMoE(d, 1024, 128, 8, experts_held=range(16),
+                             route_scale=2.826, shared_hidden=1024)
+        layer = afmoe_layer(d, 32, 4, 128, ffn,
+                            2048 if kind == "sliding_attention" else None,
+                            10000.0, 1e-5)
+    finally:
+        init_.normal_on_device = drawn
+    abstract = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+
+    def loss(p, s, x):
+        y, _ = layer.apply(p, x, s, Context(training=True,
+                                            key=jax.random.PRNGKey(0)))
+        return y.sum()
+
+    before = bt.policy()
+    bt.set_policy(bt.BF16_COMPUTE)
+    try:
+        compiled = jax.jit(jax.grad(loss)).lower(
+            abstract(layer.params()), abstract(layer.state()),
+            jax.ShapeDtypeStruct((1, t, d), F32, sharding=one_chip)
+        ).compile()
+    finally:
+        bt.set_policy(before)
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert f"{t},{t}]" not in text                  # no T x T array
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
