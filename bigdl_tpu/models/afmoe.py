@@ -10,7 +10,12 @@ with ``Optimizer(model, dataset, TimeDistributedCriterion(
 ClassNLLCriterion(), True), SGD()).optimize()`` on (B, T) 1-based token ids
 and (B, T) 1-based targets, like ``TransformerLM``.  Each decoder layer is
 wrapped in ``nn.Recompute``: the backward pass holds one layer's
-activations at a time.
+activations at a time, plus each layer's core output and expert sum (the
+attention core's output and logsumexp and the routed experts' sum are
+marked where they are made, ``parallel/ring_attention.py`` and
+``parallel/moe.py``: each costs a loop over blocks or chunks to make again
+and one array a layer to keep, so a layer's recomputation redoes the
+projections, norms, router and sort, and neither loop).
 """
 from __future__ import annotations
 

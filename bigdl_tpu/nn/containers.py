@@ -8,9 +8,13 @@ MapTable, Bottle (Bottle.scala).  Recurrent/TimeDistributed live in
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
+
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from bigdl_tpu.nn.module import Container, Module
 from bigdl_tpu.utils.table import Table
@@ -40,20 +44,68 @@ class Sequential(Container):
         return x, new_state
 
 
+KEPT = "kept/"    # the one place that defines what a ``Recompute`` keeps
+
+
+def kept(x, label: str):
+    """Mark ``x`` as dear to recompute and small to keep: a ``Recompute``
+    around the caller hands it to the backward pass instead of computing
+    it again.  The code that owns a loop decides this, where the cost is
+    known.  Under no ``Recompute`` (bare, or under a ``jax.checkpoint``
+    with no policy) the mark is an identity that lowers to nothing."""
+    return checkpoint_name(x, KEPT + label)
+
+
+_kept_report = contextvars.ContextVar("kept_report", default=None)
+
+
+@contextlib.contextmanager
+def kept_report():
+    """What the ``Recompute``s differentiated inside the block kept:
+    ``{"kept": {label: bytes over all of them}, "layers": how many}``,
+    complete when the gradient has been traced (the optimizer's step
+    logs it as its ``recompute`` event)."""
+    report = {"kept": {}, "layers": 0}
+    token = _kept_report.set(report)
+    try:
+        yield report
+    finally:
+        _kept_report.reset(token)
+
+
 class Recompute(Container):
     """Run the one child under ``jax.checkpoint``: the backward pass keeps
-    the child's input and computes its inner activations again.  A model
-    whose layers are wrapped one by one holds one layer's activations at a
-    time (``LocalOptimizer.set_gradient_checkpointing`` wraps the whole
-    model, which bounds nothing by layer).  Same parameters, same result."""
+    the child's input, every array a module inside has marked with
+    :func:`kept` (the attention core's output and logsumexp, the routed
+    experts' sum: a loop each to make, one array to hold), and computes
+    the other inner activations again.  A model whose layers are wrapped
+    one by one holds one layer's activations at a time, plus each layer's
+    marked arrays (``LocalOptimizer.set_gradient_checkpointing`` wraps the
+    whole model, which bounds nothing by layer and keeps no mark).  Same
+    parameters, same result, same gradient."""
 
     def __init__(self, module: Module):
         super().__init__(module)
 
     def apply(self, params, x, state, ctx):
+        report = _kept_report.get()
+        if report is not None:
+            report["layers"] += 1
+
+        def keep_marked(prim, *avals, **eqn):
+            # asked once per operation when the gradient is traced
+            if prim.name != "name" or not eqn["name"].startswith(KEPT):
+                return False
+            if report is not None:
+                label = eqn["name"][len(KEPT):]
+                a, = avals
+                report["kept"][label] = (report["kept"].get(label, 0)
+                                         + a.size * a.dtype.itemsize)
+            return True
+
         inner = jax.checkpoint(
             lambda p, x_, s: _child_apply(self, 0, {"0": p}, x_, {"0": s},
-                                          ctx))
+                                          ctx), policy=keep_marked)
         y, ns = inner(params["0"], x, state["0"])
         return y, dict(state, **{"0": ns})
 

@@ -49,8 +49,10 @@ logger = logging.getLogger("bigdl_tpu.obs")
 #: documents).  v7: the `forensic` type landed (obs/recorder.py
 #: tail-based request forensics, FORENSIC_KINDS: one anomalous
 #: request's full flight-recorder record + ring-neighbor context —
-#: the non-fatal analog of the crash bundle).
-SCHEMA_VERSION = 7
+#: the non-fatal analog of the crash bundle).  v8: the `recompute` type
+#: landed (what a traced train step's `nn.Recompute` layers keep for
+#: the backward pass besides their inputs).
+SCHEMA_VERSION = 8
 
 ENV_OBS = "BIGDL_OBS"
 ENV_DIR = "BIGDL_OBS_DIR"
@@ -71,6 +73,11 @@ EVENT_TYPES = {
     # the input pipeline failed to hide the fetch: the consuming loop
     # waited `seconds` for the prefetch queue at `step` (queue was empty)
     "prefetch_stall": ("step", "seconds"),
+    # written when a train step is traced whose model has `nn.Recompute`
+    # layers: `kept` maps each label a module marked (`nn.containers.
+    # kept`) to the bytes held of it over all `layers`; {} says the
+    # backward pass recomputes everything
+    "recompute": ("kept", "layers"),
     # serving lifecycle/telemetry (serve/engine.py, serve/decode.py,
     # serve/router.py, serve/cluster.py): kind-specific required fields
     # in SERVE_KINDS below; error events carry the failed request count

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bigdl_tpu.dataset import prefetch as prefetch_mod
+from bigdl_tpu.nn.containers import kept_report
 from bigdl_tpu.nn.module import Context
 from bigdl_tpu.obs import events as obs_events
 from bigdl_tpu.obs import taps as obs_taps
@@ -361,7 +362,12 @@ class LocalOptimizer:
                 with jax.named_scope(type(criterion).__name__):
                     return criterion.apply_loss(out, y), ns
 
-            (loss, new_net_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+            # runs when the step is traced: what the model's Recomputes
+            # kept for the backward pass goes into the log, once a trace
+            with kept_report() as report:
+                (loss, new_net_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+            if report["layers"]:
+                obs_events.emit("recompute", **report)
             # the scopes name the update's and the taps' operations in a
             # profile (metadata only, as the module scopes of nn/containers)
             with jax.named_scope("optim-update"):

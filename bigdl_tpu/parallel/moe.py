@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from bigdl_tpu.nn.containers import kept
+
 
 def expert_capacity(n_tokens: int, n_experts: int,
                     capacity_factor: float) -> int:
@@ -255,6 +257,9 @@ def _grouped_vjp_fwd(x, w_gate, w_up, w_down, weights, order, sizes, chunk,
                      top_k, cast):
     out = _grouped_fwd(x, w_gate, w_up, w_down, weights, order, sizes,
                        chunk, top_k, cast)
+    # a whole routed pass to make, one row a token to hold: a Recompute
+    # around the layer keeps the sum, and its recomputation runs no chunk
+    out = kept(out, "experts_out")
     return out, (x, w_gate, w_up, w_down, weights, order, sizes)
 
 

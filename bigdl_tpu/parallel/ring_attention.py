@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
 
+from bigdl_tpu.nn.containers import kept
 from bigdl_tpu.parallel.collectives import pvary
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -253,6 +254,9 @@ def _blockwise(q, k, v, window, block):
 
 def _blockwise_vjp_fwd(q, k, v, window, block):
     out, lse = _blockwise_fwd(q, k, v, window, block)
+    # a block loop to make, 1/T of its scores to hold: a Recompute around
+    # the layer keeps them, and its recomputation runs no loop
+    out, lse = kept(out, "attention_out"), kept(lse, "attention_lse")
     return out, (q, k, v, out, lse)
 
 

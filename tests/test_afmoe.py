@@ -2,6 +2,7 @@
 the plain reference (``benchmark/reference/afmoe.py``) at a small size:
 seeded weights, float32 policy."""
 import copy
+import re
 
 import jax
 import jax.numpy as jnp
@@ -12,8 +13,9 @@ import bigdl_tpu.nn as nn
 from benchmark.program import from_program_tree, to_program_tree
 from benchmark.reference import afmoe as ref
 from bigdl_tpu import tensor as bt
-from bigdl_tpu.models.afmoe import AfmoeLM
+from bigdl_tpu.models.afmoe import AfmoeLM, afmoe_layer
 from bigdl_tpu.nn.module import Context
+from bigdl_tpu.obs import events
 
 CFG = {
     "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
@@ -294,20 +296,186 @@ def test_whole_model_matches_reference():
             close(got[name][part], ref_grad[name][part], 2e-4)
 
 
-def test_recompute_changes_nothing():
-    inner = nn.Sequential(nn.RMSNorm(8), nn.GatedLinearUnit(8, 12))
-    wrapped = nn.Recompute(inner)
-    x = jax.random.normal(jax.random.PRNGKey(1), (3, 8))
-    params = {"0": inner.params(), "~": {}}
-    f = lambda p: jnp.sum(run(wrapped, p, x) ** 2)
-    g = lambda p: jnp.sum(run(inner, p, x) ** 2)
-    close(f(params), g(params["0"]))
-    a, b = jax.grad(f)(params)["0"], jax.grad(g)(params["0"])
-    for x_, y_ in zip(jax.tree_util.tree_leaves(a),
-                      jax.tree_util.tree_leaves(b)):
-        close(x_, y_)
-    assert "checkpoint" in str(jax.make_jaxpr(jax.grad(f))(params)) or \
-        "remat" in str(jax.make_jaxpr(jax.grad(f))(params))
+def _attention_layer(kind):
+    m, own, _ = _attention_pair(kind, T, 8)
+    return m, {"~": own}
+
+
+def _expert_branch():
+    # the model's second branch: the closing norm needs the experts' sum
+    d = CFG["hidden_size"]
+    m = nn.Sequential(nn.RMSNorm(d), _moe((0, 1), 2 * T), nn.RMSNorm(d))
+    return m, None
+
+
+def _decoder_layer():
+    m = afmoe_layer(CFG["hidden_size"], 4, 2, 16, _moe((0, 1), 2 * T), 8,
+                    10000.0, 1e-5).modules[0]
+    return m, None
+
+
+def _unmarked():
+    return nn.Sequential(nn.RMSNorm(CFG["hidden_size"]),
+                         nn.GatedLinearUnit(CFG["hidden_size"], 12)), None
+
+
+# per case: the builder; loops (``while``) and grouped products
+# (``ragged_dot_general``) in the jaxpr of the gradient of the bare layer,
+# which are ``nn.Recompute``'s too, and under ``jax.checkpoint`` with no
+# policy (the ``Recompute`` of PR 28, ``set_gradient_checkpointing``); what
+# ``nn.Recompute`` keeps, label -> shape.  A core is a ``scan`` over query
+# blocks around a ``while`` over key blocks: forward, its recomputation,
+# backward = 3, of which Recompute drops the recomputation; a chunk loop
+# holds 3 products forward and 9 in its backward rule (3 again + 6).
+B2 = 2
+_CORE = {"attention_out": (B2, T, 2, 2, 16), "attention_lse": (B2, 2, 2, T)}
+_SUM = {"experts_out": (B2 * T, 64)}
+RECOMPUTE_CASES = {
+    "window": (lambda: _attention_layer("sliding_attention"),
+               (2, 0), (3, 0), _CORE),
+    "full": (lambda: _attention_layer("full_attention"),
+             (2, 0), (3, 0), _CORE),
+    "experts": (_expert_branch, (2, 12), (3, 15), _SUM),
+    "layer": (_decoder_layer, (4, 12), (6, 15), {**_CORE, **_SUM}),
+    "unmarked": (_unmarked, (0, 0), (0, 0), {}),
+}
+
+
+def _variants(build_layer):
+    """The loss through one layer, bare, under a plain ``jax.checkpoint``
+    and under ``nn.Recompute``: each from a closure of its own
+    (``jax.checkpoint`` caches a function's trace), all on the parameters
+    of the first."""
+    layer, params = build_layer()
+    params = layer.params() if params is None else params
+    wrapped = nn.Recompute(layer)
+    plain = jax.checkpoint(lambda p, x: run(layer, p, x))
+    return params, {
+        "bare": lambda p, x: jnp.sum(run(layer, p, x) ** 2),
+        "checkpoint": lambda p, x: jnp.sum(plain(p, x) ** 2),
+        "recompute": lambda p, x: jnp.sum(
+            run(wrapped, {"0": p, "~": {}}, x) ** 2),
+    }
+
+
+def _gradient_text(f, *args):
+    return str(jax.make_jaxpr(jax.grad(f))(*args))
+
+
+def _loops_and_products(text):
+    return (len(re.findall(r"\bwhile\[", text)),
+            len(re.findall(r"\bragged_dot_general\[", text)))
+
+
+@pytest.mark.parametrize("case", list(RECOMPUTE_CASES))
+def test_recompute_changes_nothing(case, capsys):
+    """``nn.Recompute`` gives the gradient of a plain ``jax.checkpoint``
+    bit for bit, runs the loops of the bare layer and no more, and keeps
+    the marked arrays and nothing else of the layer's inside."""
+    build_layer, loops, _, keeps = RECOMPUTE_CASES[case]
+    params, f = _variants(build_layer)
+    x = jax.random.normal(jax.random.PRNGKey(1), (B2, T, CFG["hidden_size"]))
+    close(f["recompute"](params, x), f["bare"](params, x))
+    with nn.containers.kept_report() as report:
+        got = jax.grad(f["recompute"])(params, x)
+    for a, b, c in zip(*[jax.tree_util.tree_leaves(g) for g in (
+            got, jax.grad(f["checkpoint"])(params, x),
+            jax.grad(f["bare"])(params, x))]):
+        assert np.array_equal(a, b)
+        close(a, c)
+    text = _gradient_text(f["recompute"], params, x)
+    assert "checkpoint" in text or "remat" in text
+    assert _loops_and_products(text) == loops
+    assert report == {"layers": 1, "kept": {
+        label: 4 * int(np.prod(shape)) for label, shape in keeps.items()}}
+    # the arrays the backward pass is handed: the layer's input, the
+    # parameters, constants of the routing, and the marked arrays
+    jax.ad_checkpoint.print_saved_residuals(f["recompute"], params, x)
+    inside = [line.split()[0] for line in capsys.readouterr().out.split("\n")
+              if " from the argument " not in line and line.strip()
+              and "from a constant" not in line and "<lambda>" not in line]
+    shapes = sorted("f32[%s]" % ",".join(map(str, s))
+                    for s in keeps.values())
+    assert sorted(inside) == shapes
+
+
+@pytest.mark.parametrize("case", list(RECOMPUTE_CASES))
+@pytest.mark.parametrize("how", ["bare", "checkpoint"])
+def test_the_mark_is_inert_outside_a_recompute(case, how):
+    """Bare, and under a ``jax.checkpoint`` with no policy (what
+    ``set_gradient_checkpointing`` and the pipeline stages build), a marked
+    layer's gradient holds the loops and products it held before the mark:
+    a plain checkpoint still runs every loop a third time."""
+    build_layer, bare, checkpoint, _ = RECOMPUTE_CASES[case]
+    params, f = _variants(build_layer)
+    x = jax.random.normal(jax.random.PRNGKey(1), (B2, T, CFG["hidden_size"]))
+    with nn.containers.kept_report() as report:
+        counted = _loops_and_products(_gradient_text(f[how], params, x))
+    assert counted == {"bare": bare, "checkpoint": checkpoint}[how]
+    assert report == {"layers": 0, "kept": {}}
+
+
+def test_the_mark_is_not_in_a_step_without_recompute():
+    """The Inception train step, lowered at a toy batch: no ``kept/`` name
+    and no ``name`` operation at all (the step's text is PR 28's)."""
+    from bigdl_tpu.models.inception import Inception_v1
+    from bigdl_tpu.optim import LocalOptimizer
+    from bigdl_tpu.utils.table import T as Tbl
+    model = Inception_v1(1000)
+    opt = LocalOptimizer(model, None, nn.ClassNLLCriterion())
+    opt.set_state(Tbl(learningRate=0.01, momentum=0.9))
+    step = opt._build_step()
+    shape = jax.ShapeDtypeStruct
+    like = lambda t: jax.tree_util.tree_map(
+        lambda a: shape(a.shape, a.dtype), t)
+    params = model.params()
+    log = events.configure(None, ring=100)
+    try:
+        text = step.jitted.lower(
+            like(params), like(model.state()),
+            like(opt.optim_method.init_state(params)),
+            shape((2, 3, 224, 224), jnp.float32), shape((2, 1), jnp.float32),
+            shape((), jnp.float32), like(jax.random.PRNGKey(0)),
+            opt._lr_scales_arg).as_text(debug_info=True)
+        logged = [e["type"] for e in log.ring_events()]
+    finally:
+        events.configure(None)
+    assert "kept/" not in text and "recompute" not in logged
+    assert len(text) > 100000
+
+
+def test_the_step_logs_what_its_recomputes_keep():
+    """A toy ``AfmoeLM`` through ``Optimizer``: tracing the step writes one
+    ``recompute`` event with every label's bytes over the five layers."""
+    from bigdl_tpu.dataset import DataSet, Sample
+    from bigdl_tpu.dataset.transformer import SampleToBatch
+    from bigdl_tpu.optim import SGD, Optimizer
+    from bigdl_tpu.optim import trigger as Trigger
+
+    ids, targets = tokens(5, n=4)
+    log = events.configure(None, ring=1000)
+    try:
+        Optimizer(build(),
+                  DataSet.array([Sample(ids[i], targets[i])
+                                 for i in range(4)]) >> SampleToBatch(2),
+                  nn.TimeDistributedCriterion(nn.ClassNLLCriterion(), True),
+                  optim_method=SGD(),
+                  end_trigger=Trigger.max_iteration(2)).optimize()
+        logged = log.ring_events()
+    finally:
+        events.configure(None)
+    kept = [e for e in logged if e["type"] == "recompute"]
+    assert len(kept) == 1 and events.validate_event(kept[0])
+    heads, d, hidden = (CFG["num_attention_heads"], CFG["head_dim"],
+                        CFG["hidden_size"])
+    assert kept[0]["layers"] == 5
+    assert kept[0]["kept"] == {
+        "attention_out": 5 * 2 * T * heads * d * 4,
+        "attention_lse": 5 * 2 * T * heads * 4,
+        "experts_out": 4 * 2 * T * hidden * 4}
+    types = [e["type"] for e in logged]
+    assert types.index("run_start") < types.index("recompute") \
+        < types.index("step")
 
 
 def test_three_steps_through_the_optimizer_match_reference():

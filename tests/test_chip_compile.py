@@ -14,6 +14,7 @@ file touches it at import or collection time, and every test of the file
 runs in the one worker that was handed the file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -287,8 +288,11 @@ def test_afmoe_expert_layer_compiles_at_published_widths(one_chip, kind):
     (hidden 2048, 32/4 heads of 128, window 2048, 16 of 128 experts of
     width 1024, top-8), one 8,192-token sequence, forward and backward
     under bf16 compute: the block loops of the attention core hold no
-    T x T array, and the grouped products become the TPU's own
-    ragged-dot kernels."""
+    T x T array, the grouped products become the TPU's own ragged-dot
+    kernels, and the layer's ``Recompute`` runs neither the core's loop
+    nor the routed pass again (a core is a loop in a loop: 2 + 2 for its
+    forward and backward, and a chunk loop each way; 9 with all three
+    recomputed, as before PR 30)."""
     import bigdl_tpu.nn as nn
     from bigdl_tpu import tensor as bt
     from bigdl_tpu.models.afmoe import afmoe_layer
@@ -328,4 +332,5 @@ def test_afmoe_expert_layer_compiles_at_published_widths(one_chip, kind):
     text = compiled.as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
     assert f"{t},{t}]" not in text                  # no T x T array
+    assert len(re.findall(r" while\(", text)) == 6
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
