@@ -27,9 +27,9 @@ import bigdl_tpu.nn as nn
 # `_PALLAS_SPEC_VERIFY` the speculative (k+1)-query verify window.
 # PR-2 adoption discipline: no chip verdict yet → both default OFF;
 # True adopts on TPU, "interpret" forces the Pallas interpreter
-# (CPU equivalence tests and the perf_smoke drill).  The staged A/Bs
-# live in tools/ab_device_clock.py and `tools/bench_serve.py
-# --decode-sweep --attn-kernel`.
+# (CPU equivalence tests and the perf_smoke drill).  The staged A/B is
+# `tools/bench_serve.py --decode-sweep --attn-kernel`; the verdicts are
+# ROADMAP S6's (paged attention) and C5's (spec verify).
 _PALLAS_PAGED_ATTN = False
 _PALLAS_SPEC_VERIFY = False
 

@@ -110,9 +110,6 @@ class Recompute(Container):
         return y, dict(state, **{"0": ns})
 
 
-_MERGE_1X1 = True  # kill switch for the merged-pointwise-head execution
-
-
 class Concat(Container):
     """Apply every branch to the same input, concatenate outputs along
     ``dimension`` (1-based, ref Concat.scala).
@@ -155,7 +152,7 @@ class Concat(Container):
         return plan if len(plan) >= 2 else []
 
     def apply(self, params, x, state, ctx):
-        plan = self._merge_plan() if _MERGE_1X1 else []
+        plan = self._merge_plan()
         if plan and hasattr(x, "ndim") and x.ndim == 4:
             return self._apply_merged(params, x, state, ctx, plan)
         outs = []

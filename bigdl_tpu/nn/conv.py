@@ -27,11 +27,6 @@ from bigdl_tpu.utils.random import RNG
 _DN = ("NCHW", "OIHW", "NCHW")
 
 
-_DOT_1X1 = False  # REJECTED default: isolated 1.7-2.1x wins, end-to-end
-# loss (Inception 26.30 -> 27.92, ResNet-50 32.31 -> 32.59 ms/step) —
-# see the dot-1x1 comment in _conv and PERF_NOTES round 5
-
-
 def _conv(x, w, stride, padding, *, lhs_dilation=None, rhs_dilation=None, groups=1):
     # Both operands cast to the compute dtype (bf16 feeds the MXU at full
     # rate; accumulation is f32 inside the MXU regardless), output cast back.
@@ -40,27 +35,10 @@ def _conv(x, w, stride, padding, *, lhs_dilation=None, rhs_dilation=None, groups
     # formulation with pet=f32 in all three convs, despite a 1.7x win on an
     # isolated chained-conv microbench, measured 4-12% SLOWER end-to-end on
     # Inception-v1/VGG-16 training steps (PERF_NOTES.md), so it was removed.
+    # A stride-1 1x1 conv as a channel GEMM (lax.dot_general) went the same
+    # way: 1.7-2.1x faster alone, Inception 26.30 -> 27.92 ms/step in the
+    # model, where it breaks the conv/ReLU/concat fusions (PERF_NOTES round 5).
     p = policy()
-    if (_DOT_1X1 and x.ndim == 4 and w.shape[2:] == (1, 1)
-            and tuple(stride) == (1, 1) and groups == 1
-            and lhs_dilation in (None, (1, 1))
-            and rhs_dilation in (None, (1, 1))
-            and (isinstance(padding, str)  # k=1: SAME == VALID == zero pad
-                 or all(lo == 0 and hi == 0 for lo, hi in padding))):
-        # A stride-1 1x1 conv IS a channel GEMM.  Isolated, this form
-        # measured 1.7-2.1x faster than the conv emitter on the worst
-        # ResNet 1x1-bwd shapes and never worse on any tested 1x1, bit-
-        # exact (tools/ab_conv_form.py).  END-TO-END it LOSES: Inception
-        # 26.30 -> 27.92, ResNet-50 32.31 -> 32.59 ms/step device-busy —
-        # the emitter's 1x1s fuse with the surrounding BN/ReLU/concat
-        # eltwise and the dot+transpose breaks those fusions (the same
-        # isolated-win/in-context-loss pattern as round 4's pet=f32
-        # experiment).  Kept OFF as measured evidence, PERF_NOTES r5.
-        co, ci = w.shape[0], w.shape[1]
-        y = lax.dot_general(p.cast_compute(w).reshape(co, ci),
-                            p.cast_compute(x),
-                            (((1,), (1,)), ((), ())))
-        return y.transpose(1, 0, 2, 3).astype(p.output_dtype)
     y = lax.conv_general_dilated(
         p.cast_compute(x), p.cast_compute(w),
         window_strides=stride, padding=padding,
@@ -76,45 +54,11 @@ def _maybe_batch(x):
     return x, False
 
 
-_S2D_STEM = True  # isolated win, end-to-end neutral on Inception (PERF_NOTES); helps ResNet/AlexNet stems
-
-_SPLIT_DB = False  # REJECTED default: measured 33.84 -> 37.64 ms/step
-
-
-@jax.custom_vjp
-def _bias_add(y, b):
-    """Bias add whose backward computes db in a standalone kernel —
-    kept as measured evidence, default OFF.
-
-    Hypothesis (VERDICT r3 lever a): the autodiff db is sum(g, (0,2,3))
-    — isolated it streams at 754 GB/s, but XLA folds it into the
-    multi-operand backward fusion around the conv which runs at ~270
-    GB/s effective, so splitting it out with ``optimization_barrier``
-    should win.  Device-clock A/B (round 4): Inception device-busy
-    33.84 -> **37.64 ms/step WITH the split** — the barrier forces a
-    second full read of every conv cotangent (~2 ms of standalone
-    reduces) while the fusions shrink by less; the "270 GB/s fusion"
-    was SHARING one read between dx and db all along.  The
-    isolated-vs-fused bandwidth comparison was the misleading number.
-    See PERF_NOTES round 4."""
-    return y + b[None, :, None, None]
-
-
-def _bias_add_fwd(y, b):
-    return _bias_add(y, b), None
-
-
-def _bias_add_bwd(_, g):
-    return g, jnp.sum(lax.optimization_barrier(g), axis=(0, 2, 3))
-
-
-_bias_add.defvjp(_bias_add_fwd, _bias_add_bwd)
-
-
 def bias_add(y, b):
-    """Conv bias add (NCHW); routed through the split-db custom VJP."""
-    if _SPLIT_DB:
-        return _bias_add(y, b)
+    """Conv bias add (NCHW).  Its autodiff db shares one read of the
+    cotangent with the conv's dx fusion; a custom VJP that split db into
+    its own kernel took the Inception step from 33.84 to 37.64 ms
+    (PERF_NOTES round 4, E1)."""
     return y + b[None, :, None, None]
 
 
@@ -252,7 +196,7 @@ class SpatialConvolution(TensorModule):
         x, was3d = _maybe_batch(x)
         s = self.stride_h
         if (s == self.stride_w and s > 1 and self.n_group == 1
-                and self.n_input_plane * s * s <= 64 and _S2D_STEM
+                and self.n_input_plane * s * s <= 64
                 and self.kernel_h > s and self.kernel_w > s):
             # stem convs (few input channels, strided): space-to-depth
             # rewrite fills the MXU contraction dim s^2 times better

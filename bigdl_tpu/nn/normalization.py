@@ -21,8 +21,6 @@ from bigdl_tpu.nn.module import TensorModule
 from bigdl_tpu.nn import init as init_
 from bigdl_tpu.tensor import policy
 
-_COMPUTE_DTYPE_NORM = True  # norm APPLY chains in the policy compute dtype
-
 
 def _apply_in_compute_dtype(x):
     """The big (N, …) normalize apply is pure bandwidth: run it in the
@@ -31,10 +29,7 @@ def _apply_in_compute_dtype(x):
     Shared by BatchNormalization and LayerNorm; measured −1.6 ms/step on
     ResNet-50 (PERF_NOTES round 4)."""
     p = policy()
-    if (_COMPUTE_DTYPE_NORM and p.compute_dtype != jnp.float32
-            and p.compute_dtype != x.dtype and x.dtype == jnp.float32):
-        return x.astype(p.compute_dtype)
-    return x
+    return x.astype(p.compute_dtype) if p.narrows(x) else x
 
 
 class BatchNormalization(TensorModule):
@@ -166,21 +161,14 @@ class SpatialCrossMapLRN(TensorModule):
         self.beta = beta
         self.k = k
 
-    _STENCIL = False  # module-level A/B switches, see tools/ab_step.py:
-    _SQRT_POW = True  # in-model grid measured rw-LRN+sqrt fastest (PERF_NOTES)
-    # Fused Pallas LRN (ops/pallas_kernels.lrn_channel).  The round-3
-    # form measured SLOWER than this XLA path on the v5e (538 vs
-    # 808-852 us fwd+bwd on the Inception C64 56x56 shape,
-    # device-clock).  Round 6 rebuilt the kernel pair — the forward now
-    # stores z (the window-sum denominator base) as the VJP residual so
-    # the backward is ONE pass with a single adjoint window sum, where
-    # the round-3 backward recomputed z from x — and the verdict must
-    # be re-measured (tools/ab_device_clock.py pallas_lrn variant).
-    # DEFAULT OFF until that device A/B wins; "interpret" forces the
-    # Pallas interpreter on any backend (tests).
+    # Candidate, undecided: the fused Pallas pair of PERF_NOTES round 6
+    # (ops/pallas_kernels.lrn_channel; the forward stores z so the backward
+    # is one pass with one adjoint window sum).  The round-3 form lost to
+    # the XLA path (538 vs 808-852 us fwd+bwd on the Inception C64 56x56
+    # shape).  Off until ROADMAP S5 times it on the chip and adopts or
+    # deletes it; "interpret" runs the Pallas interpreter on any backend
+    # (tests).
     _PALLAS = False
-    _ANALYTIC_VJP = True   # see _lrn below
-    _COMPUTE_DTYPE = True  # run the LRN chain in the policy compute dtype
 
     def _forward(self, P, x, S, ctx):
         if self._PALLAS and x.ndim == 4:
@@ -188,45 +176,17 @@ class SpatialCrossMapLRN(TensorModule):
                                                       lrn_channel)
             return lrn_channel(x, self.size, self.alpha, self.beta, self.k,
                                _interpreted(self._PALLAS)), None
-        lo = (self.size - 1) // 2
-        hi = self.size - 1 - lo
-        if self._ANALYTIC_VJP and not self._STENCIL:
-            p = policy()
-            cast = (self._COMPUTE_DTYPE
-                    and p.compute_dtype != jnp.float32
-                    and p.compute_dtype != x.dtype
-                    and x.dtype == jnp.float32)
-            if cast:
-                # LRN is pure bandwidth (window sums + eltwise): the
-                # compute-dtype cast halves its bytes like every matmul/
-                # conv operand under the policy.  Denominator error is
-                # bounded: z = k + (alpha/n) sum x^2 with k=1 dominates,
-                # and bf16 keeps ~3 significant digits of the small
-                # correction term.  Measured loss drift and device win:
-                # PERF_NOTES round 4.
-                y = _lrn(x.astype(p.compute_dtype), self.size, self.alpha,
-                         self.beta, self.k, self._SQRT_POW)
-                return y.astype(x.dtype), None
-            return _lrn(x, self.size, self.alpha, self.beta, self.k,
-                        self._SQRT_POW), None
-        if self._STENCIL:
-            # Cross-channel window sum as ``size`` shifted slice-adds — a
-            # pure elementwise stencil XLA fuses into one pass regardless
-            # of layout.  Measured alternatives (tools/ab_pool_lrn.py,
-            # PERF_NOTES.md): lax.reduce_window over the channel dim is
-            # slower at C=192, and a banded [C,C] matmul gets pattern-
-            # matched into a 1x1 NHWC conv whose backward runs at
-            # single-digit % of peak in-model.
-            c = x.shape[1]
-            sqp = jnp.pad(x * x, ((0, 0), (lo, hi), (0, 0), (0, 0)))
-            sq_sum = sum(lax.slice_in_dim(sqp, t, t + c, axis=1)
-                         for t in range(self.size))
-            z = self.k + (self.alpha / self.size) * sq_sum
-        else:
-            z = self.k + (self.alpha / self.size) * _lrn_window_sum(
-                x * x, self.size, lo, hi)
-        denom = _lrn_denom(z, self.beta, self.size, self._SQRT_POW)
-        return x / denom, None
+        # LRN is pure bandwidth (window sums + eltwise): the compute-dtype
+        # cast halves its bytes like every matmul/conv operand under the
+        # policy.  Denominator error is bounded: z = k + (alpha/n) sum x^2
+        # with k=1 dominates, and bf16 keeps ~3 significant digits of the
+        # small correction term.  Measured loss drift and device win:
+        # PERF_NOTES round 4.
+        p = policy()
+        cast = p.narrows(x)
+        y = _lrn(x.astype(p.compute_dtype) if cast else x, self.size,
+                 self.alpha, self.beta, self.k)
+        return (y.astype(x.dtype) if cast else y), None
 
 
 def _lrn_window_sum(v, size, lo, hi):
@@ -248,15 +208,15 @@ def _lrn_window_sum(v, size, lo, hi):
         padding=((0, 0), (lo, hi), (0, 0), (0, 0)))
 
 
-def _lrn_denom(z, beta, size, sqrt_pow):
-    if beta == 0.75 and sqrt_pow:
+def _lrn_denom(z, beta):
+    if beta == 0.75:
         # z^(3/4) = (z^(1/4))^3 via two sqrts: no exp/log transcendentals
         return jnp.sqrt(jnp.sqrt(z)) ** 3
     return z ** beta
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
-def _lrn(x, size, alpha, beta, k, sqrt_pow):
+@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _lrn(x, size, alpha, beta, k):
     """LRN with the ANALYTIC backward instead of the jvp-transpose one.
 
     y_c = x_c z_c^{-beta} with z = k + (alpha/n) sum_win x^2 gives
@@ -269,25 +229,27 @@ def _lrn(x, size, alpha, beta, k, sqrt_pow):
     mul/add fusion chain (measured 1.44 ms of reduce_window + 2.1 ms of
     fusions per Inception step, PROFILE round 3/4).  Device-clock A/B in
     PERF_NOTES round 4.  Residuals: x and z only; denom/y are two-sqrt
-    recomputes."""
+    recomputes.
+
+    Tried and lost as the window sum (PERF_NOTES round 2): ``size`` shifted
+    slice-adds (equal alone, 2-6 ms/step slower in the Inception step) and
+    a banded [C, C] matmul (XLA turns it into a 1x1 conv whose backward is
+    1.6x slower in-model)."""
+    return _lrn_fwd(x, size, alpha, beta, k)[0]
+
+
+def _lrn_fwd(x, size, alpha, beta, k):
     lo = (size - 1) // 2
     hi = size - 1 - lo
     z = k + (alpha / size) * _lrn_window_sum(x * x, size, lo, hi)
-    return x / _lrn_denom(z, beta, size, sqrt_pow)
+    return x / _lrn_denom(z, beta), (x, z)
 
 
-def _lrn_fwd(x, size, alpha, beta, k, sqrt_pow):
-    lo = (size - 1) // 2
-    hi = size - 1 - lo
-    z = k + (alpha / size) * _lrn_window_sum(x * x, size, lo, hi)
-    return x / _lrn_denom(z, beta, size, sqrt_pow), (x, z)
-
-
-def _lrn_bwd(size, alpha, beta, k, sqrt_pow, res, g):
+def _lrn_bwd(size, alpha, beta, k, res, g):
     x, z = res
     lo = (size - 1) // 2
     hi = size - 1 - lo
-    denom = _lrn_denom(z, beta, size, sqrt_pow)
+    denom = _lrn_denom(z, beta)
     # g*y/z^  — y recomputed as x/denom; z^{-beta-1} = 1/(z*denom)
     t = _lrn_window_sum(g * x / (z * denom), size, hi, lo)  # flipped window
     dx = g / denom - (2.0 * alpha * beta / size) * x * t

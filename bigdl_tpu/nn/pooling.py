@@ -17,47 +17,34 @@ from bigdl_tpu.nn.module import TensorModule, Module
 from bigdl_tpu.tensor import policy
 
 
-_COMPUTE_DTYPE_POOL = True  # run max pools in the policy compute dtype
-_RESHAPE_POOL = True  # exact non-overlapping max pools via reshape+max
-_SEPARABLE_POOL = False  # kxk max pool as (1,k)+(k,1) passes (A/B, r5)
-_NHWC_POOL = False  # windowed pools transposed to NHWC (A/B, r5)
-# Round-6 Mosaic kernel pair (ops/pallas_kernels.mosaic_maxpool2d):
-# argmax-storing forward + scatter-free gather backward replacing
-# select_and_scatter, C-on-lanes layout, strides via index maps + phase
-# folding.  DEFAULT OFF pending a device-clock A/B win (the adoption
-# rule every pool formulation has had to meet — PERF_NOTES round 6);
-# "interpret" forces the Pallas interpreter on any backend (tests).
+# Candidate, undecided: the Mosaic kernel pair of PERF_NOTES round 6
+# (ops/pallas_kernels.mosaic_maxpool2d: argmax-storing forward, scatter-free
+# gather backward in place of select_and_scatter).  Off until ROADMAP S5
+# times it on the chip and adopts or deletes it; "interpret" runs the Pallas
+# interpreter on any backend (tests).
 _PALLAS_POOL = False
 
 
 def _max_pool2d(x, window, strides, padding):
-    """Max pooling over NCHW via lax.reduce_window.
+    """Max pooling over NCHW: narrow, then reshape + max where the windows
+    tile the input, else ``lax.reduce_window``.
 
-    The backward is XLA's default select-and-scatter VJP.  Measured
-    alternatives on v5e (tools/ab_pool_lrn.py, PERF_NOTES.md): a custom
-    gather-stencil VJP with tie-splitting was 1.1-4x SLOWER on every
-    Inception pool shape in both f32 and bf16 — select-and-scatter on TPU
-    already runs near HBM bandwidth, so it is kept.
+    Under a reduced-precision policy a float32 input is pooled in the
+    compute dtype and cast back (max of bf16 values = bf16 of the f32 max,
+    so only rounding-level tie routing can differ): the window ops are pure
+    bandwidth, and half the bytes took the Inception step from 33.62 to
+    28.60 ms (PERF_NOTES round 4, E4).
 
-    Under a reduced-precision compute policy the pool runs in the
-    COMPUTE dtype (max of bf16 values = bf16 of the f32 max, so only
-    rounding-level tie routing can differ): the window ops are pure
-    bandwidth, and halving the bytes measured 1.85x faster isolated
-    (f32 0.349 -> bf16 0.189 ms on the 128x192x56x56 fwd+bwd) and
-    -2.6 ms/step on Inception (PERF_NOTES round 4) — the same
-    dtype decision the policy already makes for every matmul/conv
-    operand.
+    The windowed backward is XLA's select-and-scatter.  Tried and lost
+    against it on the v5e: a gather-stencil VJP with tie-splitting (1.1-4x
+    slower on every Inception pool shape, PERF_NOTES round 2), two 1-D
+    passes (+3% on the Inception step) and an NHWC transpose (+1.2%; both
+    PERF_NOTES round 5).
     """
     kh, kw = window
     dh, dw = strides
     p = policy()
-    # gate on a reduced-precision policy being ACTIVE, not on any dtype
-    # mismatch: f64 inputs under the default FP32 policy must not be
-    # silently downcast, and bf16 inputs must not be upcast
-    cast = (_COMPUTE_DTYPE_POOL
-            and p.compute_dtype != jnp.float32
-            and p.compute_dtype != x.dtype
-            and x.dtype == jnp.float32)
+    cast = p.narrows(x)
     xin = x.astype(p.compute_dtype) if cast else x
     n, c, h, w = xin.shape
     if _PALLAS_POOL:
@@ -66,8 +53,7 @@ def _max_pool2d(x, window, strides, padding):
         if interp or _on_tpu():
             y = mosaic_maxpool2d(xin, window, strides, padding, interp)
             return y.astype(x.dtype) if cast else y
-    if (_RESHAPE_POOL and (kh, kw) == (dh, dw)
-            and padding == ((0, 0), (0, 0))
+    if ((kh, kw) == (dh, dw) and padding == ((0, 0), (0, 0))
             and h % kh == 0 and w % kw == 0):
         # Exact non-overlapping pool: windows tile the input, so the
         # reduce is a plain reshape+max — no window machinery forward,
@@ -78,31 +64,6 @@ def _max_pool2d(x, window, strides, padding):
         # element — an equally valid subgradient with the same
         # per-window mass; documented in porting guide #6.
         y = xin.reshape(n, c, h // kh, kh, w // kw, kw).max(axis=(3, 5))
-    elif _SEPARABLE_POOL and kh > 1 and kw > 1:
-        # separable rectangle: max over (kh,kw) == max over rows of the
-        # max over columns; two 1-D windows whose select-and-scatter
-        # backwards each route over k elements instead of k*k
-        y = lax.reduce_window(
-            xin, np.array(-np.inf, xin.dtype), lax.max,
-            window_dimensions=(1, 1, 1, kw),
-            window_strides=(1, 1, 1, dw),
-            padding=((0, 0), (0, 0), (0, 0), padding[1]))
-        y = lax.reduce_window(
-            y, np.array(-np.inf, xin.dtype), lax.max,
-            window_dimensions=(1, 1, kh, 1),
-            window_strides=(1, 1, dh, 1),
-            padding=((0, 0), (0, 0), padding[0], (0, 0)))
-    elif _NHWC_POOL:
-        # channels on the 128-wide lane dim instead of the (often
-        # half-empty) W dim: the select-and-scatter backward is the
-        # zero-FLOP bandwidth sink these layouts decide
-        y = lax.reduce_window(
-            xin.transpose(0, 2, 3, 1), np.array(-np.inf, xin.dtype),
-            lax.max,
-            window_dimensions=(1, kh, kw, 1),
-            window_strides=(1, dh, dw, 1),
-            padding=((0, 0),) + padding + ((0, 0),))
-        y = y.transpose(0, 3, 1, 2)
     else:
         y = lax.reduce_window(
             xin, np.array(-np.inf, xin.dtype), lax.max,

@@ -32,7 +32,8 @@ from bigdl_tpu.utils.table import Table
 # the Pallas interpreter on any backend (tests).  The kernels compute
 # gates/carries in f32, so they only replace the scan when the policy's
 # output dtype is f32 (FP32/BF16_COMPUTE); BF16_ACT keeps the scan,
-# whose gates round through bf16.
+# whose gates round through bf16.  Adopted; no benchmark cell runs a
+# recurrent train step yet, so ROADMAP C3 gives the chip's verdict.
 _PALLAS_BILSTM = True
 # Multi-timestep blocking (round 6): timesteps per kernel grid step for
 # ALL five recurrence paths (LSTM/Bi-LSTM/GRU/BiGRU/RNN).  >1 amortizes
@@ -41,7 +42,7 @@ _PALLAS_BILSTM = True
 # serial dh chain is untouched — it is the real dependency).  Exact
 # math (time axis zero-padded; weight-grad f32 summation order
 # differs).  DEFAULT 1 (= round-5 behavior) pending a device-clock A/B
-# win, per the adoption rule (PERF_NOTES round 6).
+# win, per the adoption rule (PERF_NOTES round 6): ROADMAP C3 gives it.
 _BLOCK_T = 1
 
 
@@ -145,9 +146,9 @@ class LSTMCell(Cell):
     # is FASTER and ships in BiRecurrent._apply_fused_lstm (PERF_NOTES
     # round 3 "LSTM").  The single-direction path here keeps the
     # concat-gemm body (simplest form; the win comes from direction
-    # batching, which needs the bidirectional wrapper).  A full Pallas
-    # scan kernel (ops/pallas_kernels.lstm_scan) measured within 1% of
-    # lax.scan and stays retired.
+    # batching, which needs the bidirectional wrapper).  A Pallas scan
+    # kernel with one grid step a timestep measured within 1% of lax.scan
+    # (PERF_NOTES round 2) and was deleted.
 
 
 class GRUCell(Cell):

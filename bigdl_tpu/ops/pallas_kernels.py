@@ -2,14 +2,8 @@
 
 The device-side hot loops of the reference's native layer (mkl.c vector
 math / axpy / scal) compile through XLA; Pallas covers the cases where
-hand-fusion still wins:
-
-- ``fused_sgd``: momentum-SGD parameter update as ONE pass over HBM
-  (read p, g, v -> write p', v').  The unfused update streams the tensors
-  multiple times; for the flat multi-MB parameter vector of a large model
-  this is pure HBM bandwidth, exactly the regime a fused elementwise
-  kernel owns.  The reference's analogue is the fp16-compressed parallel
-  update loop (FP16CompressedTensor.parallel add/scal).
+hand-fusion still wins (the recurrence kernels) or is a candidate waiting
+for its measurement (max pool, LRN, the decode attention kernels).
 
 On non-TPU backends the kernels run through the Pallas interpreter
 (``interpret=True``) so tests exercise the same code path on the CPU mesh.
@@ -23,10 +17,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-_LANE = 128
-_BLOCK = 64 * 1024  # elements per grid step (256 KiB f32 — fits VMEM easily)
-
 
 def _on_tpu() -> bool:
     """True on a TPU backend, False on the CPU (where callers take the
@@ -53,300 +43,6 @@ def _out_struct(shape, dtype, *operands):
     of a kernel traced under ``check_vma=True`` (empty outside one)."""
     vma = frozenset().union(*(jax.typeof(a).vma for a in operands))
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-
-
-def _make_sgd_kernel(nesterov: bool):
-    def kernel(p_ref, g_ref, v_ref, h_ref, p_out, v_out):
-        """g~ = g + wd*p; with momentum: v' = mom*v + (1-damp)*g~ and
-        p' = p - lr*(g~ + mom*v' if nesterov else v'); with mom == 0 the
-        unfused path's semantics hold exactly — velocity untouched, step
-        = g~ (dampening ignored).  One VMEM pass.
-        h_ref holds [lr, momentum, weight_decay, dampening] in SMEM."""
-        lr, mom, wd, damp = h_ref[0], h_ref[1], h_ref[2], h_ref[3]
-        has_mom = (mom != 0.0).astype(p_ref.dtype)
-        g = g_ref[:] + wd * p_ref[:]
-        v_new = mom * v_ref[:] + (1.0 - has_mom * damp) * g
-        # mom==0: keep stored velocity, step with plain g
-        v_out[:] = has_mom * v_new + (1.0 - has_mom) * v_ref[:]
-        d = g + mom * v_new if nesterov else v_new
-        p_out[:] = p_ref[:] - lr * (has_mom * d + (1.0 - has_mom) * g)
-    return kernel
-
-
-_SGD_KERNELS = {False: _make_sgd_kernel(False), True: _make_sgd_kernel(True)}
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "nesterov"))
-def _fused_sgd_flat(p, g, v, hyper4, interpret=False, nesterov=False):
-    n = p.shape[0]
-    # pad to a whole number of blocks (grid must be static)
-    padded = ((n + _BLOCK - 1) // _BLOCK) * _BLOCK
-    if padded != n:
-        pad = padded - n
-        p = jnp.concatenate([p, jnp.zeros(pad, p.dtype)])
-        g = jnp.concatenate([g, jnp.zeros(pad, g.dtype)])
-        v = jnp.concatenate([v, jnp.zeros(pad, v.dtype)])
-    grid = padded // _BLOCK
-    p2, v2 = pl.pallas_call(
-        _SGD_KERNELS[nesterov],
-        out_shape=(_out_struct((padded,), p.dtype, p, g, v),
-                   _out_struct((padded,), v.dtype, p, g, v)),
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec((_BLOCK,), lambda i: (i,), memory_space=pltpu.VMEM),
-            pl.BlockSpec((_BLOCK,), lambda i: (i,), memory_space=pltpu.VMEM),
-            pl.BlockSpec((_BLOCK,), lambda i: (i,), memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=(
-            pl.BlockSpec((_BLOCK,), lambda i: (i,), memory_space=pltpu.VMEM),
-            pl.BlockSpec((_BLOCK,), lambda i: (i,), memory_space=pltpu.VMEM),
-        ),
-        interpret=interpret,
-    )(p, g, v, hyper4)
-    return p2[:n], v2[:n]
-
-
-def fused_sgd(params, grads, velocity, lr, momentum=0.0, weight_decay=0.0,
-              dampening=0.0, nesterov=False):
-    """Fused momentum-SGD update over pytrees.
-
-    Flattens each leaf to 1D and runs the single-pass Pallas kernel;
-    returns (new_params, new_velocity).  Uses the interpreter off-TPU.
-    """
-    interpret = not _on_tpu()
-    hyper4 = jnp.asarray([lr, momentum, weight_decay, dampening], jnp.float32)
-
-    def leaf(p, g, v):
-        shape = p.shape
-        p2, v2 = _fused_sgd_flat(p.reshape(-1), g.reshape(-1), v.reshape(-1),
-                                 hyper4, interpret=interpret,
-                                 nesterov=bool(nesterov))
-        return p2.reshape(shape), v2.reshape(shape)
-
-    flat = jax.tree_util.tree_map(leaf, params, grads, velocity)
-    new_p = jax.tree_util.tree_map(lambda pv: pv[0], flat,
-                                   is_leaf=lambda x: isinstance(x, tuple))
-    new_v = jax.tree_util.tree_map(lambda pv: pv[1], flat,
-                                   is_leaf=lambda x: isinstance(x, tuple))
-    return new_p, new_v
-
-
-# --------------------------------------------------------------- LSTM scan
-
-def _lstm_scan_kernel(zx_ref, wht_ref, h0_ref, c0_ref, out_ref, h_scr, c_scr):
-    """One grid step = one timestep; h/c live in VMEM scratch across steps.
-
-    zx_ref: (1, B, 4H) precomputed input projection for step t (already
-    includes the bias); wht_ref: (H, 4H) recurrent weight, transposed so
-    the in-kernel dot needs no transpose; out_ref: (1, B, H).
-    """
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
-    def _init():
-        h_scr[:] = h0_ref[:]
-        c_scr[:] = c0_ref[:]
-
-    h = h_scr[:]
-    c = c_scr[:]
-    z = zx_ref[0] + pl.dot(h.astype(wht_ref.dtype), wht_ref[:],
-                           ).astype(jnp.float32)
-    hdim = h.shape[-1]
-    i = jax.nn.sigmoid(z[:, :hdim])
-    f = jax.nn.sigmoid(z[:, hdim:2 * hdim])
-    g = jnp.tanh(z[:, 2 * hdim:3 * hdim])
-    o = jax.nn.sigmoid(z[:, 3 * hdim:])
-    c_new = f * c + i * g
-    h_new = o * jnp.tanh(c_new)
-    h_scr[:] = h_new
-    c_scr[:] = c_new
-    out_ref[0] = h_new
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def lstm_scan(zx, wht, h0, c0, interpret=False):
-    """Whole-recurrence Pallas kernel: zx (T, B, 4H) f32 (input projection
-    + bias, precomputed on the MXU outside), wht (H, 4H), h0/c0 (B, H) f32.
-    Returns hs (T, B, H).  Forward only — see PERF_NOTES for the measured
-    verdict vs lax.scan before wiring this anywhere hot.
-    """
-    t, b, h4 = zx.shape
-    h = h4 // 4
-    return pl.pallas_call(
-        _lstm_scan_kernel,
-        grid=(t,),
-        in_specs=[
-            pl.BlockSpec((1, b, h4), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((h, h4), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((b, h), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((b, h), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, b, h), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=_out_struct((t, b, h), jnp.float32, zx, wht, h0, c0),
-        scratch_shapes=[pltpu.VMEM((b, h), jnp.float32),
-                        pltpu.VMEM((b, h), jnp.float32)],
-        interpret=interpret,
-    )(zx, wht, h0, c0)
-
-
-# ------------------------------------------------------------- max pooling
-#
-# XLA's reduce_window forward and especially its select-and-scatter VJP
-# run far below HBM bandwidth on v5e (PROFILE_inception.md round 3: pool
-# fwd+bwd = 7.9 ms of a 40 ms Inception step at ZERO useful FLOPs, while
-# an isolated streaming op moves the same bytes ~5x faster).  These
-# kernels compute the same maxpool (and its first-max-wins gradient, the
-# select-and-scatter tie rule) as a handful of VMEM slice/max/add passes.
-#
-# Layout: NCHW collapsed to (N*C, H, W) rows; grid over row-blocks, each
-# block (BC, H, W) resident in VMEM with W on lanes and H on sublanes.
-# STRIDE-1 windows only: every window read/write is then a unit-stride
-# VMEM slice (Mosaic forbids strided slices and the reshape that a
-# phase-decomposition of strided pools would need); strided pools stay
-# on the XLA path, whose select-and-scatter cost is acceptable there
-# because strided windows barely overlap.
-
-
-def _mp_out_size(size, k, s, pl_, ph_):
-    return (size + pl_ + ph_ - k) // s + 1
-
-
-def _maxpool_fwd_kernel(x_ref, y_ref, *, kh, kw, pads):
-    (plh, phh), (plw, phw) = pads
-    # compute in f32: this Mosaic target lacks bf16 vector compares
-    x = x_ref[:].astype(jnp.float32)
-    neg = jnp.asarray(-jnp.inf, jnp.float32)
-    xp = jnp.pad(x, ((0, 0), (plh, phh), (plw, phw)), constant_values=neg)
-    bc = x.shape[0]
-    oh = x.shape[1] + plh + phh - kh + 1
-    ow = x.shape[2] + plw + phw - kw + 1
-    y = None
-    for i in range(kh):
-        for j in range(kw):
-            s = lax.slice(xp, (0, i, j), (bc, i + oh, j + ow))
-            y = s if y is None else jnp.maximum(y, s)
-    y_ref[:] = y.astype(y_ref.dtype)
-
-
-def _maxpool_bwd_kernel(x_ref, g_ref, dx_ref, *, kh, kw, pads):
-    """First-max-wins gradient (select-and-scatter scan order: row-major
-    over window offsets)."""
-    (plh, phh), (plw, phw) = pads
-    # compute in f32: this Mosaic target lacks bf16 vector compares
-    x = x_ref[:].astype(jnp.float32)
-    g = g_ref[:].astype(jnp.float32)
-    neg = jnp.asarray(-jnp.inf, jnp.float32)
-    xp = jnp.pad(x, ((0, 0), (plh, phh), (plw, phw)), constant_values=neg)
-    bc, hp, wp = xp.shape
-    oh, ow = g.shape[1], g.shape[2]
-    y = None
-    for i in range(kh):
-        for j in range(kw):
-            s = lax.slice(xp, (0, i, j), (bc, i + oh, j + ow))
-            y = s if y is None else jnp.maximum(y, s)
-    accp = jnp.zeros((bc, hp, wp), jnp.float32)
-    claimed = jnp.zeros(g.shape, jnp.bool_)
-    for i in range(kh):
-        for j in range(kw):
-            # re-slice instead of caching all kh*kw windows: keeps the
-            # kernel's live VMEM set to ~6 frames
-            s = lax.slice(xp, (0, i, j), (bc, i + oh, j + ow))
-            m = (s == y) & ~claimed
-            claimed = claimed | m
-            contrib = g * m.astype(jnp.float32)
-            accp = accp + lax.pad(contrib, jnp.asarray(0, jnp.float32),
-                                  ((0, 0, 0), (i, hp - oh - i, 0),
-                                   (j, wp - ow - j, 0)))
-    dx_ref[:] = lax.slice(accp, (0, plh, plw),
-                          (bc, plh + x.shape[1], plw + x.shape[2])
-                          ).astype(dx_ref.dtype)
-
-
-def _pick_bc(nc, h, w, arrays=8):
-    """Largest row-block that divides nc and keeps ~arrays f32 copies of
-    the (BC, H, W) frame under a 6 MB budget — deliberately well under
-    the ~16 MB scoped-VMEM limit to leave room for Mosaic's own
-    temporaries (frames are upcast to f32 inside the kernels)."""
-    budget = 6 * 1024 * 1024
-    lanes = -(-(w + 4) // 128) * 128  # Mosaic pads the lane dim to 128
-    per_row = (h + 4) * lanes * 4 * arrays
-    bc = max(1, min(nc, budget // max(per_row, 1)))
-    while nc % bc:
-        bc -= 1
-    return bc
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("window", "strides", "pads", "interpret"))
-def _maxpool_fwd_call(x, window, strides, pads, interpret=False):
-    n, c, h, w = x.shape
-    kh, kw = window
-    assert strides == (1, 1), "pallas maxpool2d is stride-1 only"
-    oh = _mp_out_size(h, kh, 1, *pads[0])
-    ow = _mp_out_size(w, kw, 1, *pads[1])
-    nc = n * c
-    bc = _pick_bc(nc, h, w)
-    xr = x.reshape(nc, h, w)
-    y = pl.pallas_call(
-        functools.partial(_maxpool_fwd_kernel, kh=kh, kw=kw, pads=pads),
-        grid=(nc // bc,),
-        in_specs=[pl.BlockSpec((bc, h, w), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((bc, oh, ow), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=_out_struct((nc, oh, ow), x.dtype, x),
-        interpret=interpret,
-    )(xr)
-    return y.reshape(n, c, oh, ow)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("window", "strides", "pads", "interpret"))
-def _maxpool_bwd_call(x, g, window, strides, pads, interpret=False):
-    n, c, h, w = x.shape
-    kh, kw = window
-    assert strides == (1, 1), "pallas maxpool2d is stride-1 only"
-    nc = n * c
-    oh, ow = g.shape[2], g.shape[3]
-    bc = _pick_bc(nc, h, w, arrays=8)
-    dx = pl.pallas_call(
-        functools.partial(_maxpool_bwd_kernel, kh=kh, kw=kw, pads=pads),
-        grid=(nc // bc,),
-        in_specs=[pl.BlockSpec((bc, h, w), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-                  pl.BlockSpec((bc, oh, ow), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((bc, h, w), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=_out_struct((nc, h, w), x.dtype, x, g),
-        interpret=interpret,
-    )(x.reshape(nc, h, w), g.reshape(nc, oh, ow))
-    return dx.reshape(n, c, h, w)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
-def maxpool2d(x, window, strides, pads, interpret=False):
-    """NCHW maxpool with Pallas forward AND first-max backward.
-
-    ``pads`` = ((lo_h, hi_h), (lo_w, hi_w)) explicit amounts (Torch
-    ceil-mode handled by the caller, nn/pooling.py).  Gradient tie rule
-    matches XLA select-and-scatter (first max in row-major window order).
-    """
-    return _maxpool_fwd_call(x, window, strides, pads, interpret)
-
-
-def _maxpool_vjp_fwd(x, window, strides, pads, interpret=False):
-    return _maxpool_fwd_call(x, window, strides, pads, interpret), x
-
-
-def _maxpool_vjp_bwd(window, strides, pads, interpret, x, g):
-    return (_maxpool_bwd_call(x, g, window, strides, pads, interpret),)
-
-
-maxpool2d.defvjp(_maxpool_vjp_fwd, _maxpool_vjp_bwd)
 
 
 # ---------------------------------------------------------------- LRN
@@ -499,8 +195,8 @@ lrn_channel.defvjp(_lrn_vjp_fwd, _lrn_vjp_bwd)
 # (bit-exact), fwd+bwd 5.0 -> 2.15 ms vs the scan's autodiff (grads
 # equal to ~1e-4 rel, f32 accumulation order).  Every previous Pallas
 # candidate here lost to the XLA emitter (PERF_NOTES rounds 2-5:
-# flash attention, maxpool, LRN stencil, fused SGD, single-direction
-# lstm_scan) — the recurrence wins because the emitter's while-loop
+# flash attention, maxpool, LRN stencil, fused SGD, a single-direction
+# step-per-grid-step LSTM scan) — the recurrence wins because the emitter's while-loop
 # carries per-step overhead the sequential grid amortizes, not because
 # Mosaic beats XLA on the math.
 
@@ -1054,7 +750,7 @@ rnn_recurrence.defvjp(_rnn_vjp_fwd, _rnn_vjp_bwd)
 #
 # Round-6 re-litigation of the round-3 pool rejections (ISSUE 2
 # tentpole a) with the round-5 kernel skills.  What is different from
-# the retired stride-1 ``maxpool2d`` above:
+# the round-3 stride-1 kernel (deleted; PERF_NOTES round 3):
 #
 #   * layout: channels ride the 128-lane dim (NHWC inside the kernel, W
 #     on sublanes) — Inception pools carry C=64..832, so the lanes are
@@ -1313,8 +1009,8 @@ def mosaic_maxpool2d(x, window, strides, pads, interpret=False):
 #
 # Adoption gate (PR-2 discipline): default OFF via
 # `models/transformer.py _PALLAS_PAGED_ATTN / _PALLAS_SPEC_VERIFY`; no
-# chip verdict yet → the staged A/B lives in tools/ab_device_clock.py
-# and `tools/bench_serve.py --decode-sweep --attn-kernel`.  Equivalence
+# chip verdict yet (ROADMAP C3 gives it) → the staged A/B is
+# `tools/bench_serve.py --decode-sweep --attn-kernel`.  Equivalence
 # vs the gathered-view reference is pinned in interpreter mode by
 # tests/test_paged_attention.py.
 # ---------------------------------------------------------------------------

@@ -53,16 +53,10 @@ class SGD(OptimMethod):
     """SGD with weight decay / momentum / dampening / nesterov + LR schedules
     (ref SGD.scala:26; schedules :128-210).
 
-    ``fused=True`` runs the update as a single-pass Pallas kernel over HBM
-    (read p,g,v -> write p',v' once) instead of the unfused tree_map chain.
-    Default off: measured ~2x slower than the unfused path on v5e (XLA
-    already fuses the elementwise update into the backward pass, which the
-    opaque Pallas call prevents — PERF_NOTES.md); kept for kernel-authoring
-    reference and for backends where XLA's fusion is weaker.
-    """
-
-    def __init__(self, fused: bool = False):
-        self.fused = fused
+    ``update`` is a chain of ``tree_map``s that XLA fuses into the backward
+    pass; a one-pass Pallas kernel over p, g, v measured ~2x slower on the
+    v5e, because the opaque call keeps XLA from doing that (PERF_NOTES
+    round 1)."""
 
     def optimize(self, feval, x, config: Table = None, state: Table = None):
         config = config if config is not None else T()
@@ -124,14 +118,6 @@ class SGD(OptimMethod):
         nesterov = hyper.get("nesterov", False)
         lr_scales = hyper.get("lr_scales")  # per-param lr multipliers
         # (ref SGD.scala "learningRates" Tensor: per-weight lr scaling)
-        if self.fused and lr_scales is None:
-            # one-HBM-pass Pallas update (ops/pallas_kernels.fused_sgd);
-            # matches the unfused math bit-for-bit per leaf
-            from bigdl_tpu.ops.pallas_kernels import fused_sgd
-            new_params, vel = fused_sgd(
-                params, grads, opt_state["velocity"], lr, momentum=mom,
-                weight_decay=wd, dampening=damp, nesterov=nesterov)
-            return new_params, {"velocity": vel}
         if wd != 0.0:
             grads = _tree_map(lambda g, p: g + wd * p, grads, params)
         vel = opt_state["velocity"]

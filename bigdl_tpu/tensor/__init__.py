@@ -46,6 +46,15 @@ class DTypePolicy:
     def cast_output(self, x):
         return jnp.asarray(x, self.output_dtype)
 
+    def narrows(self, x) -> bool:
+        """Whether a bandwidth-bound operator (pool, LRN, a norm's apply)
+        runs ``x`` in ``compute_dtype``: only a float32 array, only while a
+        reduced-precision policy is active.  A float64 input under FP32 is
+        never cut down and a bfloat16 one is never widened.  The caller
+        casts, so the cast is booked to the operator's own file."""
+        return (self.compute_dtype != jnp.float32
+                and x.dtype == jnp.float32)
+
 
 FP32 = DTypePolicy()
 BF16_COMPUTE = DTypePolicy(compute_dtype=jnp.bfloat16)

@@ -105,15 +105,6 @@ def test_recurrence_kernels(one_chip, cell, dtype, directions, mode):
     assert "tpu_custom_call" in compile_for(one_chip, fn, *shapes)
 
 
-def test_fused_sgd_multi_mb_vector(one_chip):
-    n = 7_000_003        # ragged: exercises the pad-to-block path
-    fn = functools.partial(pk._fused_sgd_flat, interpret=False,
-                           nesterov=False)
-    text = compile_for(one_chip, fn, ((n,), F32), ((n,), F32), ((n,), F32),
-                       ((4,), F32))
-    assert "tpu_custom_call" in text
-
-
 # Inception-v1's max pools at batch 128: the four 3x3/s2 ceil-mode pools
 # and the 3x3/s1 pool inside the inception modules
 POOLS = [((128, 64, 112, 112), (2, 2), ((0, 1), (0, 1))),

@@ -5,105 +5,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from bigdl_tpu.ops.pallas_kernels import fused_sgd
-
-
-@pytest.mark.perf
-def test_fused_sgd_matches_reference():
-    rs = np.random.RandomState(0)
-    params = {"w": jnp.asarray(rs.randn(300, 37), jnp.float32),
-              "b": jnp.asarray(rs.randn(5), jnp.float32)}
-    grads = jax.tree_util.tree_map(lambda x: jnp.full_like(x, 0.1), params)
-    vel = jax.tree_util.tree_map(lambda x: jnp.full_like(x, 0.2), params)
-    p2, v2 = fused_sgd(params, grads, vel, lr=0.5, momentum=0.9,
-                       weight_decay=0.01)
-    for k in params:
-        v_ref = 0.9 * 0.2 + (0.1 + 0.01 * np.asarray(params[k]))
-        p_ref = np.asarray(params[k]) - 0.5 * v_ref
-        np.testing.assert_allclose(np.asarray(p2[k]), p_ref, rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(v2[k]), v_ref, rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.perf
-def test_fused_sgd_optim_method_equivalence():
-    """SGD(fused=True).update == SGD().update across momentum/dampening/
-    nesterov combinations (the Pallas kernel runs interpreted off-TPU)."""
-    from bigdl_tpu.optim import SGD
-    rng = np.random.RandomState(0)
-    params = {"w": jnp.asarray(rng.randn(130, 7), jnp.float32),
-              "b": jnp.asarray(rng.randn(7), jnp.float32)}
-    grads = {"w": jnp.asarray(rng.randn(130, 7), jnp.float32),
-             "b": jnp.asarray(rng.randn(7), jnp.float32)}
-    for hyper in (
-        {"lr": 0.1},
-        {"lr": 0.1, "dampening": 0.9},  # mom==0: dampening must be ignored
-        {"lr": 0.1, "momentum": 0.9},
-        {"lr": 0.1, "momentum": 0.9, "dampening": 0.9},
-        {"lr": 0.1, "momentum": 0.9, "nesterov": True},
-        {"lr": 0.1, "momentum": 0.9, "weight_decay": 1e-3},
-    ):
-        plain, fused = SGD(), SGD(fused=True)
-        s_p = plain.init_state(params)
-        s_f = fused.init_state(params)
-        p_p, p_f = params, params
-        for _ in range(3):
-            p_p, s_p = plain.update(grads, s_p, p_p, hyper)
-            p_f, s_f = fused.update(grads, s_f, p_f, hyper)
-        for k in params:
-            np.testing.assert_allclose(np.asarray(p_p[k]), np.asarray(p_f[k]),
-                                       rtol=1e-5, atol=1e-6)
-            # velocity state must also agree (checkpoint handoff between
-            # fused and unfused paths)
-            np.testing.assert_allclose(np.asarray(s_p["velocity"][k]),
-                                       np.asarray(s_f["velocity"][k]),
-                                       rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.perf
-def test_fused_sgd_nonaligned_size():
-    """Sizes that do not divide the kernel block must round-trip exactly."""
-    p = {"x": jnp.arange(100.0)}
-    g = {"x": jnp.ones(100)}
-    v = {"x": jnp.zeros(100)}
-    p2, v2 = fused_sgd(p, g, v, lr=1.0)
-    np.testing.assert_allclose(np.asarray(p2["x"]), np.arange(100.0) - 1.0)
-
-
-@pytest.mark.perf
-class TestPallasMaxPool:
-    """Stride-1 Pallas maxpool (ops/pallas_kernels.maxpool2d): exact
-    forward + first-max-wins gradient vs reduce_window/select-and-scatter
-    autodiff, including tie positions (coarsely quantized inputs).  Kept
-    as measured evidence — NOT wired into nn/pooling.py (10-50x slower
-    than the XLA emitter on TPU, PERF_NOTES round 3)."""
-
-    @pytest.mark.parametrize("shape,win,pads", [
-        ((2, 4, 14, 14), (3, 3), ((1, 1), (1, 1))),
-        ((1, 2, 8, 8), (3, 3), ((1, 1), (1, 1))),
-        ((2, 3, 10, 12), (2, 2), ((0, 1), (1, 0))),
-    ])
-    def test_fwd_bwd_vs_xla(self, shape, win, pads):
-        from bigdl_tpu.ops.pallas_kernels import maxpool2d
-        interpret = jax.devices()[0].platform != "tpu"
-
-        def ref_pool(x):
-            return lax.reduce_window(
-                x, -jnp.inf, lax.max, (1, 1) + win, (1, 1, 1, 1),
-                ((0, 0), (0, 0)) + pads)
-
-        rs = np.random.RandomState(0)
-        x = jnp.asarray(np.round(rs.randn(*shape) * 2) / 2, jnp.float32)
-        y_ref = ref_pool(x)
-        y = maxpool2d(x, win, (1, 1), pads, interpret)
-        np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref))
-
-        g = jnp.asarray(rs.randn(*y_ref.shape).astype(np.float32))
-        d_ref = jax.grad(lambda v: (ref_pool(v) * g).sum())(x)
-        d = jax.grad(
-            lambda v: (maxpool2d(v, win, (1, 1), pads, interpret) * g).sum())(x)
-        np.testing.assert_allclose(np.asarray(d), np.asarray(d_ref),
-                                   rtol=1e-5, atol=1e-5)
-
 
 @pytest.mark.perf
 class TestPallasLRN:

@@ -214,7 +214,7 @@ LAYER_CASES = {
         lambda txs, P: F.conv2d(txs[0], P["weight"], P["bias"], stride=2,
                                 padding=1, groups=2), {}),
     "SpatialConvolution(stem7x7s2)": lambda: (
-        # exercises the space-to-depth rewrite (conv.py _S2D_STEM)
+        # exercises the space-to-depth stem (conv.py _space_to_depth_conv)
         nn.SpatialConvolution(3, 8, 7, 7, 2, 2, 3, 3), [x4(3, 16, 16)],
         lambda txs, P: F.conv2d(txs[0], P["weight"], P["bias"], stride=2,
                                 padding=3),

@@ -193,9 +193,9 @@ audits should not flag these):
   (one-dispatch lag vs the reference's in-flight `invokeAndWait2` timeout);
   threshold arithmetic, finished-count division, and the max-drop rejection
   follow the reference exactly (`optim/straggler.py`).
-- Maxpool gradient tie rule (`_RESHAPE_POOL`, `bigdl_tpu/nn/pooling.py`):
-  exact non-overlapping pools (kernel == stride, unpadded — the VGG/LeNet
-  shape) use a reshape+max formulation whose backward splits the gradient
+- Maxpool gradient tie rule (`bigdl_tpu/nn/pooling.py _max_pool2d`):
+  exact non-overlapping pools (kernel == stride, unpadded, windows tiling
+  the input — the VGG/LeNet shape) use a reshape+max formulation whose backward splits the gradient
   EVENLY among tied in-window maxima; the reference/Torch routes the full
   gradient to the FIRST maximum in row-major order (overlapping/padded
   pools here use XLA select-and-scatter: one winner, possibly a different
