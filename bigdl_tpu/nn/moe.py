@@ -93,26 +93,38 @@ class DroplessMoE(TensorModule):
     No capacity and no dropped token: the assignments are sorted by expert
     and run as grouped products (``parallel.moe.grouped_experts``),
     ``chunk_rows`` sorted assignments at a time for as many passes as hold
-    one.  A pass costs nearly the same however full it is and a second one
-    costs as much again, so the default is ``CHUNK_OF_EVEN_SHARE`` times
-    what the layer would hold were the routing even: a layer trained with
-    a balance term never comes near it and pays for the empty rows; a
-    builder who knows its routing passes its own.
+    one.  A second pass pays the fixed part of a pass again (the
+    scatter-adds' and the weight-gradient sums'), so the default is
+    ``CHUNK_OF_EVEN_SHARE`` times what the layer would hold were the
+    routing even; a builder who knows its routing passes its own.  What a
+    pass costs beyond that follows the rows it is run over, padding
+    included (all but the grouped products' kernels, which take the time
+    of the rows held): so the last chunk's pass runs over its first rows
+    only, the smallest of ``STEPS_OF_CHUNK`` equal steps of the chunk that
+    holds its assignments, and a layer far under its chunk pays for the
+    rows up to its next step, not for the chunk.
 
     Params: ``router`` (D, E); ``w_gate``, ``w_up`` (n_held, D, H) and
     ``w_down`` (n_held, H, D); ``shared_gate``/``shared_up`` (D, Hs) and
     ``shared_down`` (Hs, D) where ``shared_hidden``.  Buffers:
     ``route_bias`` (E,), added to the scores for the choice only, no
-    gradient; ``tap_assignments_held`` and ``tap_expert_max``, the last
-    call's count of assignments held here and its busiest expert's count
+    gradient; ``tap_assignments_held``, ``tap_expert_max`` and
+    ``tap_rows_moved``, the last call's count of assignments held here,
+    its busiest expert's count and the rows its passes ran over
     (``obs.taps.module_counters`` hands them to the step's taps)."""
 
     # from a v5e at hidden 2048 x 1024, 16 of 128 experts held, top-8,
-    # 16,384 tokens (PERF.md section 6, PR 28): randomly initialised layers
-    # with no balance term held 0.23 to 2.7 times the even share by seed
-    # and step; twice it was crossed, three times it was not, and its
-    # padded rows cost 7% of that model's step
-    CHUNK_OF_EVEN_SHARE = 3
+    # 16,384 tokens (PERF.md section 6, PRs 28 and 32): randomly initialised
+    # layers with no balance term held 0.07 to 2.7 times the even share by
+    # seed and step, most of them under it.  One layer's forward and
+    # backward cost 14.4 ms + 0.42 ms a thousand rows passed + 0.43 ms a
+    # thousand held, and a second pass 6.8 ms before its first row.  Passes
+    # of one or two even shares (a third pass for the rare layer over two)
+    # pay for 1.5 times the rows held where one of three paid for 3.5
+    # times; a third size of pass would save ~1% of that model's step more
+    # and adds as much code again to load at every start
+    CHUNK_OF_EVEN_SHARE = 2
+    STEPS_OF_CHUNK = 2
 
     def __init__(self, d_model: int, hidden: int, n_experts: int,
                  top_k: int, experts_held=None, route_norm: bool = True,
@@ -149,10 +161,11 @@ class DroplessMoE(TensorModule):
                                                 np.float32))
         self._add_buffer("tap_assignments_held", np.zeros((), np.float32))
         self._add_buffer("tap_expert_max", np.zeros((), np.float32))
+        self._add_buffer("tap_rows_moved", np.zeros((), np.float32))
         return self
 
     def _forward(self, P, x, S, ctx):
-        from bigdl_tpu.parallel.moe import (grouped_experts,
+        from bigdl_tpu.parallel.moe import (grouped_experts, rows_moved,
                                             sigmoid_topk_routing,
                                             sort_assignments)
         xt = x.reshape(-1, x.shape[-1])
@@ -173,15 +186,18 @@ class DroplessMoE(TensorModule):
                                             n_held)
             order = jnp.pad(order[:most], (0, -most % chunk))
             y = grouped_experts(xt, P["w_gate"], P["w_up"], P["w_down"],
-                                weights, order, sizes, chunk, k,
+                                weights, order, sizes, chunk,
+                                self.STEPS_OF_CHUNK, k,
                                 policy().cast_compute)
         if self.shared_hidden:
             with jax.named_scope("MoeShared"):
                 y = y + swiglu(xt, P["shared_gate"], P["shared_up"],
                                P["shared_down"])
         counts = sizes.astype(jnp.float32)
+        moved = rows_moved(sizes, chunk, self.STEPS_OF_CHUNK)
         new_s = dict(S, tap_assignments_held=counts.sum(),
-                     tap_expert_max=counts.max())
+                     tap_expert_max=counts.max(),
+                     tap_rows_moved=moved.astype(jnp.float32))
         return y.reshape(x.shape), new_s
 
     def __repr__(self):

@@ -181,20 +181,64 @@ def sort_assignments(idx, local_of, n_held: int):
     return order, sizes
 
 
-def _chunk_rows(order, sizes, start, chunk, top_k):
-    """The sorted assignments [start, start + chunk): (rows, their tokens,
+def _chunk_rows(order, sizes, start, n, top_k):
+    """The sorted assignments [start, start + n): (rows, their tokens,
     which of them are held here, how many fall to each held expert)."""
     ends = jnp.cumsum(sizes)
-    clip = lambda a: jnp.clip(a - start, 0, chunk)
-    rows = lax.dynamic_slice(order, (start,), (chunk,))
-    valid = start + jnp.arange(chunk) < ends[-1]
+    clip = lambda a: jnp.clip(a - start, 0, n)
+    rows = lax.dynamic_slice(order, (start,), (n,))
+    valid = start + jnp.arange(n) < ends[-1]
     return rows, rows // top_k, valid, clip(ends) - clip(ends - sizes)
+
+
+def _ceil_div(a, b):
+    return (a + b - 1) // b
+
+
+def pass_rows(chunk, n_steps):
+    """The row counts a chunk's pass may run over: the chunk in ``n_steps``
+    equal steps, the chunk itself the last."""
+    return tuple(sorted({_ceil_div(chunk * j, n_steps)
+                         for j in range(1, n_steps + 1)}))
+
+
+def _step_of(held, steps):
+    """Which of ``steps`` is the smallest that is not under ``held``."""
+    return jnp.sum(held > jnp.asarray(steps[:-1], jnp.int32))
+
+
+def _walk_chunks(sizes, chunk, n_steps, run, carry):
+    """``run(n, c, carry)`` for every chunk ``c`` that holds an assignment:
+    the full ones over all their ``n = chunk`` rows, then the last one over
+    the smallest ``n`` of ``pass_rows`` that holds its assignments.  One
+    loop for each ``n``, the largest first, each of a length the routing
+    decides (all but one or two of them run no pass)."""
+    steps = pass_rows(chunk, n_steps)
+    held = jnp.sum(sizes)
+    full, last = held // chunk, held % chunk
+    for i, n in reversed(list(enumerate(steps))):
+        takes_last = (last > 0) & (_step_of(last, steps) == i)
+        first = 0 if n == chunk else full
+        carry = lax.fori_loop(first, full + takes_last, partial(run, n),
+                              carry)
+    return carry
+
+
+def rows_moved(sizes, chunk, n_steps):
+    """The rows ``grouped_experts``' passes run over, each time it walks
+    its chunks: a full chunk's all, the last chunk's up to the smallest of
+    ``pass_rows`` that holds its assignments."""
+    steps = pass_rows(chunk, n_steps)
+    held = jnp.sum(sizes)
+    last = held % chunk
+    return held - last + jnp.where(
+        last > 0, jnp.asarray(steps)[_step_of(last, steps)], 0)
 
 
 def _experts_of_rows(xs, w_gate, w_up, w_down, chunk_sizes, valid):
     """Rows that lie expert by expert through their experts' SwiGLU: three
-    grouped products.  xs: (chunk, D) and the weights in the compute
-    dtype; float32 (chunk, D), zero in the rows past the groups."""
+    grouped products.  xs: (n, D) and the weights in the compute dtype;
+    float32 (n, D), zero in the rows past the groups."""
     def ragged(a, w):
         # a grouped product says nothing of the rows past its groups, in
         # its result or in its operand's gradient: both sides are masked,
@@ -213,85 +257,98 @@ def _float0(a):
     return np.zeros(a.shape, jax.dtypes.float0)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10))
 def grouped_experts(x, w_gate, w_up, w_down, weights, order, sizes, chunk,
-                    top_k, cast):
+                    n_steps, top_k, cast):
     """sum over the assignments held here of weight * Expert(x_token), with
     no capacity: the sorted assignments are walked ``chunk`` rows at a
     time for as many chunks as hold one (a loop whose length the routing
     decides), so the memory is one chunk's and no imbalance drops a token.
     A chunk gathers its tokens' rows, runs them through their experts and
-    adds the weighted result to its tokens' rows of the output, in place;
-    it costs nearly the same however full it is (the gathers and
-    scatter-adds take all its rows), so the caller sizes it to hold a
-    usual step's assignments at once.
+    adds the weighted result to its tokens' rows of the output, in place.
+    The grouped products' kernels take the time of the rows held; all
+    else in a pass (the gathers, the masks, the SwiGLU, the scatter-adds)
+    costs by the row it is given, held or padding, and a second pass pays
+    the scatter-adds' and the weight-gradient sums' fixed parts again.  So
+    the caller sizes the chunk to hold a usual step's assignments at once,
+    and the last chunk's pass runs over its first rows only: the smallest
+    of ``n_steps`` equal steps of the chunk (``pass_rows``) that holds its
+    assignments.
 
     x: (T, D); w_gate, w_up: (n_held, D, H); w_down: (n_held, H, D);
     weights: (T, k); order, sizes: ``sort_assignments``'; ``order`` is
     padded to a multiple of ``chunk``."""
     return _grouped_fwd(x, w_gate, w_up, w_down, weights, order, sizes,
-                        chunk, top_k, cast)
+                        chunk, n_steps, top_k, cast)
 
 
-def _n_chunks(sizes, chunk):
-    return (jnp.sum(sizes) + chunk - 1) // chunk
+# A chunk's pass over its first n rows, forward and backward.  Each is
+# jitted on its own so that a model's expert layers, which pass the same
+# shapes, are traced and lowered once for each n, not once a layer and an n
+# (and again in every ``optimize()`` call, which builds its step anew).
+
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _pass_fwd(n, chunk, top_k, c, out, order, sizes, xc, flat, wg, wu, wd):
+    rows, tok, valid, chunk_sizes = _chunk_rows(order, sizes, c * chunk, n,
+                                                top_k)
+    y = _experts_of_rows(xc[tok], wg, wu, wd, chunk_sizes, valid)
+    w = jnp.where(valid, flat[rows], 0.0)
+    return out.at[tok].add(y * w[:, None])
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _pass_bwd(n, chunk, top_k, c, grads, dout, order, sizes, xc, flat, wg,
+              wu, wd):
+    dx, dwg, dwu, dwd, dflat = grads
+    f32 = lambda a: a.astype(jnp.float32)
+    rows, tok, valid, chunk_sizes = _chunk_rows(order, sizes, c * chunk, n,
+                                                top_k)
+    y, vjp = jax.vjp(lambda *a: _experts_of_rows(*a, chunk_sizes, valid),
+                     xc[tok], wg, wu, wd)
+    w = jnp.where(valid, flat[rows], 0.0)
+    dyw = dout[tok]
+    dxs, g, u, d = vjp(dyw * w[:, None])
+    # the rows past the groups are padding that names some assignment not
+    # held: they add zeros
+    dw_rows = jnp.where(valid, jnp.sum(dyw * y, axis=-1), 0.0)
+    return (dx.at[tok].add(f32(dxs)), dwg + f32(g), dwu + f32(u),
+            dwd + f32(d), dflat.at[rows].add(dw_rows))
 
 
 def _grouped_fwd(x, w_gate, w_up, w_down, weights, order, sizes, chunk,
-                 top_k, cast):
+                 n_steps, top_k, cast):
     xc, wg, wu, wd = cast(x), cast(w_gate), cast(w_up), cast(w_down)
     flat = weights.reshape(-1)
-
-    def body(c, out):
-        rows, tok, valid, chunk_sizes = _chunk_rows(order, sizes, c * chunk,
-                                                    chunk, top_k)
-        y = _experts_of_rows(xc[tok], wg, wu, wd, chunk_sizes, valid)
-        w = jnp.where(valid, flat[rows], 0.0)
-        return out.at[tok].add(y * w[:, None])
-
-    return lax.fori_loop(0, _n_chunks(sizes, chunk), body,
-                         jnp.zeros(x.shape, jnp.float32))
+    return _walk_chunks(
+        sizes, chunk, n_steps,
+        lambda n, c, out: _pass_fwd(n, chunk, top_k, c, out, order, sizes,
+                                    xc, flat, wg, wu, wd),
+        jnp.zeros(x.shape, jnp.float32))
 
 
 def _grouped_vjp_fwd(x, w_gate, w_up, w_down, weights, order, sizes, chunk,
-                     top_k, cast):
+                     n_steps, top_k, cast):
     out = _grouped_fwd(x, w_gate, w_up, w_down, weights, order, sizes,
-                       chunk, top_k, cast)
+                       chunk, n_steps, top_k, cast)
     # a whole routed pass to make, one row a token to hold: a Recompute
     # around the layer keeps the sum, and its recomputation runs no chunk
     out = kept(out, "experts_out")
     return out, (x, w_gate, w_up, w_down, weights, order, sizes)
 
 
-def _grouped_vjp_bwd(chunk, top_k, cast, res, dout):
-    """Chunk by chunk again: a chunk's rows go through their experts once
-    more (nothing of the forward pass is kept but its inputs), the
-    gradient of the output's rows comes back through them, and every sum
-    is kept in float32 and added to in place."""
+def _grouped_vjp_bwd(chunk, n_steps, top_k, cast, res, dout):
+    """Chunk by chunk again, each over its held rows: a chunk's rows go
+    through their experts once more (nothing of the forward pass is kept
+    but its inputs), the gradient of the output's rows comes back through
+    them, and every sum is kept in float32 and added to in place."""
     x, w_gate, w_up, w_down, weights, order, sizes = res
     xc, wg, wu, wd = cast(x), cast(w_gate), cast(w_up), cast(w_down)
     flat = weights.reshape(-1)
-    f32 = lambda a: a.astype(jnp.float32)
-
-    def body(c, grads):
-        dx, dwg, dwu, dwd, dflat = grads
-        rows, tok, valid, chunk_sizes = _chunk_rows(order, sizes, c * chunk,
-                                                    chunk, top_k)
-        y, vjp = jax.vjp(
-            lambda *a: _experts_of_rows(*a, chunk_sizes, valid),
-            xc[tok], wg, wu, wd)
-        w = jnp.where(valid, flat[rows], 0.0)
-        dyw = dout[tok]
-        dxs, g, u, d = vjp(dyw * w[:, None])
-        # the rows past the groups are padding that names assignment 0:
-        # they add zeros
-        dw_rows = jnp.where(valid, jnp.sum(dyw * y, axis=-1), 0.0)
-        return (dx.at[tok].add(f32(dxs)), dwg + f32(g), dwu + f32(u),
-                dwd + f32(d), dflat.at[rows].add(dw_rows))
-
     zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
-    dx, dwg, dwu, dwd, dflat = lax.fori_loop(
-        0, _n_chunks(sizes, chunk), body,
+    dx, dwg, dwu, dwd, dflat = _walk_chunks(
+        sizes, chunk, n_steps,
+        lambda n, c, grads: _pass_bwd(n, chunk, top_k, c, grads, dout, order,
+                                      sizes, xc, flat, wg, wu, wd),
         (zeros(x), zeros(w_gate), zeros(w_up), zeros(w_down), zeros(flat)))
     return (dx, dwg, dwu, dwd, dflat.reshape(weights.shape),
             _float0(order), _float0(sizes))

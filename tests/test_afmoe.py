@@ -258,6 +258,87 @@ def test_route_bias_enters_the_choice_only():
     close(w.sum(axis=-1), jnp.ones((T,)))
 
 
+_CHOSEN = ((0, 1, 2, 3), (2, 3, 4, 5), (8, 9, 10, 11))
+
+
+def _typed_tokens(counts):
+    """Tokens of three kinds and a router that knows them: a token of kind
+    f chooses the four experts ``_CHOSEN[f]``, so a share holds exactly
+    sum(counts[f] * |_CHOSEN[f] & held|) assignments."""
+    d = CFG["hidden_size"]
+    kinds = np.repeat(np.arange(3), counts)
+    kinds = kinds[np.random.RandomState(4).permutation(len(kinds))]
+    x = 0.05 * jax.random.normal(jax.random.PRNGKey(56), (len(kinds), d))
+    x = x.at[jnp.arange(len(kinds)), kinds].add(1.0)
+    whole = _whole_moe_params(jax.random.PRNGKey(55), router_std=0.3)
+    for f, chosen in enumerate(_CHOSEN):
+        whole["router"] = whole["router"].at[f, list(chosen)].set(3.0)
+    return whole, x[None]
+
+
+# per case: tokens of each kind, the experts held, ``chunk_rows``, the
+# assignments that leaves: with (1, 2) held a token of kind 0 leaves 2, of
+# kind 1 one, of kind 2 none.  A chunk of 24 in 2, 3, 4, 6 or 12 steps:
+# passes over multiples of 12, 8, 6, 4 or 2 rows.
+PASS_CASES = {
+    "nothing_held": ((0, 0, 40), (1, 2), 24, 0),
+    "a_multiple_of_every_step": ((8, 8, 14), (1, 2), 24, 24),
+    "one_row_past_a_step": ((3, 7, 20), (1, 2), 24, 13),
+    "one_row_under_a_step": ((3, 5, 20), (1, 2), 24, 11),
+    "two_chunks": ((13, 5, 10), (1, 2), 24, 31),
+    "top_k_larger_than_the_experts_held": ((0, 29, 4), (2,), 24, 29),
+}
+
+
+@pytest.mark.parametrize("case", list(PASS_CASES))
+def test_a_pass_runs_over_the_held_rows(case, monkeypatch):
+    """In however many steps a chunk's pass may shorten, the layer's
+    output and every gradient are the same bit for bit and agree with the
+    reference; ``rows_moved`` is each chunk's smallest step that holds its
+    assignments."""
+    from bigdl_tpu.obs.taps import module_counters
+    from bigdl_tpu.parallel.moe import pass_rows
+    counts, held, chunk_rows, assignments = PASS_CASES[case]
+    whole, x = _typed_tokens(counts)
+    own = _share(whole, held)
+    c = jax.random.normal(jax.random.PRNGKey(58), x.shape)
+    # a share cannot get more than every token's choices among its experts
+    chunk = min(chunk_rows, x.shape[1] * min(4, len(held)))
+
+    def through(steps):
+        monkeypatch.setattr(nn.DroplessMoE, "STEPS_OF_CHUNK", steps)
+        m = _moe(held, chunk_rows)
+
+        def f(p, x_):
+            y, state = m.apply({"~": p}, x_, m.state(),
+                               Context(training=True))
+            return jnp.sum(y * c), (y, module_counters(state))
+
+        (_, (y, counters)), grads = jax.jit(jax.value_and_grad(
+            f, (0, 1), has_aux=True))(own, x)
+        return (y, grads), {k: float(v) for k, v in counters.items()}
+
+    first, _ = through(1)               # every pass over the whole chunk
+    for steps in (2, 3, 4, 6, 12):
+        got, counters = through(steps)
+        assert counters["assignments_held/0"] == assignments
+        assert counters["rows_moved/0"] == sum(
+            min(n for n in pass_rows(chunk, steps)
+                if n >= min(chunk, assignments - start))
+            for start in range(0, assignments, chunk))
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(first)):
+            assert np.array_equal(a, b)
+    g = lambda p, x_: jnp.sum(ref.expert_layer(
+        p, x_[0], CFG, experts_held=held) * c[0])
+    y, (gp, gx) = first
+    close(y[0], ref.expert_layer(own, x[0], CFG, experts_held=held))
+    rp, rx = jax.grad(g, (0, 1))(own, x)
+    close(gx, rx)
+    for name in own:
+        close(gp[name], rp[name])
+
+
 def _laid_in(model, cfg, key):
     p0 = ref.init_params(key, cfg)
     names = list(ref.param_shapes(cfg))
@@ -325,9 +406,11 @@ def _unmarked():
 # policy (the ``Recompute`` of PR 28, ``set_gradient_checkpointing``); what
 # ``nn.Recompute`` keeps, label -> shape.  A core is a ``scan`` over query
 # blocks around a ``while`` over key blocks: forward, its recomputation,
-# backward = 3, of which Recompute drops the recomputation; a chunk loop
-# holds 3 products forward and 9 in its backward rule (3 again + 6).
+# backward = 3, of which Recompute drops the recomputation; the chunks are
+# walked by one loop for each step a pass may shorten to (``_STEPS``), each
+# with 3 products forward and 9 in its backward rule (3 again + 6).
 B2 = 2
+_STEPS = nn.DroplessMoE.STEPS_OF_CHUNK
 _CORE = {"attention_out": (B2, T, 2, 2, 16), "attention_lse": (B2, 2, 2, T)}
 _SUM = {"experts_out": (B2 * T, 64)}
 RECOMPUTE_CASES = {
@@ -335,8 +418,10 @@ RECOMPUTE_CASES = {
                (2, 0), (3, 0), _CORE),
     "full": (lambda: _attention_layer("full_attention"),
              (2, 0), (3, 0), _CORE),
-    "experts": (_expert_branch, (2, 12), (3, 15), _SUM),
-    "layer": (_decoder_layer, (4, 12), (6, 15), {**_CORE, **_SUM}),
+    "experts": (_expert_branch, (2 * _STEPS, 12 * _STEPS),
+                (3 * _STEPS, 15 * _STEPS), _SUM),
+    "layer": (_decoder_layer, (2 + 2 * _STEPS, 12 * _STEPS),
+              (3 + 3 * _STEPS, 15 * _STEPS), {**_CORE, **_SUM}),
     "unmarked": (_unmarked, (0, 0), (0, 0), {}),
 }
 
@@ -518,7 +603,10 @@ def test_three_steps_through_the_optimizer_match_reference():
         events.configure(None)
     losses = [e["loss"] for e in steps]
     assert len(losses) == 3
-    assert any("assignments_held/3" in e.get("taps", {}) for e in steps)
+    taps = [e["taps"] for e in steps if "taps" in e]
+    assert taps and all(
+        t[f"rows_moved/{i}"] >= t[f"assignments_held/{i}"] > 0
+        for t in taps for i in range(4))
 
     params = p0
     velocity = jax.tree_util.tree_map(jnp.zeros_like, p0)

@@ -283,7 +283,10 @@ def test_afmoe_expert_layer_compiles_at_published_widths(one_chip, kind):
     kernels, and the layer's ``Recompute`` runs neither the core's loop
     nor the routed pass again (a core is a loop in a loop: 2 + 2 for its
     forward and backward, and a chunk loop each way; 9 with all three
-    recomputed, as before PR 30)."""
+    recomputed, as before PR 30; the chunks are walked by a loop for each
+    of the three steps a pass may shorten to, so 3 + 3 of them).  Every
+    float32 sum is added to in place in whichever loop runs: none is
+    copied on its way from one loop to the next."""
     import bigdl_tpu.nn as nn
     from bigdl_tpu import tensor as bt
     from bigdl_tpu.models.afmoe import afmoe_layer
@@ -323,5 +326,6 @@ def test_afmoe_expert_layer_compiles_at_published_widths(one_chip, kind):
     text = compiled.as_text()
     assert "ragged-dot" in text and "tpu_custom_call" in text
     assert f"{t},{t}]" not in text                  # no T x T array
-    assert len(re.findall(r" while\(", text)) == 6
+    assert len(re.findall(r" while\(", text)) == 4 + 2 * ffn.STEPS_OF_CHUNK
+    assert not re.findall(r"= f32\[16,(2048,1024|1024,2048)\]\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
