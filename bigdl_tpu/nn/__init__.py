@@ -51,7 +51,8 @@ from bigdl_tpu.nn.recurrent import (
 from bigdl_tpu.nn.moe import MoE, DroplessMoE
 from bigdl_tpu.nn.attention import (MultiHeadSelfAttention,
                                     SinusoidalPositionalEncoding,
-                                    GatedGroupedQueryAttention)
+                                    GatedGroupedQueryAttention,
+                                    LatentAttention)
 from bigdl_tpu.nn.criterion import (
     ClassNLLCriterion, CrossEntropyCriterion, MSECriterion, AbsCriterion,
     BCECriterion, DistKLDivCriterion, ClassSimplexCriterion,

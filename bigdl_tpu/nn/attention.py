@@ -214,3 +214,101 @@ class GatedGroupedQueryAttention(TensorModule):
         kind = "full" if self.window is None else f"window={self.window}"
         return (f"GatedGroupedQueryAttention({self.d_model}, heads="
                 f"{self.n_heads}/{self.n_kv_heads}x{self.head_dim}, {kind})")
+
+
+def rotary_interleaved(x, base: float = 10000.0):
+    """Rotary positions on (B, T, H, D) whose pairs are neighbours: (2i,
+    2i + 1) rotate by t * base^(-2i/D), position t = the index along T.
+    Returns the rotated pairs with their first members in the first half of
+    D and their second members in the second ([x'_0, x'_2, .. | x'_1,
+    x'_3, ..]): the same fixed permutation of D for every caller, so a dot
+    product of two results is that of the rotated vectors in their own
+    order, and no pair is woven back: in that order the pairs are
+    :func:`rotary`'s (i, i + D/2).  float32."""
+    return rotary(jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1),
+                  base)
+
+
+class LatentAttention(TensorModule):
+    """(B, T, D) -> (B, T, D) causal multi-head latent attention (the
+    deepseek_v3 family's, without query compression): keys and values come
+    out of one ``kv_lora_rank``-wide latent per token, a head's query and
+    key are ``qk_nope_head_dim`` + ``qk_rope_head_dim`` wide, only the
+    second part takes rotary positions (neighbouring pairs), the rotary key
+    is made once per token and shared by every head, and a head's value is
+    ``v_head_dim`` wide.  Bias-free, no gate, no q/k norms.
+
+        q = x Wq                    -> heads of [q_nope | q_pe]
+        x Wkv_a                     -> [c | k_pe];  c' = RMSNorm(c)
+        c' Wkv_b                    -> heads of [k_nope | v]
+        q_h = [q_nope_h | rot(q_pe_h)],  k_h = [k_nope_h | rot(k_pe)]
+        out = concat_h(softmax(q_h . k_h / sqrt(nope + rope), causal) v_h) Wo
+
+    The core is ``parallel.ring_attention.blockwise_attention`` (no (T, T)
+    array), which takes the value head size from ``v``.  **The shared
+    rotary key reaches it broadcast to every head**, joined to ``k_nope``
+    in the compute dtype: one more (B, T, heads, rope) array a layer (67 MB
+    in bfloat16 at 16,384 tokens x 32 heads x 64) against a second pair of
+    operands through the core's loops and its backward; the broadcast's
+    transpose sums the heads' gradients back into the one key."""
+
+    quant_spec = {"wq": (1, 0), "wkv_a": (1, 0), "wkv_b": (1, 0),
+                  "wo": (1, 0)}
+
+    def __init__(self, d_model: int, n_heads: int, kv_lora_rank: int,
+                 qk_nope_head_dim: int, qk_rope_head_dim: int,
+                 v_head_dim: int, rotary_base: float = 10000.0,
+                 eps: float = 1e-6):
+        super().__init__()
+        if qk_rope_head_dim % 2:
+            raise ValueError(f"qk_rope_head_dim ({qk_rope_head_dim}) must "
+                             "be even: rotary turns pairs")
+        self.d_model = d_model
+        self.n_heads = n_heads
+        self.kv_lora_rank = kv_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.rotary_base = rotary_base
+        self.eps = eps
+        self.block = 512        # rows of a query block and of a key block
+        self.reset()
+
+    def reset(self):
+        d, h, r = self.d_model, self.n_heads, self.kv_lora_rank
+        nope, rope, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                          self.v_head_dim)
+        self._add_param("wq", init_.normal_on_device((d, h * (nope + rope))))
+        self._add_param("wkv_a", init_.normal_on_device((d, r + rope)))
+        self._add_param("kv_norm", np.ones((r,), np.float32))
+        self._add_param("wkv_b", init_.normal_on_device((r, h * (nope + dv))))
+        self._add_param("wo", init_.normal_on_device((h * dv, d)))
+        return self
+
+    def _forward(self, P, x, S, ctx):
+        from bigdl_tpu.parallel.ring_attention import blockwise_attention
+        b, t, _ = x.shape
+        h, r, nope = self.n_heads, self.kv_lora_rank, self.qk_nope_head_dim
+        cc = policy().cast_compute
+        q = dot32(x, P["wq"]).reshape(b, t, h, -1)
+        with jax.named_scope("LatentKV"):
+            latent = dot32(x, P["wkv_a"])
+            c = rms_norm(latent[..., :r], P["kv_norm"], self.eps)
+            kv = dot32(c, P["wkv_b"]).reshape(b, t, h, -1)
+            k_pe = rotary_interleaved(latent[..., None, r:],
+                                      self.rotary_base)
+            q_pe = rotary_interleaved(q[..., nope:], self.rotary_base)
+            q = jnp.concatenate([cc(q[..., :nope]), cc(q_pe)], axis=-1)
+            k = jnp.concatenate(
+                [cc(kv[..., :nope]),
+                 jnp.broadcast_to(cc(k_pe), (b, t, h, k_pe.shape[-1]))],
+                axis=-1)
+            v = cc(kv[..., nope:])
+        with jax.named_scope("FullAttentionCore"):
+            o = blockwise_attention(q, k, v, None, self.block)
+        return dot32(o.reshape(b, t, -1), P["wo"]), None
+
+    def __repr__(self):
+        return (f"LatentAttention({self.d_model}, heads={self.n_heads}x"
+                f"({self.qk_nope_head_dim}+{self.qk_rope_head_dim})/"
+                f"{self.v_head_dim}, latent={self.kv_lora_rank})")

@@ -122,7 +122,13 @@ class DroplessMoE(TensorModule):
     # of one or two even shares (a third pass for the rare layer over two)
     # pay for 1.5 times the rows held where one of three paid for 3.5
     # times; a third size of pass would save ~1% of that model's step more
-    # and adds as much code again to load at every start
+    # and adds as much code again to load at every start.  A second shape
+    # runs on the same two constants (hidden 2048 x 768, top-6, 2 shared
+    # experts: deepseek_v3, PERF.md section 6, PR 33; even share 12,288,
+    # passes of 12,288 or 24,576 rows): its routing collapses under the
+    # cut, a layer holds 0.001 to 3.1 even shares, and `rows_moved` over
+    # `assignments_held` comes to 1.73 over 80 layers and steps (1.05 ..
+    # 1,024 a layer; the median layer 1.75)
     CHUNK_OF_EVEN_SHARE = 2
     STEPS_OF_CHUNK = 2
 

@@ -154,9 +154,11 @@ def _first_key_block(i, block, window):
 
 
 def _blockwise_fwd(q, k, v, window, block):
-    """q: (B, T, Hk, G, D), k/v: (B, T, Hk, D), T a multiple of ``block``.
-    Returns (out like q in float32, logsumexp (B, Hk, G, T))."""
+    """q: (B, T, Hk, G, D), k: (B, T, Hk, D), v: (B, T, Hk, Dv), T a
+    multiple of ``block``.  Returns (out (B, T, Hk, G, Dv) in float32,
+    logsumexp (B, Hk, G, T))."""
     b, t, hk, g, d = q.shape
+    dv = v.shape[-1]
     scale = 1.0 / (d ** 0.5)
     n = t // block
 
@@ -182,15 +184,15 @@ def _blockwise_fwd(q, k, v, window, block):
 
         m0 = jnp.full((b, hk, g, block), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((b, hk, g, block), jnp.float32)
-        acc0 = jnp.zeros((b, hk, g, block, d), jnp.float32)
+        acc0 = jnp.zeros((b, hk, g, block, dv), jnp.float32)
         m, l, acc = lax.fori_loop(_first_key_block(i, block, window), i + 1,
                                   k_block, (m0, l0, acc0))
         out = acc / l[..., None]
         return None, (out, m + jnp.log(l))
 
     _, (out, lse) = lax.scan(q_block, None, jnp.arange(n))
-    # (n, B, Hk, G, block, D) -> (B, T, Hk, G, D)
-    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, t, hk, g, d)
+    # (n, B, Hk, G, block, Dv) -> (B, T, Hk, G, Dv)
+    out = out.transpose(1, 0, 4, 2, 3, 5).reshape(b, t, hk, g, dv)
     lse = lse.transpose(1, 2, 3, 0, 4).reshape(b, hk, g, t)
     return out, lse
 
@@ -241,8 +243,11 @@ def _blockwise_bwd(q, k, v, out, lse, dout, window, block):
             (dq0, dk, dv))
         return (dk, dv), dq_blk
 
-    zeros = jnp.zeros((b, t, hk, d), jnp.float32)
-    (dk, dv), dq = lax.scan(q_block, (zeros, zeros), jnp.arange(n))
+    dk0 = jnp.zeros(k.shape, jnp.float32)
+    # one array of zeros where the sizes are equal: the lowered step of a
+    # model whose heads have one size stays what it was
+    dv0 = dk0 if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)
+    (dk, dv), dq = lax.scan(q_block, (dk0, dv0), jnp.arange(n))
     dq = dq.transpose(1, 0, 2, 3, 4, 5).reshape(b, t, hk, g, d)
     return dq, dk, dv
 
@@ -275,13 +280,16 @@ def blockwise_attention(q, k, v, window=None, block=512):
     that no query of a block may see are skipped, so a window layer costs
     T x (window + block) and a full one T x T / 2.
 
-    q: (B, T, Hq, D); k, v: (B, T, Hk, D) with Hq a multiple of Hk (query
-    head h reads key head h // (Hq // Hk)); ``window``: query i sees keys
-    i - window < j <= i (None: every j <= i).  Operands multiply in their
-    own dtype and accumulate in float32; the softmax statistics are
-    float32.  Returns float32 (B, T, Hq, D)."""
+    q: (B, T, Hq, D); k: (B, T, Hk, D); v: (B, T, Hk, Dv) with Hq a
+    multiple of Hk (query head h reads key head h // (Hq // Hk)) and Dv a
+    size of its own (a latent-attention head is 192 wide for the scores and
+    128 for the values); the scores are scaled by 1/sqrt(D).  ``window``:
+    query i sees keys i - window < j <= i (None: every j <= i).  Operands
+    multiply in their own dtype and accumulate in float32; the softmax
+    statistics are float32.  Returns float32 (B, T, Hq, Dv); the gradients
+    come back in the operands' own shapes."""
     b, t, hq, d = q.shape
-    hk = k.shape[2]
+    hk, dv = k.shape[2], v.shape[3]
     block = min(block, t)
     pad = -t % block
     if pad:
@@ -290,4 +298,4 @@ def blockwise_attention(q, k, v, window=None, block=512):
         q, k, v = widen(q), widen(k), widen(v)
     out = _blockwise(q.reshape(b, t + pad, hk, hq // hk, d), k, v, window,
                      block)
-    return out.reshape(b, t + pad, hq, d)[:, :t]
+    return out.reshape(b, t + pad, hq, dv)[:, :t]

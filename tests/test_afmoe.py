@@ -144,6 +144,43 @@ def test_attention_matches_reference(kind, t, block):
         close(gp[name], rp[name])
 
 
+@pytest.mark.parametrize("window,t,block,hq,hk,d,dv", [
+    (None, 37, 8, 4, 4, 24, 16),        # a latent-attention head: 24 and 16
+    (None, 24, 512, 4, 2, 24, 16),      # one block, grouped heads
+    (8, 37, 8, 4, 2, 24, 16),
+    (8, 32, 16, 4, 4, 16, 24),          # the value head the wider one
+])
+def test_blockwise_attention_takes_a_value_head_size_of_its_own(
+        window, t, block, hq, hk, d, dv):
+    """v of another head size than q and k, against a dense float32
+    softmax: the output and all three gradients, each in its operand's own
+    shape; the scale is the score head's."""
+    from bigdl_tpu.parallel.ring_attention import blockwise_attention
+    key = lambda n: jax.random.fold_in(jax.random.PRNGKey(17), n)
+    q = jax.random.normal(key(0), (2, t, hq, d))
+    k = jax.random.normal(key(1), (2, t, hk, d))
+    v = jax.random.normal(key(2), (2, t, hk, dv))
+    c = jax.random.normal(key(3), (2, t, hq, dv))
+
+    def dense(q, k, v):
+        s = jnp.einsum("bqhgd,bkhd->bhgqk",
+                       q.reshape(2, t, hk, hq // hk, d), k) / d ** 0.5
+        i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+        seen = (j <= i) if window is None else (j <= i) & (i - j < window)
+        p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        return jnp.einsum("bhgqk,bkhd->bqhgd", p, v).reshape(2, t, hq, dv)
+
+    out = blockwise_attention(q, k, v, window, block)
+    assert out.shape == (2, t, hq, dv) and out.dtype == jnp.float32
+    close(out, dense(q, k, v))
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(
+        blockwise_attention(*a, window, block) * c), (0, 1, 2)))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * c), (0, 1, 2))(q, k, v)
+    for a, b, operand in zip(got, want, (q, k, v)):
+        assert a.shape == operand.shape
+        close(a, b)
+
+
 def test_window_layer_ignores_keys_outside_the_window():
     m, own, x = _attention_pair("sliding_attention", 40, 8)
     y = run(m, {"~": own}, x)

@@ -329,3 +329,66 @@ def test_afmoe_expert_layer_compiles_at_published_widths(one_chip, kind):
     assert len(re.findall(r" while\(", text)) == 4 + 2 * ffn.STEPS_OF_CHUNK
     assert not re.findall(r"= f32\[16,(2048,1024|1024,2048)\]\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+
+
+def test_deepseek_v3_expert_layer_compiles_at_published_widths(one_chip):
+    """One decoder layer of ``models/deepseek_v3.py`` at kanana-2-30b-a3b's
+    widths (hidden 2048, 32 heads of 128 + 64 for the scores and 128 for
+    the values out of a 512 latent, 16 of 128 experts of width 768, top-6,
+    2 shared), one 8,192-token sequence, forward and backward under bf16
+    compute: the core's block loops take a value head of their own size
+    and hold no T x T array, and the layer's ``Recompute`` runs neither the
+    core's loop nor the routed pass again: 2 + 2 loops for the core's
+    forward and backward, and one chunk loop for each step a backward pass
+    may shorten to.  The routed experts' sum goes straight into the
+    residual add, so no backward computation reads it, and this gradient,
+    which needs no forward value, runs no forward pass over the chunks at
+    all (an afmoe layer's closing norm reads the sum)."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu import tensor as bt
+    from bigdl_tpu.models.deepseek_v3 import DeepseekV3LM
+    from bigdl_tpu.nn import init as init_
+    from bigdl_tpu.nn.module import Context
+
+    t, d = 8192, 2048
+    drawn = init_.normal_on_device
+    init_.normal_on_device = lambda shape, std=None: np.broadcast_to(
+        np.float32(0), shape)           # shapes only: nothing is run
+    try:
+        # the model's own construction of its first expert layer (a
+        # vocabulary of 256 rows: the embedding and the head are not run)
+        layer = DeepseekV3LM(
+            256, d, 2, 1, 32, 512, 128, 64, 128, 6144, 768, 128, 6,
+            experts_held=range(16), n_shared_experts=2,
+            routed_scaling_factor=2.448, rope_theta=1e6,
+            rms_norm_eps=1e-6).modules[2]
+    finally:
+        init_.normal_on_device = drawn
+    abstract = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+
+    def loss(p, s, x):
+        y, _ = layer.apply(p, x, s, Context(training=True,
+                                            key=jax.random.PRNGKey(0)))
+        return y.sum()
+
+    before = bt.policy()
+    bt.set_policy(bt.BF16_COMPUTE)
+    try:
+        compiled = jax.jit(jax.grad(loss)).lower(
+            abstract(layer.params()), abstract(layer.state()),
+            jax.ShapeDtypeStruct((1, t, d), F32, sharding=one_chip)
+        ).compile()
+    finally:
+        bt.set_policy(before)
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    # no T x T array of scores (32 heads of them; the up-projection's
+    # 32 x (128 + 128) columns make a (T, 8192) array that is none)
+    assert not re.search(r"\b32,(1,)?%d,%d\]" % (t, t), text)
+    assert isinstance(layer, nn.Recompute)
+    assert len(re.findall(r" while\(", text)) == \
+        4 + nn.DroplessMoE.STEPS_OF_CHUNK
+    assert not re.findall(r"= f32\[16,(2048,768|768,2048)\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
