@@ -51,8 +51,10 @@ logger = logging.getLogger("bigdl_tpu.obs")
 #: request's full flight-recorder record + ring-neighbor context —
 #: the non-fatal analog of the crash bundle).  v8: the `recompute` type
 #: landed (what a traced train step's `nn.Recompute` layers keep for
-#: the backward pass besides their inputs).
-SCHEMA_VERSION = 8
+#: the backward pass besides their inputs).  v9: the `attention_walk`
+#: type landed (how wide the backward of each `blockwise_attention` core
+#: of a traced train step walks, and what its accumulators take).
+SCHEMA_VERSION = 9
 
 ENV_OBS = "BIGDL_OBS"
 ENV_DIR = "BIGDL_OBS_DIR"
@@ -78,6 +80,11 @@ EVENT_TYPES = {
     # kept`) to the bytes held of it over all `layers`; {} says the
     # backward pass recomputes everything
     "recompute": ("kept", "layers"),
+    # written when a train step is traced whose model runs
+    # `blockwise_attention`: one entry of `cores` for each core's backward
+    # in the order traced (`parallel.ring_attention._walk_plan`: heads and
+    # pairs a pass, the carried gradient's bytes, the bytes added by slice)
+    "attention_walk": ("cores",),
     # serving lifecycle/telemetry (serve/engine.py, serve/decode.py,
     # serve/router.py, serve/cluster.py): kind-specific required fields
     # in SERVE_KINDS below; error events carry the failed request count

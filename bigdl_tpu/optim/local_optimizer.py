@@ -34,6 +34,7 @@ from bigdl_tpu.obs.spans import SpanTracker
 from bigdl_tpu.optim.optim_method import SGD, OptimMethod, Default
 from bigdl_tpu.optim import trigger as triggers
 from bigdl_tpu.optim.metrics import Metrics
+from bigdl_tpu.parallel.ring_attention import walk_report
 from bigdl_tpu.utils.table import Table, T
 from bigdl_tpu.utils import file as File
 from bigdl_tpu.utils.engine import Engine
@@ -363,11 +364,14 @@ class LocalOptimizer:
                     return criterion.apply_loss(out, y), ns
 
             # runs when the step is traced: what the model's Recomputes
-            # kept for the backward pass goes into the log, once a trace
-            with kept_report() as report:
+            # kept for the backward pass, and how its attention cores'
+            # backward walks, go into the log, once a trace
+            with kept_report() as report, walk_report() as cores:
                 (loss, new_net_state), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
             if report["layers"]:
                 obs_events.emit("recompute", **report)
+            if cores:
+                obs_events.emit("attention_walk", cores=cores)
             # the scopes name the update's and the taps' operations in a
             # profile (metadata only, as the module scopes of nn/containers)
             with jax.named_scope("optim-update"):

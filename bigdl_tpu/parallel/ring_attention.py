@@ -19,6 +19,8 @@ Causal masking uses global block offsets derived from ``axis_index``.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from functools import partial
 
 import jax
@@ -197,10 +199,66 @@ def _blockwise_fwd(q, k, v, window, block):
     return out, lse
 
 
-def _blockwise_bwd(q, k, v, out, lse, dout, window, block):
-    """One pass over the visible (query block, key block) pairs: the
-    probabilities come back from the saved logsumexp, dq is complete after
-    a query block's inner loop, dk and dv are accumulated in place."""
+# What the backward's walk may fill of a v5e core's 128 MiB of VMEM; the
+# rest is the operands' blocks'.  The chip's compiler decides what lives
+# there; tests/test_chip_compile.py pins its decision at the cells' shapes.
+_WALK_VMEM_BYTES = 112 << 20
+
+
+def _walk_plan(b, t, block, hk, g, d, dv, window):
+    """How wide the backward walks, from the operands' shapes alone: the
+    key heads of one pass are the most (a divisor of ``hk``) whose float32
+    accumulators fit ``_WALK_VMEM_BYTES`` beside one pair's score-sized
+    arrays (as compiled, one in float32 and two in the operands' dtype are
+    live at a time: 8 bytes a score).  The accumulators are dq's block,
+    which the inner loop carries, and the pass's whole dk and dv, which it
+    adds into by slice; where not even one head's dk and dv fit (a long
+    sequence), dq's block alone is sized to fit and the slices go through
+    HBM."""
+    scores = b * g * block * block * 8
+    dq_blk = b * block * g * d * 4
+    dkv = b * t * (d + dv) * 4
+    resident = scores + dq_blk + dkv <= _WALK_VMEM_BYTES
+    fits = max(_WALK_VMEM_BYTES // (scores + dq_blk + dkv * resident), 1)
+    heads = max(h for h in range(1, hk + 1) if hk % h == 0 and h <= fits)
+    pairs = sum(i + 1 - (0 if window is None else
+                         max(i * block - (window - 1), 0) // block)
+                for i in range(t // block))     # _first_key_block's
+    return {"heads_a_pass": heads * g, "key_heads_a_pass": heads,
+            "passes": hk // heads, "pairs_a_pass": pairs,
+            "carried": "dq", "carry_bytes": heads * dq_blk,
+            "sliced_bytes": heads * dkv, "sliced_in_vmem": resident,
+            # read and written at every pair of a pass
+            "slice_bytes_a_pair": 2 * heads * b * block * (d + dv) * 4}
+
+
+_walk_report = contextvars.ContextVar("attention_walk", default=None)
+
+
+@contextlib.contextmanager
+def walk_report():
+    """The plans (``_walk_plan``) of the cores whose backward is traced
+    inside the block, in the order traced (the optimizer's step logs them
+    as its ``attention_walk`` event)."""
+    cores = []
+    token = _walk_report.set(cores)
+    try:
+        yield cores
+    finally:
+        _walk_report.reset(token)
+
+
+@partial(jax.jit, static_argnums=(6, 7, 8))
+def _blockwise_bwd(q, k, v, out, lse, dout, window, block, hp):
+    """Every visible (query block, key block) pair once, the probabilities
+    back from the saved logsumexp, ``hp`` key heads at a time
+    (``_walk_plan``): narrow enough that the float32 accumulators stay in
+    VMEM from a pass's first pair to its last.  dq is complete after a
+    query block's inner loop, which carries it; dk and dv of the pass's
+    heads are added into at the key block's rows.  Jitted, so that the
+    three nested loops are traced once for all the cores of a step that
+    share their shapes, and not again by the next ``optimize()`` call's
+    step: a step's text inlines it."""
     b, t, hk, g, d = q.shape
     scale = 1.0 / (d ** 0.5)
     n = t // block
@@ -208,47 +266,56 @@ def _blockwise_bwd(q, k, v, out, lse, dout, window, block):
     cd = q.dtype
     dout = dout.astype(cd)
 
-    def q_block(carry, i):
-        dk, dv = carry
-        q_blk = lax.dynamic_slice_in_dim(q, i * block, block, axis=1)
-        do_blk = lax.dynamic_slice_in_dim(dout, i * block, block, axis=1)
-        lse_blk = lax.dynamic_slice_in_dim(lse, i * block, block, axis=3)
-        delta_blk = lax.dynamic_slice_in_dim(delta, i * block, block, axis=3)
+    def heads_from(_, h0):
+        # block ``at`` of the sequence and the pass's heads in one slice:
+        # no copy of a group's operands is ever made
+        def cut(a, at, rows, heads):
+            start, size = [0] * a.ndim, list(a.shape)
+            start[rows], size[rows] = at * block, block
+            start[heads], size[heads] = h0, hp
+            return lax.dynamic_slice(a, start, size)
 
-        def k_block(j, inner):
-            dq_blk, dk, dv = inner
-            k_blk = lax.dynamic_slice_in_dim(k, j * block, block, axis=1)
-            v_blk = lax.dynamic_slice_in_dim(v, j * block, block, axis=1)
-            s = _pair_scores(q_blk, k_blk, i * block, j * block, window,
-                             scale)
-            p = jnp.exp(s - lse_blk[..., None])
-            dp = jnp.einsum("bqhgd,bkhd->bhgqk", do_blk, v_blk,
-                            preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta_blk[..., None]) * scale).astype(cd)
-            dv_j = jnp.einsum("bhgqk,bqhgd->bkhd", p.astype(cd), do_blk,
-                              preferred_element_type=jnp.float32)
-            dk_j = jnp.einsum("bhgqk,bqhgd->bkhd", ds, q_blk,
-                              preferred_element_type=jnp.float32)
-            dq_blk = dq_blk + jnp.einsum(
-                "bhgqk,bkhd->bqhgd", ds, k_blk,
-                preferred_element_type=jnp.float32)
-            add = lambda full, part: lax.dynamic_update_slice_in_dim(
-                full, lax.dynamic_slice_in_dim(full, j * block, block, 1)
-                + part, j * block, axis=1)
-            return dq_blk, add(dk, dk_j), add(dv, dv_j)
+        def q_block(carry, i):
+            q_blk, do_blk = cut(q, i, 1, 2), cut(dout, i, 1, 2)
+            lse_blk, delta_blk = cut(lse, i, 3, 1), cut(delta, i, 3, 1)
 
-        dq0 = jnp.zeros((b, block, hk, g, d), jnp.float32)
-        dq_blk, dk, dv = lax.fori_loop(
-            _first_key_block(i, block, window), i + 1, k_block,
-            (dq0, dk, dv))
-        return (dk, dv), dq_blk
+            def k_block(j, inner):
+                dq_blk, dk, dv = inner
+                k_blk, v_blk = cut(k, j, 1, 2), cut(v, j, 1, 2)
+                s = _pair_scores(q_blk, k_blk, i * block, j * block, window,
+                                 scale)
+                p = jnp.exp(s - lse_blk[..., None])
+                dp = jnp.einsum("bqhgd,bkhd->bhgqk", do_blk, v_blk,
+                                preferred_element_type=jnp.float32)
+                ds = (p * (dp - delta_blk[..., None]) * scale).astype(cd)
+                dv_j = jnp.einsum("bhgqk,bqhgd->bkhd", p.astype(cd), do_blk,
+                                  preferred_element_type=jnp.float32)
+                dk_j = jnp.einsum("bhgqk,bqhgd->bkhd", ds, q_blk,
+                                  preferred_element_type=jnp.float32)
+                dq_blk = dq_blk + jnp.einsum(
+                    "bhgqk,bkhd->bqhgd", ds, k_blk,
+                    preferred_element_type=jnp.float32)
+                add = lambda full, part: lax.dynamic_update_slice_in_dim(
+                    full, lax.dynamic_slice_in_dim(full, j * block, block, 1)
+                    + part, j * block, axis=1)
+                return dq_blk, add(dk, dk_j), add(dv, dv_j)
 
-    dk0 = jnp.zeros(k.shape, jnp.float32)
-    # one array of zeros where the sizes are equal: the lowered step of a
-    # model whose heads have one size stays what it was
-    dv0 = dk0 if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)
-    (dk, dv), dq = lax.scan(q_block, (dk0, dv0), jnp.arange(n))
-    dq = dq.transpose(1, 0, 2, 3, 4, 5).reshape(b, t, hk, g, d)
+            dq0 = jnp.zeros((b, block, hp, g, d), jnp.float32)
+            dq_blk, dk, dv = lax.fori_loop(
+                _first_key_block(i, block, window), i + 1, k_block,
+                (dq0,) + carry)
+            return (dk, dv), dq_blk
+
+        (dk, dv), dq = lax.scan(
+            q_block, tuple(jnp.zeros((b, t, hp, a.shape[-1]), jnp.float32)
+                           for a in (k, v)), jnp.arange(n))
+        return None, (dq, dk, dv)
+
+    # a loop of the program's, so that the step holds one pass's text
+    _, (dq, dk, dv) = lax.scan(heads_from, None, jnp.arange(0, hk, hp))
+    # (passes, n, B, block, heads, G, D) and (passes, B, T, heads, D)
+    dq = dq.transpose(2, 1, 3, 0, 4, 5, 6).reshape(q.shape)
+    dk, dv = (jnp.moveaxis(a, 0, 2).reshape(b, t, hk, -1) for a in (dk, dv))
     return dq, dk, dv
 
 
@@ -267,7 +334,13 @@ def _blockwise_vjp_fwd(q, k, v, window, block):
 
 def _blockwise_vjp_bwd(window, block, res, dout):
     q, k, v, out, lse = res
-    dq, dk, dv = _blockwise_bwd(q, k, v, out, lse, dout, window, block)
+    b, t, hk, g, d = q.shape
+    plan = _walk_plan(b, t, block, hk, g, d, v.shape[-1], window)
+    report = _walk_report.get()
+    if report is not None:
+        report.append(plan)
+    dq, dk, dv = _blockwise_bwd(q, k, v, out, lse, dout, window, block,
+                                plan["key_heads_a_pass"])
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 

@@ -123,13 +123,31 @@ def _attention_pair(kind, t, block):
     return m, own, x
 
 
-@pytest.mark.parametrize("kind,t,block", [
-    ("sliding_attention", 37, 8),       # T several windows, not a multiple
-    ("sliding_attention", 32, 16),
-    ("full_attention", 37, 8),
-    ("full_attention", 24, 512),        # one block
+def _one_key_head_fits(monkeypatch, b, t, block, g, d, dv, resident=True):
+    """Steer ``_walk_plan`` as the chip's VMEM would at a cell's size: room
+    for one key head's scores and dq block, with (``resident``) or without
+    that head's whole dk and dv, so a backward of several key heads walks
+    in several passes."""
+    from bigdl_tpu.parallel import ring_attention as ra
+    t += -t % block
+    monkeypatch.setattr(ra, "_WALK_VMEM_BYTES", (
+        b * g * block * block * 8 + b * block * g * d * 4
+        + resident * b * t * (d + dv) * 4))
+
+
+@pytest.mark.parametrize("kind,t,block,passes", [
+    ("sliding_attention", 37, 8, 1),    # T several windows, not a multiple
+    ("sliding_attention", 32, 16, 1),
+    ("full_attention", 37, 8, 1),
+    ("full_attention", 24, 512, 1),     # one block
+    ("sliding_attention", 37, 8, 2),    # a key head a pass of the backward
+    ("sliding_attention", 37, 4, 2),    # the window longer than two blocks
+    ("full_attention", 37, 8, 2),
 ])
-def test_attention_matches_reference(kind, t, block):
+def test_attention_matches_reference(kind, t, block, passes, monkeypatch):
+    if passes > 1:
+        _one_key_head_fits(monkeypatch, 2, t, block, 2, CFG["head_dim"],
+                           CFG["head_dim"])
     m, own, x = _attention_pair(kind, t, block)
     c = jax.random.normal(jax.random.PRNGKey(11), x.shape)
     f = lambda p, x_: jnp.sum(run(m, {"~": p}, x_) * c)
@@ -137,25 +155,41 @@ def test_attention_matches_reference(kind, t, block):
         jnp.sum(ref.attention(p, x_[b], CFG, kind) * c[b]) for b in range(2))
     for b in range(2):
         close(run(m, {"~": own}, x)[b], ref.attention(own, x[b], CFG, kind))
-    gp, gx = jax.jit(jax.grad(f, (0, 1)))(own, x)
+    from bigdl_tpu.parallel.ring_attention import walk_report
+    with walk_report() as cores:
+        gp, gx = jax.jit(jax.grad(f, (0, 1)))(own, x)
+    assert [core["passes"] for core in cores] == [passes]
     rp, rx = jax.grad(g, (0, 1))(own, x)
     close(gx, rx)
     for name in ref.ATTENTION_PARTS:
         close(gp[name], rp[name])
 
 
-@pytest.mark.parametrize("window,t,block,hq,hk,d,dv", [
-    (None, 37, 8, 4, 4, 24, 16),        # a latent-attention head: 24 and 16
-    (None, 24, 512, 4, 2, 24, 16),      # one block, grouped heads
-    (8, 37, 8, 4, 2, 24, 16),
-    (8, 32, 16, 4, 4, 16, 24),          # the value head the wider one
+@pytest.mark.parametrize("window,t,block,hq,hk,d,dv,walk", [
+    (None, 37, 8, 4, 4, 24, 16, None),  # a latent-attention head: 24 and 16
+    (None, 24, 512, 4, 2, 24, 16, None),    # one block, grouped heads
+    (8, 37, 8, 4, 2, 24, 16, None),
+    (8, 32, 16, 4, 4, 16, 24, None),    # the value head the wider one
+    # the backward in passes of one key head (``walk``: whether the pass's
+    # whole dk and dv were sized to fit): one-to-one and grouped heads,
+    # full, a window shorter and one longer than two blocks
+    (None, 37, 8, 6, 6, 24, 16, True),
+    (None, 37, 8, 6, 6, 24, 16, False),
+    (None, 37, 8, 12, 3, 24, 16, True),
+    (5, 37, 8, 4, 4, 24, 16, True),
+    (5, 37, 8, 8, 2, 24, 16, False),
+    (20, 37, 8, 6, 6, 24, 16, False),
+    (20, 37, 8, 8, 2, 16, 24, True),
 ])
 def test_blockwise_attention_takes_a_value_head_size_of_its_own(
-        window, t, block, hq, hk, d, dv):
+        window, t, block, hq, hk, d, dv, walk, monkeypatch):
     """v of another head size than q and k, against a dense float32
     softmax: the output and all three gradients, each in its operand's own
     shape; the scale is the score head's."""
+    from bigdl_tpu.parallel import ring_attention as ra
     from bigdl_tpu.parallel.ring_attention import blockwise_attention
+    if walk is not None:
+        _one_key_head_fits(monkeypatch, 2, t, block, hq // hk, d, dv, walk)
     key = lambda n: jax.random.fold_in(jax.random.PRNGKey(17), n)
     q = jax.random.normal(key(0), (2, t, hq, d))
     k = jax.random.normal(key(1), (2, t, hk, d))
@@ -173,12 +207,44 @@ def test_blockwise_attention_takes_a_value_head_size_of_its_own(
     out = blockwise_attention(q, k, v, window, block)
     assert out.shape == (2, t, hq, dv) and out.dtype == jnp.float32
     close(out, dense(q, k, v))
-    got = jax.jit(jax.grad(lambda *a: jnp.sum(
-        blockwise_attention(*a, window, block) * c), (0, 1, 2)))(q, k, v)
+    with ra.walk_report() as cores:
+        got = jax.jit(jax.grad(lambda *a: jnp.sum(
+            blockwise_attention(*a, window, block) * c), (0, 1, 2)))(q, k, v)
     want = jax.grad(lambda *a: jnp.sum(dense(*a) * c), (0, 1, 2))(q, k, v)
     for a, b, operand in zip(got, want, (q, k, v)):
         assert a.shape == operand.shape
         close(a, b)
+    core, = cores
+    assert core["passes"] == (1 if walk is None else hk)
+    assert core["sliced_in_vmem"] == (walk is not False)
+
+
+@pytest.mark.parametrize("shape,heads,passes,pairs,resident", [
+    # kanana-2-30b-a3b's core, trinity-mini's full and window cores
+    ((2, 8192, 512, 32, 1, 192, 128, None), 4, 8, 136, True),
+    ((2, 8192, 512, 4, 8, 128, 128, None), 16, 2, 136, True),
+    ((2, 8192, 512, 4, 8, 128, 128, 2048), 16, 2, 70, True),
+    # a sequence whose dk and dv no pass holds: dq's block alone is sized
+    ((2, 131072, 512, 32, 1, 192, 128, None), 16, 2, 32896, False),
+    # six key heads where four would fit: a pass takes a divisor, three
+    ((2, 8192, 512, 6, 1, 192, 128, None), 3, 2, 136, True),
+])
+def test_the_walk_is_sized_from_the_shapes(shape, heads, passes, pairs,
+                                           resident):
+    """``_walk_plan`` at the cells' sizes and at two that bend the rule:
+    query heads a pass, passes (always a divisor of the key heads), visible
+    pairs, and whether a pass's whole dk and dv were counted in."""
+    from bigdl_tpu.parallel.ring_attention import _walk_plan
+    b, t, block, hk, g, d, dv, window = shape
+    plan = _walk_plan(*shape)
+    assert (plan["heads_a_pass"], plan["passes"], plan["pairs_a_pass"],
+            plan["sliced_in_vmem"]) == (heads, passes, pairs, resident)
+    assert plan["key_heads_a_pass"] * plan["passes"] == hk
+    assert plan["carried"] == "dq"
+    assert plan["carry_bytes"] == b * block * heads * d * 4
+    assert plan["sliced_bytes"] == b * t * (heads // g) * (d + dv) * 4
+    assert plan["slice_bytes_a_pair"] == \
+        2 * b * block * (heads // g) * (d + dv) * 4
 
 
 def test_window_layer_ignores_keys_outside_the_window():
@@ -598,6 +664,20 @@ def test_the_step_logs_what_its_recomputes_keep():
     types = [e["type"] for e in logged]
     assert types.index("run_start") < types.index("recompute") \
         < types.index("step")
+    # and one ``attention_walk`` event: what each core's backward carries,
+    # as the shapes imply (2 key heads of 2 query heads each: one pass)
+    walk = [e for e in logged if e["type"] == "attention_walk"]
+    assert len(walk) == 1 and events.validate_event(walk[0])
+    assert types.index("recompute") < types.index("attention_walk") \
+        < types.index("step")
+    cores = walk[0]["cores"]
+    assert len(cores) == 5
+    for core in cores:
+        assert (core["carried"], core["heads_a_pass"], core["passes"]) == \
+            ("dq", heads, 1)
+        assert core["pairs_a_pass"] == 1        # T is one block here
+        assert core["carry_bytes"] == 2 * T * heads * d * 4
+        assert core["sliced_bytes"] == 2 * T * 2 * (d + d) * 4
 
 
 def test_three_steps_through_the_optimizer_match_reference():

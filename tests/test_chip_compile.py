@@ -273,6 +273,57 @@ def test_conv_then_lrn_compiles_at_small_batch(one_chip, batch, mode):
         bt.set_policy(before)
 
 
+def _loop_carries(text):
+    """[[(shape, layout) of every array a ``while`` of the compiled text
+    carries]], one list a loop."""
+    return [re.findall(r"(\w+\[[\d,]*\])(\{[^}]*\})", carried)
+            for carried in re.findall(r"^\s*\S+ = (\(.*?\)) while\(", text,
+                                      re.M)]
+
+
+@pytest.mark.parametrize("hq,hk,d,dv,window,heads,in_vmem,temp", [
+    (32, 32, 192, 128, None, 4, True, 1.07e9),      # kanana-2-30b-a3b
+    (32, 4, 128, 128, None, 2, True, 0.3e9),        # trinity-mini, full
+    (32, 4, 128, 128, 2048, 2, False, 0.3e9),       # and window
+], ids=["mla", "afmoe_full", "afmoe_window"])
+def test_attention_backward_accumulates_in_vmem(one_chip, hq, hk, d, dv,
+                                                window, heads, in_vmem,
+                                                temp):
+    """``blockwise_attention``'s forward and gradients at the benchmark
+    cells' core shapes (2 x 8,192 tokens, blocks of 512, bfloat16), compiled
+    for the described v5e.  **This pins a decision of the compiler, not of
+    the program**: ``_walk_plan`` sizes a pass of the backward (4 key heads
+    of kanana's 32, 2 of trinity-mini's 4) so that the float32 accumulators
+    *can* live in VMEM, and the memory space in the layout of the inner
+    loop's carries (``S(1)``) says whether they *do*: dq's block at every
+    shape, and a pass's whole dk and dv where the loop runs from key block
+    0 (the window layer's are added to by slice in HBM: its inner loop
+    starts at a block the compiler cannot bound).  Before PR 34 all three
+    went through HBM at every block pair (PERF.md section 6).  A jax or
+    libtpu upgrade that flips the placement fails here, on the CPU, and not
+    as a slower step on the chip.  No (T, T) array; one more loop than the
+    one-pass walk (2 forward, 3 backward); temporaries no higher than
+    before the walk was split (1.07 GB at kanana's shape)."""
+    from bigdl_tpu.parallel.ring_attention import blockwise_attention
+    b, t, block, g = 2, 8192, 512, hq // hk
+    fn = jax.value_and_grad(
+        lambda q, k, v: blockwise_attention(q, k, v, window).sum(),
+        argnums=(0, 1, 2))
+    args = [jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+            for shape in ((b, t, hq, d), (b, t, hk, d), (b, t, hk, dv))]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert f"{t},{t}]" not in text
+    loops = _loop_carries(text)
+    assert len(loops) == 5
+    dq_blk = f"f32[{b},{block},{heads},{g},{d}]"
+    inner, = [dict(loop) for loop in loops if dq_blk in dict(loop)]
+    assert "S(1)" in inner[dq_blk]
+    for width in {d, dv}:
+        assert ("S(1)" in inner[f"f32[{b},{t},{heads},{width}]"]) == in_vmem
+    assert compiled.memory_analysis().temp_size_in_bytes <= temp
+
+
 @pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
 def test_afmoe_expert_layer_compiles_at_published_widths(one_chip, kind):
     """One decoder layer of ``models/afmoe.py`` at Trinity-Mini's widths
@@ -282,7 +333,8 @@ def test_afmoe_expert_layer_compiles_at_published_widths(one_chip, kind):
     T x T array, the grouped products become the TPU's own ragged-dot
     kernels, and the layer's ``Recompute`` runs neither the core's loop
     nor the routed pass again (a core is a loop in a loop: 2 + 2 for its
-    forward and backward, and a chunk loop each way; 9 with all three
+    forward and backward, one sequence's four key heads being one pass of
+    the backward, and a chunk loop each way; 9 with all three
     recomputed, as before PR 30; the chunks are walked by a loop for each
     of the three steps a pass may shorten to, so 3 + 3 of them).  Every
     float32 sum is added to in place in whichever loop runs: none is
@@ -338,9 +390,10 @@ def test_deepseek_v3_expert_layer_compiles_at_published_widths(one_chip):
     2 shared), one 8,192-token sequence, forward and backward under bf16
     compute: the core's block loops take a value head of their own size
     and hold no T x T array, and the layer's ``Recompute`` runs neither the
-    core's loop nor the routed pass again: 2 + 2 loops for the core's
-    forward and backward, and one chunk loop for each step a backward pass
-    may shorten to.  The routed experts' sum goes straight into the
+    core's loop nor the routed pass again: 2 + 3 loops for the core's
+    forward and backward (the third walks one sequence's 32 key heads in
+    four passes of 8), and one chunk loop for each step a backward pass may
+    shorten to.  The routed experts' sum goes straight into the
     residual add, so no backward computation reads it, and this gradient,
     which needs no forward value, runs no forward pass over the chunks at
     all (an afmoe layer's closing norm reads the sum)."""
@@ -389,6 +442,6 @@ def test_deepseek_v3_expert_layer_compiles_at_published_widths(one_chip):
     assert not re.search(r"\b32,(1,)?%d,%d\]" % (t, t), text)
     assert isinstance(layer, nn.Recompute)
     assert len(re.findall(r" while\(", text)) == \
-        4 + nn.DroplessMoE.STEPS_OF_CHUNK
+        5 + nn.DroplessMoE.STEPS_OF_CHUNK
     assert not re.findall(r"= f32\[16,(2048,768|768,2048)\]\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
