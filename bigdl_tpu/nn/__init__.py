@@ -17,7 +17,7 @@ from bigdl_tpu.nn.activations import (
 )
 from bigdl_tpu.nn.linear import (
     Linear, Bilinear, CMul, CAdd, Mul, Add, MulConstant, AddConstant, MM, MV,
-    Cosine, Euclidean, LookupTable, GatedLinearUnit, LmHead,
+    Cosine, Euclidean, LookupTable, GatedLinearUnit, LmHead, TiedLmHead,
 )
 from bigdl_tpu.nn.conv import (
     SpatialConvolution, SpatialShareConvolution, SpatialDilatedConvolution,
@@ -49,8 +49,10 @@ from bigdl_tpu.nn.recurrent import (
     Cell, RnnCell, LSTMCell, GRUCell, Recurrent, BiRecurrent, TimeDistributed,
 )
 from bigdl_tpu.nn.moe import MoE, DroplessMoE
+from bigdl_tpu.nn.shortconv import ShortConv
 from bigdl_tpu.nn.attention import (MultiHeadSelfAttention,
                                     SinusoidalPositionalEncoding,
+                                    GroupedQueryAttention,
                                     GatedGroupedQueryAttention,
                                     LatentAttention)
 from bigdl_tpu.nn.criterion import (

@@ -147,20 +147,20 @@ def rotary(x, base: float = 10000.0):
                            axis=-1)
 
 
-class GatedGroupedQueryAttention(TensorModule):
+class GroupedQueryAttention(TensorModule):
     """(B, T, D) -> (B, T, D) causal self-attention with grouped heads
     (``n_heads`` query heads share ``n_kv_heads`` key/value heads), an
-    RMSNorm on every head's query and key, a sigmoid gate on the joined
-    heads before the output projection, and bias-free projections.
+    RMSNorm on every head's query and key (one weight vector each, shared
+    by the heads), and bias-free projections.
 
     ``window``: query i sees keys i - window < j <= i (None: every key up
-    to i).  ``rotary_base``: rotary positions on q and k (None: no
-    positions).  The core is ``parallel.ring_attention.
+    to i).  ``rotary_base``: rotary positions on q and k, after their
+    norms (None: no positions).  The core is ``parallel.ring_attention.
     blockwise_attention``: no (T, T) array, and a window layer skips the
     key blocks outside its window."""
 
-    quant_spec = {"wq": (1, 0), "wk": (1, 0), "wv": (1, 0), "wg": (1, 0),
-                  "wo": (1, 0)}
+    quant_spec = {"wq": (1, 0), "wk": (1, 0), "wv": (1, 0), "wo": (1, 0)}
+    gated = False       # GatedGroupedQueryAttention: a sigmoid output gate
 
     def __init__(self, d_model: int, n_heads: int, n_kv_heads: int,
                  head_dim: int, window: int = None,
@@ -182,9 +182,11 @@ class GatedGroupedQueryAttention(TensorModule):
     def reset(self):
         d, hd = self.d_model, self.head_dim
         q_out, kv_out = self.n_heads * hd, self.n_kv_heads * hd
-        for name, shape in (("wq", (d, q_out)), ("wk", (d, kv_out)),
-                            ("wv", (d, kv_out)), ("wg", (d, q_out)),
-                            ("wo", (q_out, d))):
+        shapes = [("wq", (d, q_out)), ("wk", (d, kv_out)),
+                  ("wv", (d, kv_out))]
+        if self.gated:
+            shapes.append(("wg", (d, q_out)))
+        for name, shape in shapes + [("wo", (q_out, d))]:
             self._add_param(name, init_.normal_on_device(shape))
         self._add_param("q_norm", np.ones((hd,), np.float32))
         self._add_param("k_norm", np.ones((hd,), np.float32))
@@ -207,13 +209,24 @@ class GatedGroupedQueryAttention(TensorModule):
         with jax.named_scope(core):
             o = blockwise_attention(cc(q), cc(k), cc(v), self.window,
                                     self.block)
-        o = o.reshape(b, t, -1) * jax.nn.sigmoid(dot32(x, P["wg"]))
+        o = o.reshape(b, t, -1)
+        if self.gated:
+            o = o * jax.nn.sigmoid(dot32(x, P["wg"]))
         return dot32(o, P["wo"]), None
 
     def __repr__(self):
         kind = "full" if self.window is None else f"window={self.window}"
-        return (f"GatedGroupedQueryAttention({self.d_model}, heads="
+        return (f"{type(self).__name__}({self.d_model}, heads="
                 f"{self.n_heads}/{self.n_kv_heads}x{self.head_dim}, {kind})")
+
+
+class GatedGroupedQueryAttention(GroupedQueryAttention):
+    """:class:`GroupedQueryAttention` with a sigmoid gate on the joined
+    heads before the output projection (``wg``, after ``wv``): the afmoe
+    family's attention."""
+
+    quant_spec = dict(GroupedQueryAttention.quant_spec, wg=(1, 0))
+    gated = True
 
 
 def rotary_interleaved(x, base: float = 10000.0):

@@ -14,7 +14,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from bigdl_tpu.nn.module import TensorModule, Module
+from bigdl_tpu.nn.module import Container, TensorModule, Module
 from bigdl_tpu.nn import init as init_
 from bigdl_tpu.tensor import policy
 from bigdl_tpu.utils.table import Table
@@ -355,3 +355,42 @@ class LmHead(TensorModule):
 
     def __repr__(self):
         return f"LmHead({self.d_model} -> {self.vocab_size})"
+
+
+class TiedLmHead(Container):
+    """A language model whose output head is its embedding, transposed:
+    (B, T) 1-based token ids -> (B, T, V) log-probabilities,
+    ``log_softmax(body(E[ids]) E^T)``.  The one table ``weight`` (V, D) is
+    this container's own parameter and ``body`` (hidden states to hidden
+    states, the final norm included) its one child, so ``params()`` holds
+    the table once: its gradient is the sum of the lookup's and the head's,
+    and an optimizer keeps one state for it.  The lookup traces under the
+    scope ``LookupTable`` and the head's product and softmax (float32
+    logits) under ``LmHead``, as the untied modules' do."""
+
+    def __init__(self, vocab_size: int, d_model: int, body: Module,
+                 init_std: float = init_.LM_INIT_STD):
+        super().__init__(body)
+        self.vocab_size = vocab_size
+        self.d_model = d_model
+        self.init_std = init_std
+        self._reset_table()
+
+    def _reset_table(self):
+        self._add_param("weight", init_.normal_on_device(
+            (self.vocab_size, self.d_model), self.init_std))
+
+    def reset(self):
+        super().reset()
+        self._reset_table()
+        return self
+
+    def apply(self, params, x, state, ctx):
+        from bigdl_tpu.nn.containers import _child_apply
+        table = params["~"]["weight"]
+        with jax.named_scope("LookupTable"):
+            h = jnp.take(table, jnp.asarray(x, jnp.int32) - 1, axis=0)
+        h, body_state = _child_apply(self, 0, params, h, state, ctx)
+        with jax.named_scope("LmHead"):
+            logp = jax.nn.log_softmax(dot32(h, table.T), axis=-1)
+        return logp, dict(state, **{"0": body_state})

@@ -108,7 +108,9 @@ class DroplessMoE(TensorModule):
     ``w_down`` (n_held, H, D); ``shared_gate``/``shared_up`` (D, Hs) and
     ``shared_down`` (Hs, D) where ``shared_hidden``.  Buffers:
     ``route_bias`` (E,), added to the scores for the choice only, no
-    gradient; ``tap_assignments_held``, ``tap_expert_max`` and
+    gradient (``route_eps``: what the chosen scores' sum is raised by where
+    ``route_norm`` divides by it, a family's own constant);
+    ``tap_assignments_held``, ``tap_expert_max`` and
     ``tap_rows_moved``, the last call's count of assignments held here,
     its busiest expert's count and the rows its passes ran over
     (``obs.taps.module_counters`` hands them to the step's taps)."""
@@ -135,7 +137,7 @@ class DroplessMoE(TensorModule):
     def __init__(self, d_model: int, hidden: int, n_experts: int,
                  top_k: int, experts_held=None, route_norm: bool = True,
                  route_scale: float = 1.0, shared_hidden: int = 0,
-                 chunk_rows=None):
+                 chunk_rows=None, route_eps: float = 1e-20):
         super().__init__()
         self.d_model = d_model
         self.hidden = hidden
@@ -151,6 +153,7 @@ class DroplessMoE(TensorModule):
         self.route_scale = route_scale
         self.shared_hidden = shared_hidden
         self.chunk_rows = chunk_rows
+        self.route_eps = route_eps
         self.reset()
 
     def reset(self):
@@ -187,7 +190,7 @@ class DroplessMoE(TensorModule):
         with jax.named_scope("MoeRoute"):
             idx, weights = sigmoid_topk_routing(
                 xt, P["router"], S["route_bias"], k, self.route_norm,
-                self.route_scale)
+                self.route_scale, self.route_eps)
             order, sizes = sort_assignments(idx, jnp.asarray(local_of),
                                             n_held)
             order = jnp.pad(order[:most], (0, -most % chunk))

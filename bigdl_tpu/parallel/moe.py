@@ -151,19 +151,20 @@ def moe_apply_sharded_tokens(router_w, expert_w1, expert_b1, expert_w2,
 # ---------------------------------------------------------------------------
 
 def sigmoid_topk_routing(x, router_w, bias, top_k: int, route_norm: bool,
-                         route_scale: float):
+                         route_scale: float, norm_eps: float = 1e-20):
     """x: (T, D) -> (idx (T, k) int32 expert ids, weights (T, k) float32).
     Scores are sigmoid(x W) in true float32 (the product is D x E: free);
     the experts are the top k of score + bias, the bias entering the
-    choice only; the weights are the chosen scores, normalised to sum 1
-    where ``route_norm``, times ``route_scale``."""
+    choice only; the weights are the chosen scores, divided by their sum +
+    ``norm_eps`` where ``route_norm`` (afmoe and deepseek_v3 write 1e-20,
+    lfm2_moe 1e-6), times ``route_scale``."""
     logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
     _, idx = lax.top_k(scores + lax.stop_gradient(bias), top_k)
     weights = jnp.take_along_axis(scores, idx, axis=-1)
     if route_norm:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + norm_eps)
     return idx, weights * route_scale
 
 

@@ -10,7 +10,8 @@ import numpy as np
 import pytest
 
 import bigdl_tpu.nn as nn
-from benchmark.program import from_program_tree, to_program_tree
+from benchmark.program import (from_program_tree, leaf_dicts,
+                               to_program_tree)
 from benchmark.reference import afmoe as ref
 from bigdl_tpu import tensor as bt
 from bigdl_tpu.models.afmoe import AfmoeLM, afmoe_layer
@@ -245,6 +246,40 @@ def test_the_walk_is_sized_from_the_shapes(shape, heads, passes, pairs,
     assert plan["sliced_bytes"] == b * t * (heads // g) * (d + dv) * 4
     assert plan["slice_bytes_a_pair"] == \
         2 * b * block * (heads // g) * (d + dv) * 4
+
+
+def test_the_gate_is_the_gated_modules_alone():
+    """One module with an optional gate: the gated class keeps its
+    parameter names and their order (``wg`` after ``wv``: the reference's
+    mapping is by construction order), the ungated one has no ``wg``, and
+    the gated output is the ungated heads' (read through an identity output
+    projection) times sigmoid(x Wg), through Wo."""
+    d, heads, kv, hd = 32, 4, 2, 8
+    gated = nn.GatedGroupedQueryAttention(d, heads, kv, hd, window=8,
+                                          rotary_base=1e4)
+    plain = nn.GroupedQueryAttention(d, heads, kv, hd, window=8,
+                                     rotary_base=1e4)
+    assert list(gated.params()["~"]) == list(ref.ATTENTION_PARTS[:5]) + [
+        "q_norm", "k_norm"] == ["wq", "wk", "wv", "wg", "wo", "q_norm",
+                                "k_norm"]
+    assert list(plain.params()["~"]) == ["wq", "wk", "wv", "wo", "q_norm",
+                                         "k_norm"]
+    assert set(gated.quant_spec) == {"wq", "wk", "wv", "wg", "wo"}
+    assert set(plain.quant_spec) == {"wq", "wk", "wv", "wo"}
+    assert repr(gated).startswith("GatedGroupedQueryAttention(32, heads=4/2x8")
+    key = lambda n: jax.random.fold_in(jax.random.PRNGKey(23), n)
+    own = {name: (1.0 if leaf.ndim == 1 else 0.3) * jax.random.normal(
+        key(n), leaf.shape)
+        for n, (name, leaf) in enumerate(gated.params()["~"].items())}
+    x = jax.random.normal(key(99), (2, 19, d))
+    joined = run(plain, {"~": dict(
+        {k: v for k, v in own.items() if k != "wg"}, wo=jnp.eye(d))}, x)
+    close(run(gated, {"~": own}, x),
+          (joined * jax.nn.sigmoid(x @ own["wg"])) @ own["wo"])
+    # the model's tree: every attention leaf gated, in the reference's order
+    leaves = [leaf for leaf in leaf_dicts(build().params()) if "wq" in leaf]
+    assert len(leaves) == CFG["num_hidden_layers"] and all(
+        tuple(leaf) == ref.ATTENTION_PARTS for leaf in leaves)
 
 
 def test_window_layer_ignores_keys_outside_the_window():

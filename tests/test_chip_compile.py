@@ -445,3 +445,66 @@ def test_deepseek_v3_expert_layer_compiles_at_published_widths(one_chip):
         5 + nn.DroplessMoE.STEPS_OF_CHUNK
     assert not re.findall(r"= f32\[16,(2048,768|768,2048)\]\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+
+
+@pytest.mark.parametrize("kind,core_loops", [("conv", 0),
+                                             ("full_attention", 4)])
+def test_lfm2_moe_expert_layer_compiles_at_published_widths(one_chip, kind,
+                                                            core_loops):
+    """One decoder layer of ``models/lfm2_moe.py`` at LFM2-24B-A2B's widths
+    (hidden 2048; a gated short convolution of 3 taps, or 32/8 heads of 64
+    with q/k norms and rotary and no gate; 8 of 64 experts of width 1536,
+    top-4, no shared expert), one 8,192-token sequence, forward and
+    backward under bf16 compute.  The short convolution's taps are shifted
+    multiply-adds: no depthwise convolution and no loop (the layer's
+    ``Recompute`` makes the (T, 3 x 2048) projection again in the backward
+    pass; nothing of it is held).  The
+    attention layer's core holds no T x T array (2 + 2 loops: one
+    sequence's 8 key heads are one pass of the backward).  As in a deepseek_v3 layer the routed experts'
+    sum goes straight into the residual add: one chunk loop for each step
+    a backward pass may shorten to, and no forward pass over the chunks."""
+    import bigdl_tpu.nn as nn
+    from bigdl_tpu import tensor as bt
+    from bigdl_tpu.models.lfm2_moe import Lfm2MoeLM
+    from bigdl_tpu.nn import init as init_
+    from bigdl_tpu.nn.module import Context
+
+    t, d = 8192, 2048
+    drawn = init_.normal_on_device
+    init_.normal_on_device = lambda shape, std=None: np.broadcast_to(
+        np.float32(0), shape)           # shapes only: nothing is run
+    try:
+        # the model's own construction of an expert layer of this kind (a
+        # vocabulary of 256 rows: the tied table is not run)
+        layer = Lfm2MoeLM(
+            256, d, [kind], 0, 32, 8, 11776, 1536, 64, 4,
+            experts_held=range(8)).modules[0].modules[0]
+    finally:
+        init_.normal_on_device = drawn
+    abstract = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+
+    def loss(p, s, x):
+        y, _ = layer.apply(p, x, s, Context(training=True,
+                                            key=jax.random.PRNGKey(0)))
+        return y.sum()
+
+    before = bt.policy()
+    bt.set_policy(bt.BF16_COMPUTE)
+    try:
+        compiled = jax.jit(jax.grad(loss)).lower(
+            abstract(layer.params()), abstract(layer.state()),
+            jax.ShapeDtypeStruct((1, t, d), F32, sharding=one_chip)
+        ).compile()
+    finally:
+        bt.set_policy(before)
+    text = compiled.as_text()
+    assert isinstance(layer, nn.Recompute)
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert "feature_group_count" not in text        # no depthwise conv
+    assert not re.search(r"\b32,(1,)?%d,%d\]" % (t, t), text)
+    assert len(re.findall(r" while\(", text)) == \
+        core_loops + nn.DroplessMoE.STEPS_OF_CHUNK
+    assert not re.findall(r"= f32\[8,(2048,1536|1536,2048)\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
