@@ -22,6 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from bigdl_tpu.nn.containers import kept
 from bigdl_tpu.nn.module import TensorModule
 from bigdl_tpu.nn import init as init_
 from bigdl_tpu.nn.linear import swiglu
@@ -120,17 +121,19 @@ class DroplessMoE(TensorModule):
     # layers with no balance term held 0.07 to 2.7 times the even share by
     # seed and step, most of them under it.  One layer's forward and
     # backward cost 14.4 ms + 0.42 ms a thousand rows passed + 0.43 ms a
-    # thousand held, and a second pass 6.8 ms before its first row.  Passes
-    # of one or two even shares (a third pass for the rare layer over two)
-    # pay for 1.5 times the rows held where one of three paid for 3.5
-    # times; a third size of pass would save ~1% of that model's step more
-    # and adds as much code again to load at every start.  A second shape
-    # runs on the same two constants (hidden 2048 x 768, top-6, 2 shared
-    # experts: deepseek_v3, PERF.md section 6, PR 33; even share 12,288,
-    # passes of 12,288 or 24,576 rows): its routing collapses under the
-    # cut, a layer holds 0.001 to 3.1 even shares, and `rows_moved` over
-    # `assignments_held` comes to 1.73 over 80 layers and steps (1.05 ..
-    # 1,024 a layer; the median layer 1.75)
+    # thousand held (9.0 ms + the same since PR 36: 5.4 ms of the fixed part
+    # were the routing's scalar gathers, their scatter, and a second top-k
+    # and sort in the recomputation), and a second pass 6.8 ms before its
+    # first row.  Passes of one or two even shares (a third pass for the
+    # rare layer over two) pay for 1.5 times the rows held where one of
+    # three paid for 3.5 times; a third size of pass would save ~1% of that
+    # model's step more and adds as much code again to load at every start.
+    # A second shape runs on the same two constants (hidden 2048 x 768,
+    # top-6, 2 shared experts: deepseek_v3, PERF.md section 6, PR 33; even
+    # share 12,288, passes of 12,288 or 24,576 rows): its routing collapses
+    # under the cut, a layer holds 0.001 to 3.1 even shares, and
+    # `rows_moved` over `assignments_held` comes to 1.73 over 80 layers and
+    # steps (1.05 .. 1,024 a layer; the median layer 1.75)
     CHUNK_OF_EVEN_SHARE = 2
     STEPS_OF_CHUNK = 2
 
@@ -173,27 +176,34 @@ class DroplessMoE(TensorModule):
         self._add_buffer("tap_rows_moved", np.zeros((), np.float32))
         return self
 
+    def chunk_of(self, n_tokens: int):
+        """(the most assignments this share can get of ``n_tokens``: every
+        token's choices among the experts held; the rows of a chunk)."""
+        n_held = len(self.experts_held)
+        most = n_tokens * min(self.top_k, n_held)
+        even = n_tokens * self.top_k * n_held / self.n_experts
+        return most, min(most, self.chunk_rows or -(-int(
+            self.CHUNK_OF_EVEN_SHARE * even) // 256) * 256)
+
     def _forward(self, P, x, S, ctx):
         from bigdl_tpu.parallel.moe import (grouped_experts, rows_moved,
                                             sigmoid_topk_routing,
                                             sort_assignments)
         xt = x.reshape(-1, x.shape[-1])
-        n_held, k = len(self.experts_held), self.top_k
-        local_of = np.full((self.n_experts,), n_held, np.int32)
-        local_of[list(self.experts_held)] = np.arange(n_held)
-        # the most assignments this share can get: every token's choices
-        # among the experts held
-        most = xt.shape[0] * min(k, n_held)
-        even = xt.shape[0] * k * n_held / self.n_experts
-        chunk = min(most, self.chunk_rows or -(-int(
-            self.CHUNK_OF_EVEN_SHARE * even) // 256) * 256)
+        k = self.top_k
+        most, chunk = self.chunk_of(xt.shape[0])
         with jax.named_scope("MoeRoute"):
             idx, weights = sigmoid_topk_routing(
                 xt, P["router"], S["route_bias"], k, self.route_norm,
                 self.route_scale, self.route_eps)
-            order, sizes = sort_assignments(idx, jnp.asarray(local_of),
-                                            n_held)
-            order = jnp.pad(order[:most], (0, -most % chunk))
+            order, sizes = sort_assignments(idx, self.experts_held)
+            # what the routing decided (with ``route_idx``, marked where it
+            # is chosen), half a megabyte a layer: a ``Recompute`` around
+            # the layer hands it to the backward pass, and the layer's
+            # recomputation holds no top-k, no sort and no count
+            order = kept(jnp.pad(order[:most], (0, -most % chunk)),
+                         "route_order")
+            sizes = kept(sizes, "route_sizes")
             y = grouped_experts(xt, P["w_gate"], P["w_up"], P["w_down"],
                                 weights, order, sizes, chunk,
                                 self.STEPS_OF_CHUNK, k,

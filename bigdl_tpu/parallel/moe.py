@@ -157,28 +157,47 @@ def sigmoid_topk_routing(x, router_w, bias, top_k: int, route_norm: bool,
     the experts are the top k of score + bias, the bias entering the
     choice only; the weights are the chosen scores, divided by their sum +
     ``norm_eps`` where ``route_norm`` (afmoe and deepseek_v3 write 1e-20,
-    lfm2_moe 1e-6), times ``route_scale``."""
+    lfm2_moe 1e-6), times ``route_scale``.
+
+    The choice is made once: a ``Recompute`` around the caller keeps
+    ``idx`` and its recomputation runs no top-k.  A chosen score is picked
+    out of its token's E by compares (``take_along_axis``'s result bit for
+    bit: one term of each sum is not zero): on the chip a gather of T x k
+    scalars costs ~8 ns a scalar, forward, and a scalar scatter backward,
+    where the compares fuse into a reduction."""
     logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
     _, idx = lax.top_k(scores + lax.stop_gradient(bias), top_k)
-    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    idx = kept(idx, "route_idx")
+    chosen = idx[:, :, None] == jnp.arange(scores.shape[-1])
+    weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0.0), axis=-1)
     if route_norm:
+        # XLA joins a sum of sums into one sum over (k, E), which adds a
+        # token's k scores in another order than a sum over k does and
+        # moves the last digit: the barrier keeps the two sums apart
+        weights = lax.optimization_barrier(weights)
         weights = weights / (weights.sum(axis=-1, keepdims=True) + norm_eps)
     return idx, weights * route_scale
 
 
-def sort_assignments(idx, local_of, n_held: int):
+def sort_assignments(idx, experts_held):
     """The (token, choice) assignments grouped by the expert held here.
-    idx: (T, k) global expert ids; ``local_of``: (E,) the local index of a
-    held expert, ``n_held`` for one that lives elsewhere.  Returns
-    (order, sizes): ``order`` (T*k,) lists the flat assignment indices
-    (token * k + choice), held experts first in local order, the absent
-    ones' last; ``sizes`` (n_held,) counts each held expert's."""
-    local = local_of[idx].reshape(-1)
+    idx: (T, k) global expert ids; ``experts_held``: the distinct ids of
+    the experts held, in local order.  Returns (order, sizes): ``order``
+    (T*k,) lists the flat assignment indices (token * k + choice), held
+    experts first in local order, the absent ones' last; ``sizes``
+    (n_held,) counts each held expert's.  An id's local index is found by
+    compares against the held ids, not looked up (a scalar gather, as
+    above)."""
+    n_held = len(experts_held)
+    is_held = idx.reshape(-1, 1) == jnp.asarray(experts_held, jnp.int32)
+    # the local index of a held expert, ``n_held`` for one that lives
+    # elsewhere
+    local = jnp.min(jnp.where(is_held, jnp.arange(n_held, dtype=jnp.int32),
+                              n_held), axis=-1)
     order = jnp.argsort(local, stable=True).astype(jnp.int32)
-    sizes = jnp.sum(local[:, None] == jnp.arange(n_held), axis=0,
-                    dtype=jnp.int32)
+    sizes = jnp.sum(is_held, axis=0, dtype=jnp.int32)
     return order, sizes
 
 

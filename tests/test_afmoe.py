@@ -546,11 +546,17 @@ def _unmarked():
 # blocks around a ``while`` over key blocks: forward, its recomputation,
 # backward = 3, of which Recompute drops the recomputation; the chunks are
 # walked by one loop for each step a pass may shorten to (``_STEPS``), each
-# with 3 products forward and 9 in its backward rule (3 again + 6).
+# with 3 products forward and 9 in its backward rule (3 again + 6).  Of the
+# routing ``_moe((0, 1), 2 * T)`` decides for B2 * T tokens: their four
+# choices, the 2 * B2 * T assignments two held experts can get, in chunks of
+# 2 * T, and the two counts (int32, a label carries its dtype).
 B2 = 2
 _STEPS = nn.DroplessMoE.STEPS_OF_CHUNK
-_CORE = {"attention_out": (B2, T, 2, 2, 16), "attention_lse": (B2, 2, 2, T)}
-_SUM = {"experts_out": (B2 * T, 64)}
+_CORE = {"attention_out": ("f32", (B2, T, 2, 2, 16)),
+         "attention_lse": ("f32", (B2, 2, 2, T))}
+_SUM = {"route_idx": ("i32", (B2 * T, 4)),
+        "route_order": ("i32", (2 * B2 * T,)), "route_sizes": ("i32", (2,)),
+        "experts_out": ("f32", (B2 * T, 64))}
 RECOMPUTE_CASES = {
     "window": (lambda: _attention_layer("sliding_attention"),
                (2, 0), (3, 0), _CORE),
@@ -610,15 +616,16 @@ def test_recompute_changes_nothing(case, capsys):
     assert "checkpoint" in text or "remat" in text
     assert _loops_and_products(text) == loops
     assert report == {"layers": 1, "kept": {
-        label: 4 * int(np.prod(shape)) for label, shape in keeps.items()}}
+        label: 4 * int(np.prod(shape))
+        for label, (_, shape) in keeps.items()}}
     # the arrays the backward pass is handed: the layer's input, the
     # parameters, constants of the routing, and the marked arrays
     jax.ad_checkpoint.print_saved_residuals(f["recompute"], params, x)
     inside = [line.split()[0] for line in capsys.readouterr().out.split("\n")
               if " from the argument " not in line and line.strip()
               and "from a constant" not in line and "<lambda>" not in line]
-    shapes = sorted("f32[%s]" % ",".join(map(str, s))
-                    for s in keeps.values())
+    shapes = sorted("%s[%s]" % (dtype, ",".join(map(str, shape)))
+                    for dtype, shape in keeps.values())
     assert sorted(inside) == shapes
 
 
@@ -692,9 +699,14 @@ def test_the_step_logs_what_its_recomputes_keep():
     heads, d, hidden = (CFG["num_attention_heads"], CFG["head_dim"],
                         CFG["hidden_size"])
     assert kept[0]["layers"] == 5
+    # an expert layer's routing of 2 * T tokens: four choices a token,
+    # 2 * 2 * T assignments that its two held experts can get (one chunk),
+    # two counts, all int32
     assert kept[0]["kept"] == {
         "attention_out": 5 * 2 * T * heads * d * 4,
         "attention_lse": 5 * 2 * T * heads * 4,
+        "route_idx": 4 * 2 * T * 4 * 4, "route_order": 4 * 2 * 2 * T * 4,
+        "route_sizes": 4 * 2 * 4,
         "experts_out": 4 * 2 * T * hidden * 4}
     types = [e["type"] for e in logged]
     assert types.index("run_start") < types.index("recompute") \

@@ -324,6 +324,14 @@ def test_attention_backward_accumulates_in_vmem(one_chip, hq, hk, d, dv,
     assert compiled.memory_analysis().temp_size_in_bytes <= temp
 
 
+# A routing is decided once: a ``Recompute``d expert layer's gradient holds
+# one top-k (a sort of the scores' lanes on this chip) and one sort of the
+# assignments, none in the recomputation (2 of them, 4 before PR 36; the
+# passes' scatter-adds sort their rows too, under other names).
+ROUTING_SORT = (r' sort\(.*op_name="[^"]*MoeRoute/'
+                r'(top_k|jit\(argsort\)/sort)"')
+
+
 @pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
 def test_afmoe_expert_layer_compiles_at_published_widths(one_chip, kind):
     """One decoder layer of ``models/afmoe.py`` at Trinity-Mini's widths
@@ -380,6 +388,7 @@ def test_afmoe_expert_layer_compiles_at_published_widths(one_chip, kind):
     assert f"{t},{t}]" not in text                  # no T x T array
     assert len(re.findall(r" while\(", text)) == 4 + 2 * ffn.STEPS_OF_CHUNK
     assert not re.findall(r"= f32\[16,(2048,1024|1024,2048)\]\S* copy\(", text)
+    assert len(re.findall(ROUTING_SORT, text)) == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
 
 
@@ -444,6 +453,7 @@ def test_deepseek_v3_expert_layer_compiles_at_published_widths(one_chip):
     assert len(re.findall(r" while\(", text)) == \
         5 + nn.DroplessMoE.STEPS_OF_CHUNK
     assert not re.findall(r"= f32\[16,(2048,768|768,2048)\]\S* copy\(", text)
+    assert len(re.findall(ROUTING_SORT, text)) == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
 
 
@@ -507,4 +517,33 @@ def test_lfm2_moe_expert_layer_compiles_at_published_widths(one_chip, kind,
     assert len(re.findall(r" while\(", text)) == \
         core_loops + nn.DroplessMoE.STEPS_OF_CHUNK
     assert not re.findall(r"= f32\[8,(2048,1536|1536,2048)\]\S* copy\(", text)
+    assert len(re.findall(ROUTING_SORT, text)) == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+
+
+@pytest.mark.parametrize("top_k,experts,held", [(8, 128, 16), (6, 128, 16),
+                                                (4, 64, 8)],
+                         ids=["afmoe", "deepseek_v3", "lfm2_moe"])
+def test_the_routing_fuses_its_selections(one_chip, top_k, experts, held):
+    """``MoeRoute`` outside the passes at the three cells' sizes (16,384
+    tokens a step), forward and backward: the chosen scores and the local
+    indices are compares that the compiler fuses into the reductions that
+    read them, so no (token, choice, expert) array goes through HBM (67 MB
+    at afmoe's size) and no gather or scatter is left."""
+    from bigdl_tpu.parallel.moe import sigmoid_topk_routing, sort_assignments
+    t, d = 16384, 2048
+
+    def routed(x, w, bias, c):
+        idx, weights = sigmoid_topk_routing(x, w, bias, top_k, True, 1.0)
+        order, sizes = sort_assignments(idx, tuple(range(held)))
+        return jnp.sum(weights * c), (idx, order, sizes)
+
+    text = compile_for(one_chip,
+                       jax.value_and_grad(routed, (0, 1), has_aux=True),
+                       ((t, d), F32), ((d, experts), F32), ((experts,), F32),
+                       ((t, top_k), F32))
+    entry = text[text.index("\nENTRY "):]
+    assert not re.search(r"= \w+\[(%d,%d,%d|%d,%d)\]" % (
+        t, top_k, experts, t * top_k, held), entry)
+    assert not re.search(r" (gather|scatter)\(", text)
+    assert len(re.findall(r" sort\(", text)) == 2

@@ -352,7 +352,9 @@ def test_recompute_changes_nothing_and_holds_the_core_only(capsys):
     latent path is made again.  The routed experts' sum is marked too, but
     here it goes straight into the residual add: no backward computation
     reads it, so it is offered and not held (an afmoe layer's closing norm
-    reads it), and neither grouped pass runs in the recomputation."""
+    reads it), and neither grouped pass runs in the recomputation.  What
+    the routing decided (the choices, the sorted assignments, the counts:
+    int32) is held, and decided once."""
     heads, dv, d = (CFG["num_attention_heads"], CFG["v_head_dim"],
                     CFG["hidden_size"])
     attention, own, x = _attention_pair(T, 8)
@@ -369,20 +371,26 @@ def test_recompute_changes_nothing_and_holds_the_core_only(capsys):
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(jax.grad(f["bare"])(params, x))):
         close(a, b)
-    keeps = {"attention_out": (2, T, heads, 1, dv),
-             "attention_lse": (2, heads, 1, T), "experts_out": (2 * T, d)}
+    # 2 * T tokens choose 6 of 16, three of them held: 3 * 2 * T
+    # assignments at most, one chunk
+    keeps = {"attention_out": ("f32", (2, T, heads, 1, dv)),
+             "attention_lse": ("f32", (2, heads, 1, T)),
+             "route_idx": ("i32", (2 * T, 6)),
+             "route_order": ("i32", (3 * 2 * T,)),
+             "route_sizes": ("i32", (3,)), "experts_out": ("f32", (2 * T, d))}
     assert report == {"layers": 1, "kept": {
-        label: 4 * int(np.prod(shape)) for label, shape in keeps.items()}}
+        label: 4 * int(np.prod(shape))
+        for label, (_, shape) in keeps.items()}}
     jax.ad_checkpoint.print_saved_residuals(f["recompute"], params, x)
     inside = [line.split()[0] for line in capsys.readouterr().out.split("\n")
               if " from the argument " not in line and line.strip()
               and "from a constant" not in line and "<lambda>" not in line]
     assert sorted(inside) == sorted(
-        "f32[%s]" % ",".join(map(str, s)) for label, s in keeps.items()
-        if label != "experts_out")
+        "%s[%s]" % (dtype, ",".join(map(str, shape)))
+        for label, (dtype, shape) in keeps.items() if label != "experts_out")
     text = str(jax.make_jaxpr(jax.grad(f["recompute"]))(params, x))
     bare_text = str(jax.make_jaxpr(jax.grad(f["bare"]))(params, x))
-    for op in ("while[", "ragged_dot_general["):
+    for op in ("while[", "ragged_dot_general[", " top_k[", " sort["):
         assert text.count(op) == bare_text.count(op) > 0
 
 
@@ -440,9 +448,13 @@ def test_three_steps_through_the_optimizer_match_reference():
     assert len(kept) == 1 and events.validate_event(kept[0])
     heads, dv = CFG["num_attention_heads"], CFG["v_head_dim"]
     assert kept[0]["layers"] == layers
+    # an expert layer's routing: six choices of 2 * T tokens, the 3 * 2 * T
+    # assignments three held experts can get, three counts, all int32
     assert kept[0]["kept"] == {
         "attention_out": layers * 2 * T * heads * dv * 4,
         "attention_lse": layers * 2 * T * heads * 4,
+        "route_idx": sparse * 2 * T * 6 * 4,
+        "route_order": sparse * 3 * 2 * T * 4, "route_sizes": sparse * 3 * 4,
         "experts_out": sparse * 2 * T * CFG["hidden_size"] * 4}
 
     params = p0

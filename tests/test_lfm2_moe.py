@@ -442,9 +442,12 @@ def test_reference_in_chunks_and_recomputed_is_the_same_reference():
 def test_recompute_changes_nothing_and_remakes_the_projection(capsys):
     """One conv expert layer under its ``nn.Recompute``: the gradient of
     the bare layer; nothing of the layer's inside is handed to the backward
-    pass (the (B, T, 3D) projection is made again, not kept; the routed
-    experts' sum is offered and not held, no backward computation reads
-    it), and neither grouped pass runs in the recomputation."""
+    pass but what the routing decided (the (B, T, 3D) projection is made
+    again, not kept; the routed experts' sum is offered and not held, no
+    backward computation reads it; the four choices of 2 * T tokens, the
+    3 * 2 * T assignments three held experts can get and the three counts
+    are held, int32), neither grouped pass runs in the recomputation, and
+    the routing is decided once."""
     d = CFG["hidden_size"]
     conv = nn.ShortConv(d, 3)
     wrapped = pre_norm_layer(d, conv, _moe((0, 1, 2)), CFG["norm_eps"])
@@ -460,16 +463,19 @@ def test_recompute_changes_nothing_and_remakes_the_projection(capsys):
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(jax.grad(f["bare"])(params, x))):
         close(a, b)
-    assert report == {"layers": 1, "kept": {"experts_out": 4 * 2 * T * d}}
+    assert report == {"layers": 1, "kept": {
+        "route_idx": 4 * 2 * T * 4, "route_order": 4 * 3 * 2 * T,
+        "route_sizes": 4 * 3, "experts_out": 4 * 2 * T * d}}
     jax.ad_checkpoint.print_saved_residuals(f["recompute"], params, x)
     inside = [line.split()[0] for line in capsys.readouterr().out.split("\n")
               if " from the argument " not in line and line.strip()
               and "from a constant" not in line and "<lambda>" not in line]
-    assert inside == []
+    assert sorted(inside) == sorted([
+        "i32[%d,4]" % (2 * T), "i32[%d]" % (3 * 2 * T), "i32[3]"])
     text = str(jax.make_jaxpr(jax.grad(f["recompute"]))(params, x))
     bare_text = str(jax.make_jaxpr(jax.grad(f["bare"]))(params, x))
-    assert text.count("ragged_dot_general[") \
-        == bare_text.count("ragged_dot_general[") > 0
+    for op in ("ragged_dot_general[", " top_k[", " sort["):
+        assert text.count(op) == bare_text.count(op) > 0
 
 
 def test_three_steps_through_the_optimizer_match_reference():
@@ -529,6 +535,10 @@ def test_three_steps_through_the_optimizer_match_reference():
     assert kept[0]["kept"] == {
         "attention_out": 2 * T * heads * hd * 4,        # one attention layer
         "attention_lse": 2 * T * heads * 4,
+        # an expert layer's four choices of 2 * T tokens, the 3 * 2 * T
+        # assignments three held experts can get, three counts: int32
+        "route_idx": sparse * 2 * T * 4 * 4,
+        "route_order": sparse * 3 * 2 * T * 4, "route_sizes": sparse * 3 * 4,
         "experts_out": sparse * 2 * T * CFG["hidden_size"] * 4}
 
     params = p0
