@@ -11,6 +11,9 @@ read:
     crash-<reason>-p<proc>-<pid>/
       reason.txt     what tripped, free text
       events.jsonl   the event ring buffer's last N events (obs/events)
+      spans.json     the loops' span rings' tails (obs/spans): the last
+                     iterations' (path, start, end, step) and the span
+                     open when this was dumped
       memory.json    per-device HBM stats (utils/profiler)
       config.json    BIGDL_*/JAX_* env, jax version, process topology
       threads.txt    Python stack of every live thread (where was the
@@ -122,6 +125,14 @@ def dump_crash_bundle(reason: str, run_dir: str | None = None,
         _write(os.path.join(path, "events.jsonl"), lambda f: f.writelines(
             json.dumps(e, default=events_mod._jsonable) + "\n"
             for e in log.ring_events()))
+    try:
+        from bigdl_tpu.obs import spans as spans_mod
+        tails = spans_mod.timeline_tails()
+    except Exception as e:
+        tails = [{"unavailable": repr(e)}]
+    if tails:
+        _write(os.path.join(path, "spans.json"),
+               lambda f: json.dump(tails, f, default=repr))
     _write(os.path.join(path, "threads.txt"),
            lambda f: f.write(thread_stacks()))
     _write(os.path.join(path, "config.json"),

@@ -53,8 +53,10 @@ logger = logging.getLogger("bigdl_tpu.obs")
 #: landed (what a traced train step's `nn.Recompute` layers keep for
 #: the backward pass besides their inputs).  v9: the `attention_walk`
 #: type landed (how wide the backward of each `blockwise_attention` core
-#: of a traced train step walks, and what its accumulators take).
-SCHEMA_VERSION = 9
+#: of a traced train step walks, and what its accumulators take).  v10: the
+#: `step_timeline` type landed (the loop's iterations of one `optimize()`
+#: call as a distribution, from `obs/spans.py`'s ring).
+SCHEMA_VERSION = 10
 
 ENV_OBS = "BIGDL_OBS"
 ENV_DIR = "BIGDL_OBS_DIR"
@@ -85,6 +87,19 @@ EVENT_TYPES = {
     # in the order traced (`parallel.ring_attention._walk_plan`: heads and
     # pairs a pass, the carried gradient's bytes, the bytes added by slice)
     "attention_walk": ("cores",),
+    # written when an `optimize()` call of the local loop ends: over its
+    # `steps` iterations (the `sampled` last ones for the distributions:
+    # `obs/spans.py`'s ring is bounded) p50 / p95 / max milliseconds of an
+    # iteration's wall (`iter_ms`), of the `dispatch/call` span
+    # (`call_ms`) and of what lies between two calls (`between_calls_ms`);
+    # `in_flight`: {steps the device still held: dispatches that found
+    # as many}; `device_empty`: {what a dispatch to an idle device
+    # followed, a flush's reason, `start` or `none`: count}; `slowest`:
+    # the five slowest iterations, each with its `step`, its `ms`, its
+    # longest `span` and that span's `span_ms`
+    "step_timeline": ("steps", "sampled", "iter_ms", "call_ms",
+                      "between_calls_ms", "in_flight", "device_empty",
+                      "slowest"),
     # serving lifecycle/telemetry (serve/engine.py, serve/decode.py,
     # serve/router.py, serve/cluster.py): kind-specific required fields
     # in SERVE_KINDS below; error events carry the failed request count

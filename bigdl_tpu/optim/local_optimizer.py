@@ -16,6 +16,7 @@ JVM-thread artifact).  What is kept, capability-for-capability:
 """
 from __future__ import annotations
 
+import collections
 import logging
 import os
 import time
@@ -30,7 +31,7 @@ from bigdl_tpu.nn.containers import kept_report
 from bigdl_tpu.nn.module import Context
 from bigdl_tpu.obs import events as obs_events
 from bigdl_tpu.obs import taps as obs_taps
-from bigdl_tpu.obs.spans import SpanTracker
+from bigdl_tpu.obs.spans import SpanTracker, render_timeline
 from bigdl_tpu.optim.optim_method import SGD, OptimMethod, Default
 from bigdl_tpu.optim import trigger as triggers
 from bigdl_tpu.optim.metrics import Metrics
@@ -128,6 +129,19 @@ class _HostSyncWindow:
     def push(self, entry: _PendingStep):
         self.arm()
         self.pending.append(entry)
+
+    def in_flight(self) -> int:
+        """Steps dispatched whose loss the device has not produced yet.
+        Every dispatched and unflushed step is in ``pending`` and the
+        device runs them in order, so the walk goes from the newest and
+        stops at the first ready one: no wait, no transfer, and a cost
+        that is the depth and not the cadence."""
+        n = 0
+        for entry in reversed(self.pending):
+            if entry.loss.is_ready():
+                break
+            n += 1
+        return n
 
     def due(self) -> bool:
         """Same chunk-safe gate as ``TapsMonitor``: at least ``cadence``
@@ -634,9 +648,10 @@ class LocalOptimizer:
             # window there), and a span open around that read would book
             # its whole wall after the read
             while not self.end_when(state):
-                fetch_start = time.perf_counter()   # the iteration's top
                 neval0 = int(state["neval"])
                 epoch0 = int(state["epoch"])
+                # the iteration's top: its spans carry the step from here
+                fetch_start = self.spans.begin_step(neval0)
                 self._window.arm()
                 dev = qdepth = None
                 with self.spans.span("data-load"):
@@ -670,11 +685,18 @@ class LocalOptimizer:
 
                 train_start = time.perf_counter()
                 with self.spans.span("dispatch"):
-                    lr = self._current_lr()
-                    key = RNG.next_key()
-                    params, net_state, opt_state, loss, finite, taps = \
-                        step_fn(params, net_state, opt_state, x, y,
-                                jnp.float32(lr), key, self._lr_scales_arg)
+                    # the rate and the key (two small device programs and
+                    # a host-to-device copy a step) apart from the call,
+                    # which may block in the runtime
+                    with self.spans.span("prepare"):
+                        lr = self._current_lr()
+                        key = RNG.next_key()
+                        lr_dev = jnp.float32(lr)
+                    in_flight = self._note_in_flight()
+                    with self.spans.span("call"):
+                        params, net_state, opt_state, loss, finite, taps = \
+                            step_fn(params, net_state, opt_state, x, y,
+                                    lr_dev, key, self._lr_scales_arg)
                 train_time = time.perf_counter() - train_start
 
                 with self.spans.span("bookkeep"):
@@ -684,8 +706,9 @@ class LocalOptimizer:
                     state["neval"] = neval0 + n_disp
                     state["evalCounter"] = \
                         state.get("evalCounter", 0) + n_disp
-                    extra = ({"queue_depth": int(qdepth)}
-                             if qdepth is not None else {})
+                    extra = {"in_flight": in_flight}
+                    if qdepth is not None:
+                        extra["queue_depth"] = int(qdepth)
                     # loss/finite/taps stay ON DEVICE; the window
                     # materializes them at the next cadence/boundary flush
                     # (no per-step device→host sync — the tentpole of this
@@ -722,9 +745,7 @@ class LocalOptimizer:
                 if preempt:
                     self._checkpoint_and_stop(params, net_state, opt_state,
                                               state)
-                # the iteration's wall, so that time under no span is
-                # measured (loop minus the spans above) and not inferred
-                self.spans.record("loop", time.perf_counter() - fetch_start)
+                self.spans.end_step()
                 if preempt:
                     break
             # the closing flush belongs to the loop's wall, as its spans
@@ -946,10 +967,38 @@ class LocalOptimizer:
             flags["mesh"] = {k: int(v) for k, v in dict(mesh.shape).items()}
         return flags
 
+    def _note_in_flight(self) -> int:
+        """How far ahead the host is, read where the work happens: the
+        steps the device still holds just before the next one is handed
+        to it, booked on the span tree as two counters in the manner of
+        ``loop``.  ``dispatch/in-flight`` sums the counts, one booking a
+        dispatch; ``dispatch/device-empty`` books each dispatch that
+        found none, from which until the launch lands the device has no
+        work, and the run keeps by what such a dispatch followed: the
+        flush that drained the device, by its reason, the call's
+        ``start``, or ``none`` (the device ran out before the host came
+        back)."""
+        w = self._window
+        n = w.in_flight()
+        self.spans.record("dispatch/in-flight", n)
+        self._in_flight_hist[n] += 1
+        if n == 0:
+            self.spans.record("dispatch/device-empty", 0.0)
+            if w.pending:
+                reason = "none"
+            else:
+                reason = w.flush_reasons[-1] if w.flush_reasons else "start"
+            self._empty_after[reason] += 1
+        return n
+
     def _start_obs_run(self):
-        """Fresh taps monitor + run_start event at each optimize()."""
+        """Fresh taps monitor + run_start event at each optimize(); the
+        step timeline of this call starts at the ring's present end."""
         self._taps_monitor = obs_taps.TapsMonitor(self._taps_cadence,
                                                   self._taps_enabled)
+        self._timeline_mark = self.spans.appended
+        self._in_flight_hist = collections.Counter()
+        self._empty_after = collections.Counter()
         try:
             # BIGDL_OBS_HBM_SAMPLE=<s>: cadence HBM sampler for the
             # run (process-wide, started once; obs/ledger.py)
@@ -962,9 +1011,20 @@ class LocalOptimizer:
 
     def _end_obs_run(self, state, wall_start):
         """Flush the tap tail (short runs still log one sample), emit
-        the cumulative phase breakdown and the run_end event."""
+        the cumulative phase breakdown, this call's step timeline (what
+        an untraced slow window leaves behind) and the run_end event."""
         ev = obs_events.get()
         tail = self._taps_monitor.flush() if self._taps_monitor else None
+        timeline = self.spans.step_timeline(self._timeline_mark)
+        if timeline is not None:
+            timeline.update(
+                steps=sum(self._in_flight_hist.values()),
+                in_flight={str(n): c for n, c
+                           in sorted(self._in_flight_hist.items())},
+                device_empty=dict(self._empty_after))
+            logger.info(render_timeline(timeline))
+            if ev is not None:
+                ev.emit("step_timeline", **timeline)
         if ev is not None:
             self.spans.emit_phase_events(ev, int(state["neval"]))
             fields = {"steps": int(state["neval"]) - 1,

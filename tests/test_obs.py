@@ -320,7 +320,7 @@ class TestEvents:
 # ---------------------------------------------------------------------------
 
 class TestSpans:
-    def test_nesting_and_report(self):
+    def test_nesting_and_rows(self):
         m = MetricsClass()
         tr = SpanTracker(m)
         with tr.span("dispatch"):
@@ -330,8 +330,9 @@ class TestSpans:
             pass
         rows = {path: count for path, _, _, _, count in tr.rows()}
         assert rows == {"dispatch": 1, "dispatch/wait": 1, "data-load": 1}
-        rep = tr.report()
-        assert "dispatch" in rep and "wait" in rep
+        # tree order, a child at its parent's depth + 1
+        assert [(path, depth) for path, depth, _, _, _ in tr.rows()] == [
+            ("data-load", 0), ("dispatch", 0), ("dispatch/wait", 1)]
         # nested paths stay local; top-level phases are distributed
         assert "span: dispatch" in m._distributed
         assert "span: dispatch/wait" not in m._distributed
@@ -406,6 +407,15 @@ class TestLoopSpans:
         # two segments an iteration: counters + rollover, trigger probes
         assert spans["bookkeep"][1] == 2 * iterations
         assert spans["validate"][1] == 2
+        if not distri:
+            # the local loop's dispatch, split where its work divides,
+            # and the two counters read between the halves
+            for path in ("dispatch/prepare", "dispatch/call",
+                         "dispatch/in-flight"):
+                assert spans[path][1] == iterations, path
+            assert 1 <= spans["dispatch/device-empty"][1] <= iterations
+            assert spans["dispatch/prepare"][0] + spans["dispatch/call"][0] \
+                <= spans["dispatch"][0]
         inline_h2d = spans["h2d"][0] - spans.get(pf.H2D, (0.0, 0))[0]
         named = sum(spans.get(p, (0.0, 0))[0] for p in self.MAIN) \
             + inline_h2d
