@@ -55,8 +55,10 @@ logger = logging.getLogger("bigdl_tpu.obs")
 #: type landed (how wide the backward of each `blockwise_attention` core
 #: of a traced train step walks, and what its accumulators take).  v10: the
 #: `step_timeline` type landed (the loop's iterations of one `optimize()`
-#: call as a distribution, from `obs/spans.py`'s ring).
-SCHEMA_VERSION = 10
+#: call as a distribution, from `obs/spans.py`'s ring).  v11: a
+#: `step_timeline` event must carry `flushes` (the call's flushes by
+#: reason, and how many of them left the newest step in flight).
+SCHEMA_VERSION = 11
 
 ENV_OBS = "BIGDL_OBS"
 ENV_DIR = "BIGDL_OBS_DIR"
@@ -96,7 +98,9 @@ EVENT_TYPES = {
     # as many}; `device_empty`: {what a dispatch to an idle device
     # followed, a flush's reason, `start` or `none`: count}; `slowest`:
     # the five slowest iterations, each with its `step`, its `ms`, its
-    # longest `span` and that span's `span_ms`
+    # longest `span` and that span's `span_ms`; from v11 also `flushes`:
+    # {reason: {`count`: flushes that materialized something, `kept`:
+    # those of them that left the newest step in flight}}
     "step_timeline": ("steps", "sampled", "iter_ms", "call_ms",
                       "between_calls_ms", "in_flight", "device_empty",
                       "slowest"),
@@ -316,6 +320,10 @@ def validate_event(event: dict) -> dict:
                 raise ValueError(
                     f"stream timeline must be a non-empty list of "
                     f"[ms, tokens] pairs: {tl!r}")
+    if etype == "step_timeline" and event["v"] >= 11 \
+            and "flushes" not in event:
+        raise ValueError(f"'step_timeline' event (v11) missing "
+                         f"['flushes']: {event}")
     if etype == "trace":
         hops = event["hops"]
         if (not isinstance(hops, list) or not hops

@@ -246,6 +246,8 @@ def render_timeline(t: dict) -> str:
     dist = lambda d: f"p50 {d['p50']:.3f} p95 {d['p95']:.3f} max " \
                      f"{d['max']:.3f} ms"
     counts = lambda d: ", ".join(f"{k}: {v}" for k, v in d.items()) or "-"
+    kept = {k: f"{v['kept']} of {v['count']}"
+            for k, v in t["flushes"].items()}
     slowest = "; ".join(
         f"step {s['step']} {s['ms']:.3f} ms ({s['span']} "
         f"{s['span_ms']:.3f})" for s in t["slowest"])
@@ -255,6 +257,7 @@ def render_timeline(t: dict) -> str:
             f"{dist(t['between_calls_ms'])}; steps in flight at a "
             f"dispatch {{{counts(t['in_flight'])}}}; dispatched to an "
             f"empty device after {{{counts(t['device_empty'])}}}; "
+            f"flushes that left a step in flight {{{counts(kept)}}}; "
             f"slowest: {slowest}")
 
 

@@ -93,6 +93,12 @@ class _PendingStep:
         self.extra = extra
 
 
+#: flushes whose only purpose is to bring the host's record up to date.
+#: Every other reason (``trigger``, ``preempt``, ``run-end``, ``exception``)
+#: needs the device's last word and drains.
+_RECORD_FLUSHES = ("cadence", "epoch")
+
+
 class _HostSyncWindow:
     """Cadence-gated device→host synchronization for the training loops
     (docs/observability.md "host pipeline").
@@ -107,8 +113,25 @@ class _HostSyncWindow:
     checkpoint boundaries, on preemption, and at run end.  The in-jit
     skip-step guard (PR 1) keeps params safe between syncs.
 
-    ``flush_steps``/``flush_reasons`` are the audit trail the sync-count
-    test asserts on: host syncs happen at flush boundaries, nowhere else.
+    **A flush that only brings the record up to date leaves the newest
+    step running.**  Waiting for the step dispatched a moment ago empties
+    the device, and everything the loop does at a boundary (the log
+    lines, the next batch, the key, the call) then runs with nothing on
+    it.  So a ``cadence`` or ``epoch`` flush at a cadence above 1
+    materializes every pending step but the last one pushed; that step
+    stays the head of ``pending`` and goes out with the next flush.
+    ``state["loss"]``, the non-finite ledger and the step events are then
+    one step behind the dispatch (they already lag by up to a cadence
+    between flushes).  Whatever needs the device's last word drains as
+    before: ``trigger`` (validation, checkpoint), ``preempt``,
+    ``run-end``, ``exception``, and every flush at cadence 1
+    (``BIGDL_SYNC_EVERY_STEP``, straggler mode).  The rule is
+    :meth:`flushable`, decided by the reason and the cadence alone.
+
+    ``flush_steps``/``flush_reasons``/``flush_kept`` are the audit trail
+    the sync-count test asserts on: the last step each flush materialized,
+    why, and the step it left in flight (None: it drained).  Host syncs
+    happen at flush boundaries, nowhere else.
     """
 
     def __init__(self, cadence: int):
@@ -118,6 +141,7 @@ class _HostSyncWindow:
         self._t0 = None
         self.flush_steps = deque(maxlen=1024)
         self.flush_reasons = deque(maxlen=1024)
+        self.flush_kept = deque(maxlen=1024)
 
     def arm(self):
         """Start the window wall clock — called at the top of the first
@@ -143,22 +167,50 @@ class _HostSyncWindow:
             n += 1
         return n
 
-    def due(self) -> bool:
-        """Same chunk-safe gate as ``TapsMonitor``: at least ``cadence``
-        iterations have begun since the last flushed step."""
-        return bool(self.pending) and \
-            (self.pending[-1].neval0 - self._last_flush) >= self.cadence
+    def dispatched_since_flush(self) -> bool:
+        """Whether a step was pushed after the last flush: ``pending``
+        alone does not say, its newest may be the step that flush kept."""
+        return bool(self.pending) and not (
+            self.flush_kept
+            and self.pending[-1].neval0 == self.flush_kept[-1])
 
-    def flush(self):
-        """Materialize every pending step (the only device→host block in
-        the loop).  Returns (entries, losses, finites, window_wall)."""
-        entries, self.pending = self.pending, []
+    def flushable(self, reason: str) -> int:
+        """How many of the pending steps a flush for ``reason`` would
+        materialize: all of them, or all but the newest (see the class)."""
+        keep = int(self.cadence > 1 and reason in _RECORD_FLUSHES)
+        return max(0, len(self.pending) - keep)
+
+    def due(self) -> bool:
+        """Same chunk-safe gate as ``TapsMonitor``, over the steps a
+        cadence flush would materialize: at least ``cadence`` iterations
+        have begun between the last flushed step and the newest of them.
+        A flush so covers ``cadence`` steps, and a window that holds
+        only the step the last flush kept is never due."""
+        n = self.flushable("cadence")
+        return n > 0 and \
+            (self.pending[n - 1].neval0 - self._last_flush) >= self.cadence
+
+    def flush(self, reason: str):
+        """Materialize what :meth:`flushable` says (the only device→host
+        block in the loop) and book the audit trail.  Returns (entries,
+        losses, finites, window_wall), or None where there is nothing to
+        materialize.  Where a step stays in flight the wall clock is
+        armed again at once: that step is the next window's first, so
+        each window's wall is that of the steps it materializes."""
+        n = self.flushable(reason)
+        if not n:
+            return None
+        entries, self.pending = self.pending[:n], self.pending[n:]
         losses = [np.asarray(e.loss) for e in entries]
         finites = [np.asarray(e.finite) for e in entries]
-        wall = (time.perf_counter() - self._t0) if self._t0 else 0.0
-        self._t0 = None
-        if entries:
-            self._last_flush = entries[-1].neval0
+        now = time.perf_counter()
+        wall = (now - self._t0) if self._t0 else 0.0
+        self._t0 = now if self.pending else None
+        self._last_flush = entries[-1].neval0
+        self.flush_steps.append(entries[-1].neval0)
+        self.flush_reasons.append(reason)
+        self.flush_kept.append(
+            self.pending[0].neval0 if self.pending else None)
         return entries, losses, finites, wall
 
 
@@ -520,40 +572,64 @@ class LocalOptimizer:
         """Materialize the pending window: one blocking device→host sync
         (the ``host-wait`` span), then (the ``flush`` span) the per-step
         host work the serial loop did eagerly — loss logging, the
-        non-finite ledger, step events and TensorBoard scalars.  An abort
-        raised by the ledger is deferred until every pending step's events
-        are out."""
+        non-finite ledger, step events and TensorBoard scalars.
+
+        What is materialized is the window's to say
+        (``_HostSyncWindow.flushable``): a ``cadence`` or ``epoch`` flush
+        leaves the newest step in flight (counter ``flush/kept``) and the
+        host's record one step behind the dispatch; every other reason
+        drains.  A call with nothing to materialize books nothing.  The
+        taps monitor is fed from the flushed entries only, so it never
+        waits for the step still running.  An abort raised by the ledger
+        is deferred until every pending step's events are out, the kept
+        step's too (a second flush, booked as ``exception``)."""
         w = self._window
-        if w is None or not w.pending:
+        if w is None:
             return
-        with self.spans.span("host-wait"):
-            entries, losses, finites, wall = w.flush()
-        with self.spans.span("flush"):
-            w.flush_steps.append(entries[-1].neval0)
-            w.flush_reasons.append(reason)
-            records = sum(e.records for e in entries)
-            rate = records / max(wall, 1e-9)
-            self._note_window_utilization(entries, wall)
-            epoch_size = self.dataset.size()
-            abort = None
-            for e, lv, fv in zip(entries, losses, finites):
-                loss_f = float(lv.reshape(-1)[-1])
-                state["loss"] = loss_f
-                logger.info(
-                    "Epoch %d %d/%d loss %.6f lr %.5g throughput %.1f "
-                    "records/s (fetch %.4fs dispatch %.4fs, synced %s)",
-                    e.epoch, e.count, epoch_size, loss_f, e.lr, rate,
-                    e.fetch_t, e.train_t, reason)
-                if abort is None:
-                    try:
-                        self._note_finite(fv, state)
-                    except NonFiniteGradError as exc:
-                        abort = exc  # emit the remaining step events first
-                self._emit_step_event(e.neval0, loss_f, e.lr, rate,
-                                      monitor.push(e.neval0, e.taps),
-                                      **e.extra)
+        abort = None
+        for why in (reason, "exception"):
+            if not w.flushable(why):
+                break
+            with self.spans.span("host-wait"):
+                flushed = w.flush(why)
+            with self.spans.span("flush"):
+                abort = self._book_flushed(state, monitor, why, flushed,
+                                           abort)
+            if abort is None:
+                break
         if abort is not None:
             raise abort
+
+    def _book_flushed(self, state, monitor, reason, flushed, abort):
+        """The host's record of the steps a flush materialized, oldest
+        first.  Returns the ledger's abort (``abort`` where one is
+        already on its way: the ledger is not asked again)."""
+        entries, losses, finites, wall = flushed
+        self._flushes[reason] += 1
+        if self._window.flush_kept[-1] is not None:
+            self._flushes_kept[reason] += 1
+            self.spans.record("flush/kept", 0.0)
+        records = sum(e.records for e in entries)
+        rate = records / max(wall, 1e-9)
+        self._note_window_utilization(entries, wall)
+        epoch_size = self.dataset.size()
+        for e, lv, fv in zip(entries, losses, finites):
+            loss_f = float(lv.reshape(-1)[-1])
+            state["loss"] = loss_f
+            logger.info(
+                "Epoch %d %d/%d loss %.6f lr %.5g throughput %.1f "
+                "records/s (fetch %.4fs dispatch %.4fs, synced %s)",
+                e.epoch, e.count, epoch_size, loss_f, e.lr, rate,
+                e.fetch_t, e.train_t, reason)
+            if abort is None:
+                try:
+                    self._note_finite(fv, state)
+                except NonFiniteGradError as exc:
+                    abort = exc  # emit the remaining step events first
+            self._emit_step_event(e.neval0, loss_f, e.lr, rate,
+                                  monitor.push(e.neval0, e.taps),
+                                  **e.extra)
+        return abort
 
     def _note_window_utilization(self, entries, wall):
         """Windowed ``train_mfu`` + ``train_step_wall_seconds`` gauges,
@@ -975,16 +1051,18 @@ class LocalOptimizer:
         dispatch; ``dispatch/device-empty`` books each dispatch that
         found none, from which until the launch lands the device has no
         work, and the run keeps by what such a dispatch followed: the
-        flush that drained the device, by its reason, the call's
-        ``start``, or ``none`` (the device ran out before the host came
-        back)."""
+        flush it is the first dispatch after, by its reason (one that
+        drained the device, or one whose kept step ended before the host
+        came back), the call's ``start``, or ``none`` (steps were
+        dispatched since the last flush and the device ran out of
+        them)."""
         w = self._window
         n = w.in_flight()
         self.spans.record("dispatch/in-flight", n)
         self._in_flight_hist[n] += 1
         if n == 0:
             self.spans.record("dispatch/device-empty", 0.0)
-            if w.pending:
+            if w.dispatched_since_flush():
                 reason = "none"
             else:
                 reason = w.flush_reasons[-1] if w.flush_reasons else "start"
@@ -999,6 +1077,8 @@ class LocalOptimizer:
         self._timeline_mark = self.spans.appended
         self._in_flight_hist = collections.Counter()
         self._empty_after = collections.Counter()
+        self._flushes = collections.Counter()
+        self._flushes_kept = collections.Counter()
         try:
             # BIGDL_OBS_HBM_SAMPLE=<s>: cadence HBM sampler for the
             # run (process-wide, started once; obs/ledger.py)
@@ -1021,7 +1101,10 @@ class LocalOptimizer:
                 steps=sum(self._in_flight_hist.values()),
                 in_flight={str(n): c for n, c
                            in sorted(self._in_flight_hist.items())},
-                device_empty=dict(self._empty_after))
+                device_empty=dict(self._empty_after),
+                flushes={reason: {"count": n,
+                                  "kept": self._flushes_kept[reason]}
+                         for reason, n in self._flushes.items()})
             logger.info(render_timeline(timeline))
             if ev is not None:
                 ev.emit("step_timeline", **timeline)

@@ -236,10 +236,45 @@ class TestTrajectoryParity:
 # 2. cadenced host sync: no per-step device→host sync, one jit dispatch
 # ---------------------------------------------------------------------------
 
+class _StubLoss:
+    """A step's device scalar as the window sees it: ``is_ready`` without
+    a wait, and a conversion that notes itself (on a device it would block
+    until the step has run)."""
+
+    def __init__(self, ready, waited):
+        self.ready = ready
+        self._waited = waited
+
+    def is_ready(self):
+        return self.ready
+
+    def __array__(self, dtype=None, copy=None):
+        self._waited.append(self)
+        return np.zeros((), np.float32)
+
+
+def _stub_step(neval0, ready, waited):
+    from bigdl_tpu.optim.local_optimizer import _PendingStep
+    return _PendingStep(neval0, 1, 0, _StubLoss(ready, waited),
+                        _StubLoss(ready, waited), {}, 0.1, 4, 0.0, 0.0, {})
+
+
+def _stub_window(cadence, ready):
+    """A window of ``len(ready)`` pushed steps; returns it and the list
+    every conversion of a loss or a finite flag appends itself to."""
+    from bigdl_tpu.optim.local_optimizer import _HostSyncWindow
+    waited = []
+    w = _HostSyncWindow(cadence)
+    for i, r in enumerate(ready):
+        w.push(_stub_step(i + 1, r, waited))
+    return w, waited
+
+
 class TestCadencedSync:
-    def _opt(self, cadence=None):
-        ds = DataSet.array(_samples(n=64)) >> SampleToBatch(8)
-        opt = LocalOptimizer(_mlp(), ds, nn.ClassNLLCriterion())
+    def _opt(self, cadence=None, n=64, distri=False):
+        ds = DataSet.array(_samples(n=n)) >> SampleToBatch(8)
+        cls = DistriOptimizer if distri else LocalOptimizer
+        opt = cls(_mlp(), ds, nn.ClassNLLCriterion())
         opt.set_state(T(learningRate=0.2))
         if cadence is not None:
             opt.set_taps(enabled=True, cadence=cadence)
@@ -248,7 +283,9 @@ class TestCadencedSync:
     def test_sync_only_at_cadence_boundaries(self, ring_log):
         """The sync-count probe: the window's audit trail shows host
         materializations at cadence boundaries and run end, nowhere else
-        (64-sample epoch = 8 steps, so no epoch flush inside 7 steps)."""
+        (64-sample epoch = 8 steps, so no epoch flush inside 7 steps).  A
+        cadence flush covers the cadence's steps and comes one dispatch
+        later than they: the step dispatched last stays in flight."""
         set_seed(5)
         opt = self._opt(cadence=3)
         opt.set_end_when(max_iteration(7))
@@ -256,10 +293,13 @@ class TestCadencedSync:
         assert list(opt._window.flush_steps) == [3, 6, 7]
         assert list(opt._window.flush_reasons) == ["cadence", "cadence",
                                                   "run-end"]
+        assert list(opt._window.flush_kept) == [4, 7, None]
         # the taps monitor synced at the same boundaries (one host-wait
-        # covers both), and every step still produced its event
+        # covers both: the step it reads is one the flush materialized,
+        # never the one still running), and every step produced its event
         assert list(opt._taps_monitor.materialized_steps) == [3, 6, 7]
-        assert len(_step_events(obs_events.get())) == 7
+        assert [e["step"] for e in _step_events(obs_events.get())] == \
+            list(range(1, 8))
 
     def test_sync_every_step_escape_hatch(self, monkeypatch, ring_log):
         monkeypatch.setenv(pf.ENV_SYNC_EVERY_STEP, "1")
@@ -268,6 +308,8 @@ class TestCadencedSync:
         opt.set_end_when(max_iteration(4))
         opt.optimize()
         assert list(opt._window.flush_steps) == [1, 2, 3, 4]
+        # cadence 1 drains: the host's record is level with the dispatch
+        assert list(opt._window.flush_kept) == [None] * 4
 
     def test_cadenced_losses_match_every_step_sync(self, monkeypatch,
                                                    ring_log):
@@ -296,13 +338,15 @@ class TestCadencedSync:
         opt.set_checkpoint(str(tmp_path), several_iteration(5))
         opt.set_end_when(max_iteration(7))
         opt.optimize()
-        # 24-sample epoch = 3 steps: epoch flushes at 3 and 6; the
-        # checkpoint trigger fires once neval reaches 5 (after step 4 —
-        # neval is the NEXT iteration index, the historical semantics)
-        # and forces its own flush; run-end covers 7
-        assert list(opt._window.flush_steps) == [3, 4, 6, 7]
+        # 24-sample epoch = 3 steps: the epoch flushes after dispatches 3
+        # and 6 bring the record up to steps 2 and 5 and leave 3 and 6
+        # running; the checkpoint trigger fires once neval reaches 5
+        # (after step 4 — neval is the NEXT iteration index, the
+        # historical semantics) and drains, as run-end does
+        assert list(opt._window.flush_steps) == [2, 4, 5, 7]
         assert list(opt._window.flush_reasons) == ["epoch", "trigger",
                                                    "epoch", "run-end"]
+        assert list(opt._window.flush_kept) == [3, None, 6, None]
         assert os.path.exists(tmp_path / "model.5")
 
     def test_unwind_flushes_pending_steps(self, ring_log):
@@ -328,6 +372,171 @@ class TestCadencedSync:
         assert [e["step"] for e in _step_events(obs_events.get())] == \
             [1, 2, 3, 4]
         assert list(opt._window.flush_reasons) == ["exception"]
+        assert list(opt._window.flush_kept) == [None]
+        assert not opt._window.pending
+
+    # -- the newest step stays in flight (PR 38) ---------------------------
+
+    @pytest.mark.parametrize("reason, cadence, left", [
+        ("cadence", 10, 1), ("epoch", 10, 1),
+        ("trigger", 10, 0), ("preempt", 10, 0), ("run-end", 10, 0),
+        ("exception", 10, 0), ("cadence", 1, 0), ("epoch", 1, 0)])
+    def test_only_a_record_flush_leaves_the_newest_step(self, reason,
+                                                        cadence, left):
+        """The step dispatched last reports its loss not ready: a flush
+        that only brings the record up to date must not convert (wait
+        for) it, every other flush and every flush at cadence 1 must."""
+        w, waited = _stub_window(cadence, [True, True, False])
+        newest = w.pending[-1]
+        entries, losses, finites, _ = w.flush(reason)
+        assert len(entries) == len(losses) == len(finites) == 3 - left
+        assert [e.neval0 for e in w.pending] == ([3] if left else [])
+        assert ((newest.loss in waited) and (newest.finite in waited)) \
+            == (not left)
+        assert len(waited) == 2 * (3 - left)
+        assert list(w.flush_steps) == [3 - left]
+        assert list(w.flush_kept) == ([3] if left else [None])
+
+    def test_a_window_holding_only_the_kept_step_books_no_flush(self):
+        w, waited = _stub_window(2, [True, True, False])
+        assert w.due()
+        w.flush("cadence")
+        assert len(w.pending) == 1 and not w.due()
+        for reason in ("cadence", "epoch"):
+            assert w.flushable(reason) == 0 and w.flush(reason) is None
+        assert list(w.flush_steps) == [2] and len(waited) == 4
+        # the gate counts from the last step really materialized (2), over
+        # the steps a flush would materialize: [3] is one, [3, 4] two
+        for neval0, due in ((4, False), (5, True)):
+            w.push(_stub_step(neval0, False, waited))
+            assert w.due() is due
+        entries, *_ = w.flush("cadence")
+        assert [e.neval0 for e in entries] == [3, 4]
+        assert w.flushable("run-end") == 1
+
+    def test_the_window_clock_is_armed_again_where_a_step_stays(self):
+        w, _ = _stub_window(10, [True, True])
+        *_, wall = w.flush("epoch")
+        assert wall > 0 and w._t0 is not None     # the kept step's window
+        *_, wall = w.flush("run-end")
+        assert wall >= 0 and w._t0 is None        # drained: the next arm()
+
+    @pytest.mark.parametrize("distri", [False, True],
+                             ids=["local", "distri"])
+    @pytest.mark.parametrize("cadence, epoch_steps, end", [
+        (3, 8, 7),      # cadence flushes only
+        (4, 3, 9),      # epoch flushes inside a cadence
+        (2, 1, 5),      # an epoch of one step: lags by one and terminates
+        (10, 4, 11),    # the benchmark cell's shape
+        (2, 5, 10),     # the last dispatch ends a cadence and an epoch
+        (1, 3, 5)])     # cadence 1 drains at every step
+    def test_every_step_gets_one_event_whatever_the_flushes(
+            self, monkeypatch, ring_log, distri, cadence, epoch_steps, end):
+        def run(sync_env):
+            monkeypatch.setenv(pf.ENV_SYNC_EVERY_STEP, sync_env)
+            obs_events.configure(None)
+            set_seed(5)
+            opt = self._opt(cadence=cadence, n=8 * epoch_steps,
+                            distri=distri)
+            opt.set_end_when(max_iteration(end))
+            opt.optimize()
+            return opt, _step_events(obs_events.get())
+
+        opt, events = run("0")
+        w = opt._window
+        # exactly one event a step, in order, and nothing left behind
+        assert [e["step"] for e in events] == list(range(1, end + 1))
+        assert not w.pending
+        steps, reasons, kept = (list(w.flush_steps), list(w.flush_reasons),
+                                list(w.flush_kept))
+        assert steps == sorted(set(steps)) and steps[-1] == end
+        # only a flush that kept a step leaves run-end anything to do
+        assert set(reasons) <= {"cadence", "epoch", "run-end"}
+        assert (reasons[-1] == "run-end") == (cadence > 1)
+        for step, reason, left in zip(steps, reasons, kept):
+            if cadence > 1 and reason in ("cadence", "epoch"):
+                assert left == step + 1, (steps, reasons, kept)
+            else:
+                assert left is None, (steps, reasons, kept)
+        # a flush that materializes nothing books nothing
+        assert opt.metrics.get("span: host-wait")[1] == len(steps)
+        assert opt.metrics.get("span: flush")[1] == len(steps)
+        if cadence > 1:
+            n_kept = sum(left is not None for left in kept)
+            assert opt.metrics.get("span: flush/kept")[1] == n_kept > 0
+            # an epoch flush brings the record up to the step before the
+            # epoch's last; a cadence flush covers the cadence's steps
+            for step, reason in zip(steps, reasons):
+                if reason == "epoch":
+                    assert (step + 1) % epoch_steps == 0
+        if (cadence, epoch_steps) == (2, 1):
+            assert steps == [1, 2, 3, 4, 5] and kept == [2, 3, 4, 5, None]
+        # same arithmetic, same keys, same records, same order
+        ref, ref_events = run("1")
+        assert list(ref._window.flush_steps) == list(range(1, end + 1))
+        assert [e["loss"] for e in events] == \
+            [e["loss"] for e in ref_events]
+        np.testing.assert_array_equal(_params_vec(opt.model),
+                                      _params_vec(ref.model))
+
+    @pytest.mark.parametrize("distri", [False, True],
+                             ids=["local", "distri"])
+    def test_a_trigger_and_a_preemption_drain(self, ring_log, tmp_path,
+                                              distri):
+        from bigdl_tpu.utils.engine import Engine
+        set_seed(5)
+        opt = self._opt(cadence=100, distri=distri)
+        opt.set_checkpoint(str(tmp_path), several_iteration(3))
+
+        def end(state):
+            if state.get("neval", 0) == 6 and not Engine.preempted():
+                Engine.request_preemption()
+            return state.get("neval", 0) > 50
+        opt.set_end_when(end)
+        try:
+            opt.optimize()
+        finally:
+            Engine.clear_preemption()
+        # checkpoints after steps 2 and 5 (neval 3 and 6); the notice
+        # lands before step 6, whose iteration honours it
+        assert list(opt._window.flush_reasons) == ["trigger", "trigger",
+                                                   "preempt"]
+        assert list(opt._window.flush_steps) == [2, 5, 6]
+        assert list(opt._window.flush_kept) == [None] * 3
+        assert opt.state["preempted"] and not opt._window.pending
+        assert [e["step"] for e in _step_events(obs_events.get())] == \
+            list(range(1, 7))
+
+    @pytest.mark.parametrize("distri", [False, True],
+                             ids=["local", "distri"])
+    def test_nonfinite_abort_fires_a_step_later_with_every_event_out(
+            self, ring_log, distri):
+        """Every step poisoned, abort after 3 consecutive skips, cadence
+        2: the flush after dispatch 3 reads steps 1-2, the one after
+        dispatch 5 reads step 3 and aborts (a drained window would have
+        at dispatch 4); steps 4 and 5 were dispatched by then and their
+        events go out before the raise, the kept step's by a second
+        flush, and the ledger is not asked again."""
+        from bigdl_tpu.optim import NonFiniteGradError
+        from bigdl_tpu.resilience import faults
+        faults.configure("nan_grad@every=1")
+        try:
+            set_seed(5)
+            opt = self._opt(cadence=2, distri=distri)
+            opt.set_nonfinite_policy(3)
+            opt.set_end_when(max_iteration(20))
+            with pytest.raises(NonFiniteGradError, match="3 consecutive"):
+                opt.optimize()
+        finally:
+            faults.clear()
+        events = obs_events.get().ring_events()
+        assert [e["step"] for e in events if e["type"] == "step"] == \
+            [1, 2, 3, 4, 5]
+        assert len([e for e in events if e["type"] == "abort"]) == 1
+        assert list(opt._window.flush_reasons) == ["cadence", "cadence",
+                                                  "exception"]
+        assert list(opt._window.flush_steps) == [2, 4, 5]
+        assert not opt._window.pending
 
     def test_single_jit_dispatch_with_prefetch(self, monkeypatch,
                                                ring_log):

@@ -3,7 +3,10 @@
 by their path, ``dispatch`` is split into ``dispatch/prepare`` and
 ``dispatch/call`` with a count of the steps in flight between them, and
 every ``optimize()`` call of the local loop leaves one ``step_timeline``
-event and one log line behind.  All on the CPU: no number here is a device
+event and one log line behind; since PR 38 a cadence or epoch flush leaves
+the newest step in flight, the event counts the flushes that did
+(``flushes``, counter ``flush/kept``) and ``device_empty`` stays right with
+a step pending after a flush.  All on the CPU: no number here is a device
 metric."""
 import collections
 import contextlib
@@ -174,9 +177,16 @@ class _Loss:
         return self.ready
 
 
+class _Array(_Loss):
+    """One the window can materialize."""
+
+    def __array__(self, dtype=None, copy=None):
+        return np.zeros((), np.float32)
+
+
 def _pending(neval0, ready):
-    return _PendingStep(neval0, 1, 0, _Loss(ready), None, {}, 0.1, 4, 0.0,
-                        0.0, {})
+    return _PendingStep(neval0, 1, 0, _Array(ready), np.True_, {}, 0.1, 4,
+                        0.0, 0.0, {})
 
 
 @pytest.mark.parametrize("ready, want", [
@@ -219,17 +229,29 @@ def test_device_empty_books_exactly_the_zeros_by_what_preceded_them():
     for p in w.pending:
         p.loss.ready = True
     assert opt._note_in_flight() == 0               # ran dry, no flush
-    w.pending.clear()
-    w.flush_reasons.append("cadence")
-    assert opt._note_in_flight() == 0               # after a flush
-    w.push(_pending(3, False))
+    # a cadence flush leaves step 2 pending: while it runs the device is
+    # not empty, and nothing is booked
+    w.pending[-1].loss.ready = False
+    assert [e.neval0 for e in w.flush("cadence")[0]] == [1]
+    assert opt._note_in_flight() == 1
+    # it ended before the host came back: the zero is the flush's, not
+    # ``none``, though ``pending`` is not empty
+    w.pending[0].loss.ready = True
+    assert opt._note_in_flight() == 0
+    w.push(_pending(3, True))
+    assert opt._note_in_flight() == 0               # dispatched since: none
+    w.flush("trigger")                              # drains
+    assert not w.pending
+    assert opt._note_in_flight() == 0               # after the drain
+    w.push(_pending(4, False))
     assert opt._note_in_flight() == 1
     rows = {path: (total, count)
             for path, _, _, total, count in opt.spans.rows()}
-    assert rows["dispatch/in-flight"] == (4.0, 6)
-    assert rows["dispatch/device-empty"] == (0.0, 3)
-    assert opt._in_flight_hist == {0: 3, 1: 2, 2: 1}
-    assert opt._empty_after == {"start": 1, "none": 1, "cadence": 1}
+    assert rows["dispatch/in-flight"] == (5.0, 9)
+    assert rows["dispatch/device-empty"] == (0.0, 5)
+    assert opt._in_flight_hist == {0: 5, 1: 3, 2: 1}
+    assert opt._empty_after == {"start": 1, "none": 2, "cadence": 1,
+                                "trigger": 1}
 
 
 def test_the_count_rides_the_step_events(run):
@@ -238,11 +260,13 @@ def test_the_count_rides_the_step_events(run):
     assert len(steps) == 11
     assert all(isinstance(e["in_flight"], int) and e["in_flight"] >= 0
                for e in steps)
-    # the first dispatch of a call, and each one after a flush (epochs of
-    # 4 steps, cadence 10), finds nothing in flight
+    # the first dispatch of a call finds nothing in flight; the one after
+    # an epoch's flush (epochs of 4 steps, cadence 10) at most the step
+    # the flush left running
+    assert steps[0]["in_flight"] == 0
     for e in steps:
-        if e["step"] in (1, 5, 9):
-            assert e["in_flight"] == 0, e
+        if e["step"] in (5, 9):
+            assert e["in_flight"] <= 1, e
     total, count = opt.metrics.get("span: dispatch/in-flight")
     assert count == 11 and total == sum(e["in_flight"] for e in steps)
 
@@ -256,11 +280,16 @@ def test_an_optimize_call_emits_one_valid_step_timeline(run):
     assert t["steps"] == t["sampled"] == 11
     assert sum(t["in_flight"].values()) == 11
     assert set(t["in_flight"]) <= {"0", "1", "2", "3"}
-    # two epoch flushes and the call's start; a CPU step this small may
-    # also end before the host is back ("none")
+    # the call's start; the two epoch flushes each left a step running,
+    # which a CPU step this small may end before the host is back (booked
+    # under ``epoch``), as any other may (``none``)
     assert t["device_empty"]["start"] == 1
-    assert t["device_empty"]["epoch"] == 2
+    assert t["device_empty"].get("epoch", 0) <= 2
     assert sum(t["device_empty"].values()) == t["in_flight"]["0"]
+    assert t["flushes"] == {"epoch": {"count": 2, "kept": 2},
+                            "run-end": {"count": 1, "kept": 0}}
+    assert opt.metrics.get("span: flush/kept") == (0.0, 2)
+    assert list(opt._window.flush_steps) == [3, 7, 11]
     for key in ("iter_ms", "call_ms", "between_calls_ms"):
         d = t[key]
         assert 0 <= d["p50"] <= d["p95"] <= d["max"]
@@ -273,6 +302,12 @@ def test_an_optimize_call_emits_one_valid_step_timeline(run):
     assert types.index("step_timeline") < types.index("run_end")
     with pytest.raises(ValueError, match="missing"):
         validate_event({k: v for k, v in t.items() if k != "slowest"})
+    # ``flushes`` came with schema v11: an older event reads without it
+    assert t["v"] == obs_events.SCHEMA_VERSION == 11
+    older = {k: v for k, v in t.items() if k != "flushes"}
+    with pytest.raises(ValueError, match="flushes"):
+        validate_event(older)
+    validate_event(dict(older, v=10))
 
 
 def test_a_second_call_summarises_its_own_iterations(event_log):
@@ -320,13 +355,17 @@ def test_the_five_slowest_are_the_five_slowest(monkeypatch):
     assert t["between_calls_ms"] == {"p50": 2.0, "p95": 2.0, "max": 2.0}
     assert tr.step_timeline(since=tr.appended) is None
     # the log line (``SpanTracker.report()``'s successor) says all of it
-    line = render_timeline(dict(t, steps=12, in_flight={"0": 2, "1": 10},
-                                device_empty={"start": 1, "cadence": 1}))
+    line = render_timeline(dict(
+        t, steps=12, in_flight={"0": 2, "1": 10},
+        device_empty={"start": 1, "cadence": 1},
+        flushes={"cadence": {"count": 1, "kept": 1},
+                 "run-end": {"count": 1, "kept": 0}}))
     assert "\n" not in line
     for piece in ("12 iterations (12 in the ring)",
                   "iteration p50 15.000 p95 90.000 max 90.000 ms",
                   "dispatch/call p50 13.000", "between two calls p50 2.000",
                   "{0: 2, 1: 10}", "{start: 1, cadence: 1}",
+                  "left a step in flight {cadence: 1 of 1, run-end: 0 of 1}",
                   "step 5 90.000 ms (dispatch/call 88.000)"):
         assert piece in line, (piece, line)
 
